@@ -1,16 +1,13 @@
-# Continuous-benchmark kernel-tier workloads (round 15): the three
-# Pallas kernels for the measured memory-bound tail — lane-aware repack,
-# fused CholeskyQR2 panel, fused lasso sweep — each driven THROUGH its
+# Continuous-benchmark kernel-tier workloads (round 15): the two Pallas
+# kernels for the memory-bound tail that Mosaic compiles — fused
+# CholeskyQR2 panel, fused lasso sweep — each driven THROUGH its
 # autotune-dispatched surface (never called directly), with the tuning
 # plane enabled so the row records the measured arm choice.
 #
-# Honesty contract: off TPU the kernels safely decline (interpret mode is
-# a correctness tool, not a performance claim), so CPU rows dispatch the
-# classic arm and say so in the `arm` field + note.  On TPU the same code
-# registers the kernel arm, explores both lowerings, and the row carries
-# whichever dispatch measurement actually won — plus the roofline
-# placement that motivated the kernel (the r05 reshape row sat at ~4.4%
-# of the HBM roofline through the padded narrow-minor store).
+# Off TPU the kernels decline (interpret mode is a correctness tool), so
+# rehearsal rows dispatch the classic arm and say so in the `arm` field.
+# On TPU the same code registers the kernel arm, explores both
+# lowerings, and the row carries whichever dispatch measurement won.
 import numpy as np
 
 import heat_tpu as ht
@@ -54,34 +51,6 @@ class _Tuned:
 
 def run():
     rng = np.random.default_rng(15)
-
-    # ---- reshape_repack: narrow-minor tiled reshape, pad-carrying source
-    gin, gout = config.REPACK_IN, config.REPACK_OUT
-    x = ht.array(
-        rng.standard_normal(gin).astype(np.float32), split=0
-    )
-    with _Tuned():
-
-        def run_reshape(k):
-            out = None
-            for _ in range(k):
-                out = ht.reshape(x, gout)
-            config.drain(out.larray)
-
-        run_reshape(1)  # warmup: compile both arms' programs
-        sl = config.slope(run_reshape)
-        arm, note_arm = _kernel_arm_note()
-    nelem = float(np.prod(gin))
-    record(
-        "reshape_repack", sl.per_unit_s, per="reshape",
-        gin=list(gin), gout=list(gout), arm=arm, **sl.fields(),
-        **config.hbm_fields(2.0 * nelem * 4.0, sl.per_unit_s),
-        note="narrow-minor output (10 lanes of 128): the classic store "
-             "pads every row to the full vector width (~12.8x logical "
-             "write traffic, r05 measured ~4.4% of roofline); the repack "
-             "kernel writes minor-dims compacted at ~1x logical bytes."
-             + note_arm,
-    )
 
     # ---- qr_panel_fused: CholeskyQR2 through the fused panel kernel arm
     m, n = config.QR_PANEL_M, config.QR_PANEL_N

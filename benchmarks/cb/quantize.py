@@ -97,7 +97,7 @@ def _linear_int8(rng):
         **_residency_fields(master_nbytes, qw.nbytes, by_dtype),
         **config.mfu_fields(
             config.matmul_flops_mkn(m, k, n), sl.per_unit_s,
-            config.PEAK_BF16_TFLOPS, "v5e bf16",
+            config.PEAK_BF16_TFLOPS, "bf16",
         ),
         note="int8 weight resident in HBM (absmax per out-channel), "
              "dequant folded into the ring epilogue as runtime operands; "
@@ -143,7 +143,7 @@ def _moe_ffn_int8(rng):
         **_residency_fields(master_nbytes, quant_nbytes, by_dtype),
         **config.mfu_fields(
             config.moe_flops(t, dm, h, k=2), sl.per_unit_s,
-            config.PEAK_BF16_TFLOPS, "v5e bf16",
+            config.PEAK_BF16_TFLOPS, "bf16",
         ),
         note="per-(expert, channel) int8 expert weights through the "
              "routed FFN; scales enter the shard program as runtime "

@@ -7,11 +7,9 @@
 # bought (the acceptance bar is >=3x residency vs the 4*n^2-byte dense
 # affinity at <=5% density; bytes are exact ledger sums, not modeled).
 #
-# Honesty contract: on the CPU CI mesh the Pallas kernel arm does not
-# run natively (it needs HEAT_TPU_PALLAS=interpret, which is far slower
-# than the jitted gather), so the rows are measured from a COLD tuning
-# table — the timed region includes the explore phase running every
-# available arm — and the note says which arm the table resolved to.
+# The rows are measured from a COLD tuning table — the timed region
+# includes the explore phase running both arms — and the note says
+# which arm the table resolved to.
 # The residency and zero-densification columns are the headline; the
 # wall rides the arm choice, hence the wide cited tolerance
 # (history.py).
@@ -30,14 +28,11 @@ import config
 
 def _spmv_arm_note():
     """(arm, suffix) from the tuning table after a workload ran: the
-    resolved winner of a ("dense","gather","kernel") entry, or the
-    honest static default when tuning never saw the site."""
-    # the entry's arm set is the SUPPORTED subset of SPMV_ARMS — on a
-    # CPU mesh the Pallas kernel arm declines, leaving ("dense","gather")
+    resolved winner of a ("dense","gather") entry, or the honest static
+    default when tuning never saw the site."""
     rows = [
         r for r in autotune.report()["rows"]
-        if {"dense", "gather"} <= set(r.get("arms", ()))
-        and set(r.get("arms", ())) <= set(autotune.SPMV_ARMS)
+        if set(r.get("arms", ())) == set(autotune.SPMV_ARMS)
     ]
     if not rows:
         return (

@@ -163,7 +163,7 @@ def _matmul_ring_wire(rng):
         **sl.fields(), **_wire_fields(st, ref, out),
         **config.mfu_fields(
             config.matmul_flops_mkn(m, k, n), sl.per_unit_s,
-            config.PEAK_BF16_TFLOPS, "v5e bf16",
+            config.PEAK_BF16_TFLOPS, "bf16",
         ),
         note="ring matmul with int8 moving blocks (one f32 scale per "
              "k-slice) hopping the ppermute chain beside their scale "

@@ -1,20 +1,19 @@
-"""ResNet-50 DP roofline arithmetic (round-3 VERDICT weak #1 / next #4).
+"""ResNet-50 DP roofline arithmetic.
 
-The 33%-MFU measurement needs its defense committed as numbers, not prose:
-this script compiles the EXACT fused train step the cb suite times, pulls
-XLA's own cost analysis from the compiled module (bytes accessed + flops),
-and divides by the v5e's HBM bandwidth to get the minimum possible
-ms/step for this program.  If measured/roofline >= ~85%, the step is
-proven memory-bound and 33% MFU is the architecture's number, not an
-implementation gap.
+Compiles the EXACT fused train step the cb suite times, pulls XLA's own
+cost analysis from the compiled module (bytes accessed + flops), and
+divides by the chip's HBM bandwidth to get the minimum possible ms/step
+for this program.  If measured/roofline >= ~85%, the step is memory-bound
+and its MFU is the architecture's number, not an implementation gap.
 
-Also runs the batch-scaling sweep (the last unexercised lever named by the
-verdict): throughput vs batch size on the chip.
+Also runs the batch-scaling sweep: throughput vs batch size on the chip.
 
-Output: ROOFLINE_resnet.json at the repo root.
+A device measurement: needs a TPU whose device_kind is in the peak table
+(heat_tpu/core/roofline.py); otherwise it raises.  Output:
+chiprun_out/ROOFLINE_resnet.json (git-ignored) and stdout.
 
 Reference workload: /root/reference/examples/nn/imagenet-DASO/
-(BASELINE.md DP row).  v5e spec constants: 197 TFLOP/s bf16, 819 GB/s HBM.
+(BASELINE.md DP row).
 """
 
 import json
@@ -30,8 +29,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import jax
 import jax.numpy as jnp
 
-HBM_GBPS = 819.0  # v5e spec sheet
-PEAK_BF16_TFLOPS = 197.0
 RESNET50_GMACS_PER_IMG = 4.09  # fwd; train ~3x (fwd + 2x bwd)
 
 
@@ -64,8 +61,6 @@ def cost_analysis(model, X, y):
     )
     compiled = lowered.compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older jax returns [dict]
-        ca = ca[0]
     return {
         "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
         "xla_flops": float(ca.get("flops", 0.0)),
@@ -91,24 +86,28 @@ def chain_delta_ms(model, X, y):
 
 
 def main():
-    on_tpu = jax.default_backend() == "tpu"
-    dt = jnp.bfloat16 if on_tpu else jnp.float32
-    img = 224 if on_tpu else 32
-    flagship_batch = 256 if on_tpu else 8
+    from heat_tpu.core import roofline
+    from heat_tpu.utils import compile_cache
+
+    peaks = roofline.require_peaks()
+    hbm_gbps, peak_bf16_tflops = peaks["hbm_gbps"], peaks["bf16_tflops"]
+    compile_cache.enable()
+    dt, img, flagship_batch = jnp.bfloat16, 224, 256
 
     out = {
-        "hardware": str(jax.devices()[0].device_kind),
-        "hbm_gbps_spec": HBM_GBPS,
-        "peak_bf16_tflops_spec": PEAK_BF16_TFLOPS,
+        "hardware": peaks["device"],
+        "device_count": len(jax.devices()),
+        "hbm_gbps_spec": hbm_gbps,
+        "peak_bf16_tflops_spec": peak_bf16_tflops,
         "image": img,
-        "dtype": str(np.dtype("bfloat16") if on_tpu else np.float32),
+        "dtype": "bfloat16",
     }
 
     model, X, y = build_step(flagship_batch, img, dt)
     ca = cost_analysis(model, X, y)
     measured_ms, sl = chain_delta_ms(model, X, y)
 
-    roofline_ms = ca["bytes_accessed"] / (HBM_GBPS * 1e9) * 1e3
+    roofline_ms = ca["bytes_accessed"] / (hbm_gbps * 1e9) * 1e3
     # useful-work FLOPs (2-flops-per-MAC, fwd + 2x bwd) for the MFU column
     useful_tflops_step = 2 * RESNET50_GMACS_PER_IMG * 3 * flagship_batch / 1e3
     out["flagship"] = {
@@ -120,10 +119,10 @@ def main():
         "roofline_fraction": round(roofline_ms / measured_ms, 3) if measured_ms else None,
         "useful_tflops_per_step_model": round(useful_tflops_step, 3),
         "mfu_measured": round(
-            useful_tflops_step / (measured_ms / 1e3) / PEAK_BF16_TFLOPS, 3
+            useful_tflops_step / (measured_ms / 1e3) / peak_bf16_tflops, 3
         ) if measured_ms else None,
         "mfu_at_roofline": round(
-            useful_tflops_step / (roofline_ms / 1e3) / PEAK_BF16_TFLOPS, 3
+            useful_tflops_step / (roofline_ms / 1e3) / peak_bf16_tflops, 3
         ) if roofline_ms else None,
         "method": f"chain-delta k1={sl.k1} k2={sl.k2}",
     }
@@ -131,7 +130,7 @@ def main():
 
     # batch-scaling sweep: the last unexercised lever
     sweep = []
-    for b in ([128, 256, 384] if on_tpu else [4, 8]):
+    for b in (128, 256, 384):
         try:
             m, Xb, yb = build_step(b, img, dt)
             ms, _sl = chain_delta_ms(m, Xb, yb)
@@ -147,8 +146,9 @@ def main():
             sweep.append({"batch": b, "error": type(e).__name__})
     out["batch_sweep"] = sweep
 
-    path = os.path.join(os.path.dirname(__file__), "..", "ROOFLINE_resnet.json")
-    with open(path, "w") as f:
+    out_dir = os.path.join(os.path.dirname(__file__), "..", "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "ROOFLINE_resnet.json"), "w") as f:
         json.dump(out, f, indent=2)
     print(json.dumps(out, indent=2))
 

@@ -1,3 +1,7 @@
+# CPU-STRUCTURAL TOOL: every leg is a child process forced onto the CPU
+# (JAX_PLATFORMS=cpu, virtual devices), and this parent never touches JAX,
+# so it neither needs nor holds a chip.  Nothing it prints is a device time.
+#
 # Weak/strong scaling sweep over virtual mesh sizes 1/2/4/8 (reference:
 # benchmarks/2020/*/config.json; round-3 VERDICT missing #6).  Each mesh
 # size runs in a SUBPROCESS with its own forced device count; results

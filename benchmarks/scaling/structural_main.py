@@ -1,3 +1,8 @@
+# CPU-STRUCTURAL TOOL: every leg is a child process forced onto the CPU
+# (JAX_PLATFORMS=cpu, virtual devices), and this parent never touches JAX,
+# so it neither needs nor holds a chip.  It counts collectives and bytes
+# in compiled programs; it measures no device time.
+#
 # Structural-census sweep + scaling-law verdicts (round 5; VERDICT r4 #1).
 #
 # Runs structural.py at mesh sizes 2/4/8 (each in a subprocess: the forced

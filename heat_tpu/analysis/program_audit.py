@@ -106,9 +106,10 @@ _RESHARD_HLO = (
 )
 _ALL_HLO = _RESHARD_HLO + ("all-reduce(", "all-reduce-start(",
                            "reduce-scatter(")
+# host-callback primitives as jax 0.9 names them in a jaxpr
+# (jax.debug.print traces to its own ``debug_print`` primitive)
 _CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "callback", "debug_callback",
-    "outside_call", "host_callback_call",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
 })
 
 

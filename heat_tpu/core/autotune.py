@@ -112,13 +112,9 @@ WIRE_ARMS = ("wire_f32", "wire_int8", "wire_fp8")
 # round 19: the sparse compute tier (sparse/matmul.py) — "dense" is the
 # todense() matmul (the authoritative reference; explore always returns
 # its result so numerics never depend on tuning state), "gather" the
-# jitted segment-sum CSR matvec that runs on every backend, "kernel" the
-# lane-aware Pallas ELL SpMV with safe decline (non-TPU, non-f32,
-# VMEM-exceeding row blocks).  A triple, not a pair: the measured winner
-# on a given sparsity geometry is genuinely any of the three (dense wins
-# near-full matrices, gather wins tiny ones, the kernel wins the
-# lane-friendly middle).
-SPMV_ARMS = ("dense", "gather", "kernel")
+# jitted segment-sum CSR matvec (dense wins near-full matrices, gather
+# the sparse ones).
+SPMV_ARMS = ("dense", "gather")
 # round 22: the out-of-core streaming engine (core/stream.py) — the arms
 # are SLAB SIZES, not lowerings: "slab_full" is the budget-derived
 # maximum slab (budget//2 rows, two slabs live under double buffering),
@@ -363,7 +359,7 @@ def matmul_key(
 
 def kernel_key(site: str, *geometry) -> Tuple[str, str]:
     """Tuning-table key for one Pallas-kernel dispatch site
-    (``reshape_repack`` / ``qr_panel`` / ``lasso_sweep``) at one
+    (``qr_panel`` / ``lasso_sweep``) at one
     geometry.  The entry's arms are :data:`KERNEL_ARMS` — "classic" (the
     pre-round-15 lowering) vs "kernel" (the Pallas arm); both are
     measured by the same explore/exploit machinery as ring-vs-GSPMD."""
@@ -385,11 +381,10 @@ def quant_key(site: str, *geometry) -> Tuple[str, str]:
 def spmv_key(site: str, *geometry) -> Tuple[str, str]:
     """Tuning-table key for one sparse-matmul dispatch site
     (``spmv_csr`` — sparse/matmul.py) at one sparsity geometry
-    (shape, nnz bucket, slab capacity, ELL width, rhs columns, dtype,
-    mesh size).  The entry's arms are :data:`SPMV_ARMS`: "dense"
-    (todense() + the ordinary matmul — the reference arm explore
-    returns), "gather" (jitted segment-sum CSR matvec, every backend),
-    "kernel" (the Pallas ELL SpMV, safe decline off-TPU/non-f32)."""
+    (shape, rhs columns, nnz bucket, slab capacity, dtype, mesh size).
+    The entry's arms are :data:`SPMV_ARMS`: "dense" (todense() + the
+    ordinary matmul — the reference arm explore returns) and "gather"
+    (jitted segment-sum CSR matvec)."""
     fp = telemetry.fingerprint(("spmv", site) + tuple(geometry))
     return fp, device_kind()
 
@@ -528,14 +523,13 @@ def timed(fn: Callable, *args) -> Tuple[Any, float]:
     """Run ``fn(*args)`` and return ``(out, wall_s)`` with a
     ``block_until_ready`` fence — the explore-phase measurement (always
     fenced; the steady-state path keeps telemetry's *sampled* fence)."""
+    import jax
+
     t0 = time.perf_counter()
     out = fn(*args)
-    try:
-        import jax
-
-        jax.block_until_ready(out)  # ht: HT002 ok — this IS the measured-arm timing barrier (autotune.timed)
-    except Exception:
-        pass
+    # an asynchronous device error (OOM, runtime fault) surfaces here and
+    # must propagate: a poisoned result is not a timing
+    jax.block_until_ready(out)  # ht: HT002 ok — this IS the measured-arm timing barrier (autotune.timed)
     return out, time.perf_counter() - t0
 
 
@@ -749,32 +743,14 @@ def merge(paths, out) -> str:
     return out
 
 
-def _enable_jax_compilation_cache(path: str) -> None:
-    """Turn on JAX's persistent compilation cache next to the tuning
-    cache (same warm-restart story for LOWERED programs: the second
-    process skips XLA compilation the way it skips exploration).
-    Respects an operator's explicit setting; never raises — an old jax
-    without the knob just misses the warm lowering."""
-    try:
-        import jax
-
-        if jax.config.jax_compilation_cache_dir:
-            return
-        jax.config.update("jax_compilation_cache_dir", path + ".jaxcache")
-        # compile walls on a warm serving path are short; cache them all
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
-        pass
-
-
 def _init_from_env() -> None:
     """Import-time warm start: ``HEAT_TPU_AUTOTUNE_CACHE=<path>`` loads
-    the tuning table (a missing file is a fresh start, not a fallback)
-    and enables the JAX compilation cache at ``<path>.jaxcache``."""
+    the tuning table (a missing file is a fresh start, not a fallback).
+    The XLA compilation cache is placed separately, by the entry point
+    (:func:`heat_tpu.utils.compile_cache.enable`)."""
     path = os.environ.get("HEAT_TPU_AUTOTUNE_CACHE", "").strip()
     if not path or not enabled():
         return
-    _enable_jax_compilation_cache(path)
     if os.path.exists(path):
         load(path)
 

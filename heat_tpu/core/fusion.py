@@ -672,10 +672,17 @@ def reset_cache() -> None:
     telemetry.reset_group("fusion")
 
 
-def count_fallback(reason: str = "unfusable") -> None:
+def count_fallback(reason: str = "unfusable", error: Optional[BaseException] = None) -> None:
     _STATS["fallbacks"] += 1
     _FALLBACK_REASONS[reason] = _FALLBACK_REASONS.get(reason, 0) + 1
-    telemetry.record_event("fallback", reason=reason)
+    if error is None:
+        telemetry.record_event("fallback", reason=reason)
+    else:
+        # the degraded path swallows the exception: keep what it said
+        telemetry.record_event(
+            "fallback", reason=reason,
+            error=f"{type(error).__name__}: {error}"[:500],
+        )
     if reason == "exec_error":
         # a cached executable dying at run time is the flight recorder's
         # flagship postmortem case: dump the trail before degrading
@@ -1096,14 +1103,14 @@ def _run_many_impl(exprs, gshapes, splits, comm, donate: Tuple[int, ...] = ()):
             donated_ran = True
             if fold:
                 outs, flag = outs[:-1], outs[-1]
-        except Exception:
+        except Exception as exc:
             # trace/lowering/compile/first-run failure: the executable is
             # unusable — do NOT cache it; recompute per-op eagerly
             telemetry.record_event(
                 "compile_end", fingerprint=fp, ok=False,
                 dur_s=round(time.monotonic() - t0, 6),
             )
-            count_fallback("compile_error")
+            count_fallback("compile_error", exc)
             flag = None
             outs = _eager_fallback(
                 instrs, vals, lshapes, out_slots, gshapes, splits, comm, targets
@@ -1141,8 +1148,8 @@ def _run_many_impl(exprs, gshapes, splits, comm, donate: Tuple[int, ...] = ()):
             donated_ran = True
             if fold:
                 outs, flag = outs[:-1], outs[-1]
-        except Exception:
-            count_fallback("exec_error")
+        except Exception as exc:
+            count_fallback("exec_error", exc)
             flag = None
             outs = _eager_fallback(
                 instrs, vals, lshapes, out_slots, gshapes, splits, comm, targets

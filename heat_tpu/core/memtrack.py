@@ -20,7 +20,7 @@ the ``memtrack`` group in ``telemetry.snapshot()`` carries the summary.
 :func:`min_free_bytes` are the ONE ``device.memory_stats()`` reader
 (previously three hand-rolled copies: ``utils/monitor.py``,
 ``cluster/kmeans.py``, and per-call max loops), tolerant of backends that
-return ``None`` (CPU, remote TPU tunnels).  :func:`stats_override` lets
+return ``None`` (CPU).  :func:`stats_override` lets
 tests — and :meth:`FaultInjector.low_hbm` — simulate a memory-starved
 device on backends with no stats, so the informed OOM backoff is testable
 on the CI mesh.
@@ -381,8 +381,7 @@ def _device_readers() -> List[tuple]:
 
 def _raw_device_stats() -> List[Tuple[str, Optional[dict]]]:
     """``(device, memory_stats() or None)`` per local device — ``None``
-    where the backend has no reader (CPU) or the read fails (remote
-    tunnels)."""
+    where the backend has no reader (CPU) or the read fails."""
     if _STATS_OVERRIDE is not None:
         return [
             (str(d.get("device", f"injected:{i}")), d)

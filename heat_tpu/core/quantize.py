@@ -369,8 +369,7 @@ def quantize_weights(
 
 
 def _is_traced(value) -> bool:
-    tracer = getattr(jax.core, "Tracer", ())
-    return isinstance(value, tracer)
+    return isinstance(value, jax.core.Tracer)
 
 
 def tuned_arm(

@@ -36,12 +36,13 @@ import json
 import os
 from typing import Any, Dict, List, Optional
 
-__all__ = ["attribute", "detect_peaks", "render", "report"]
+__all__ = ["attribute", "detect_peaks", "render", "report", "require_peaks"]
 
-# Public per-chip peak numbers by TPU generation: dense bf16 MXU TFLOP/s
-# and HBM GB/s.  f32 rides the MXU at 1/4 rate (the same peak model the
-# cb config uses: PEAK_F32_TFLOPS = PEAK_BF16_TFLOPS / 4).  Matched as
-# lowercase substrings of jax's device_kind, most specific first.
+# THE peak table (bench.py, benchmarks/cb and the roofline report all
+# read it).  Public per-chip numbers by TPU generation (Google Cloud TPU
+# documentation, one page per generation): dense bf16 MXU TFLOP/s and
+# HBM GB/s.  f32 rides the MXU at 1/4 rate.  Matched as lowercase
+# substrings of jax's device_kind, most specific first.
 _KNOWN = (
     ("v6e", 918.0, 1640.0),
     ("v6", 918.0, 1640.0),
@@ -75,6 +76,21 @@ def _parse_env(raw: str) -> Optional[Dict[str, float]]:
         return None
 
 
+def _table_peaks(kind: str) -> Optional[Dict[str, Any]]:
+    low = kind.lower()
+    for sub, bf16, hbm in _KNOWN:
+        if sub in low:
+            return {
+                "device": kind,
+                "known": True,
+                "bf16_tflops": bf16,
+                "f32_tflops": bf16 / 4.0,
+                "hbm_gbps": hbm,
+                "source": "detected",
+            }
+    return None
+
+
 def detect_peaks() -> Dict[str, Any]:
     """The active device's peak table: ``{"device", "known",
     "bf16_tflops", "f32_tflops", "hbm_gbps", "source"}``.  ``source`` is
@@ -100,18 +116,7 @@ def detect_peaks() -> Dict[str, Any]:
             "hbm_gbps": hbm,
             "source": "env",
         }
-    low = kind.lower()
-    for sub, bf16, hbm in _KNOWN:
-        if sub in low:
-            return {
-                "device": kind,
-                "known": True,
-                "bf16_tflops": bf16,
-                "f32_tflops": bf16 / 4.0,
-                "hbm_gbps": hbm,
-                "source": "detected",
-            }
-    return {
+    return _table_peaks(kind) or {
         "device": kind,
         "known": False,
         "bf16_tflops": None,
@@ -119,6 +124,30 @@ def detect_peaks() -> Dict[str, Any]:
         "hbm_gbps": None,
         "source": "unknown",
     }
+
+
+def require_peaks(device=None) -> Dict[str, Any]:
+    """:func:`detect_peaks` for measurement paths (``bench.py``,
+    ``benchmarks/cb``): a device that is not a TPU, or whose
+    ``device_kind`` has no entry in the table, is an error — never a
+    default peak."""
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    kind = str(device.device_kind)
+    if device.platform != "tpu":
+        raise RuntimeError(
+            f"this measurement needs a TPU; JAX found platform "
+            f"{device.platform!r} (device_kind {kind!r})"
+        )
+    peaks = _table_peaks(kind)
+    if peaks is None:
+        raise RuntimeError(
+            f"device_kind {kind!r} has no entry in the peak table "
+            "(heat_tpu/core/roofline.py:_KNOWN); add it with its source"
+        )
+    return peaks
 
 
 def _flops_peak(peaks: dict, dtype) -> Optional[float]:

@@ -941,14 +941,13 @@ def timed_call(fp: Optional[str], fn: Callable, *args, observer=None):
     b0, src0 = memtrack.sample_bytes()
     if b0 is not None:
         record_event("mem_sample", fingerprint=fp, bytes_in_use=b0, source=src0)
+    import jax
+
     t0 = time.perf_counter()
     out = fn(*args)
-    try:
-        import jax
-
-        jax.block_until_ready(out)  # ht: HT002 ok — this IS timed_call's measurement barrier
-    except Exception:  # timing must never break the computation
-        pass
+    # an asynchronous device error (OOM, runtime fault) surfaces here and
+    # must propagate: a poisoned result is neither timed nor returned
+    jax.block_until_ready(out)  # ht: HT002 ok — this IS timed_call's measurement barrier
     dur = time.perf_counter() - t0
     record_timing(fp, dur)
     if observer is not None:

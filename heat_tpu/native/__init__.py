@@ -60,8 +60,10 @@ def _needs_build() -> bool:
 
 
 def _build() -> bool:
+    # generic code only, no host-CPU tuning flag: a built tree may be
+    # copied to a machine with another CPU
     cmd = [
-        "g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
+        "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
         "-pthread", "-o", _SO,
     ] + [os.path.join(_SRC, s) for s in _SOURCES]
     try:

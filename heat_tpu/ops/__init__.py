@@ -7,9 +7,6 @@ schedule ourselves wins (SURVEY.md §7, design stance #6):
 * :mod:`~heat_tpu.ops.halo` — halo exchange for stencils/convolution, the
   TPU counterpart of the reference's eager ``DNDarray.get_halo``
   (heat/core/dndarray.py:383-453).
-* :mod:`~heat_tpu.ops.matmul` — Pallas tiled matmul feeding the MXU with
-  explicit VMEM blocking (replaces the reference's ATen GEMM under its
-  block-cyclic schedule, heat/core/linalg/basics.py:424).
 * :mod:`~heat_tpu.ops.cdist` — fused pairwise-distance kernel, the hot loop
   of KMeans (reference: heat/spatial/distance.py:16-134 metric kernels).
 * :mod:`~heat_tpu.ops.attention` — flash attention (blockwise online
@@ -18,16 +15,12 @@ schedule ourselves wins (SURVEY.md §7, design stance #6):
 """
 
 from .halo import halo_exchange, map_with_halos
-from .matmul import matmul as pallas_matmul
 from .cdist import cdist as fused_cdist
 from .attention import flash_attention
-from .spmv import spmv_ell
 
 __all__ = [
     "halo_exchange",
     "map_with_halos",
-    "pallas_matmul",
     "fused_cdist",
     "flash_attention",
-    "spmv_ell",
 ]

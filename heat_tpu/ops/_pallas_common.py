@@ -1,11 +1,11 @@
 """Shared Pallas plumbing for every kernel in :mod:`heat_tpu.ops`.
 
-Rounds 4–8 grew three Pallas kernels (matmul/cdist/attention) that each
-carried a private copy of the same three pieces: the compiler-params
-version shim, the ``HEAT_TPU_PALLAS`` mode selection, and lane/sublane
-pad helpers.  Round 15 adds three more kernels (repack, fused
-CholeskyQR2 panel, fused lasso sweep), so the boilerplate moves here
-once and all six route through it.
+The ``HEAT_TPU_PALLAS`` mode selection and the lane/sublane pad helpers
+that the four kernels (cdist, attention, fused CholeskyQR2 panel, fused
+lasso sweep) share.  Every kernel here has been compiled by Mosaic on a
+v5e (``chip_smoke.py``); three that could not be (a GEMM with no caller,
+a narrow-minor repack whose shape cast Mosaic refuses, an ELL SpMV whose
+flat gather has no lowering) were deleted rather than wrapped.
 
 Mode contract (unchanged from PR 4): ``HEAT_TPU_PALLAS`` forces
 ``interpret`` / ``tpu`` / ``off``; unset picks ``tpu`` on a TPU backend
@@ -14,7 +14,7 @@ interpreter by exporting ``HEAT_TPU_PALLAS=interpret``).
 
 Per-kernel kill switches: the round-15 kernels are *autotune dispatch
 arms*, so each also honors its own env knob
-(``HEAT_TPU_KERNEL_REPACK`` / ``_QR`` / ``_LASSO`` = ``off``) via
+(``HEAT_TPU_KERNEL_QR`` / ``_LASSO`` = ``off``) via
 :func:`kernel_enabled` — an operator can disable one kernel family
 without touching the others or the Pallas tier as a whole.
 """
@@ -25,7 +25,6 @@ import os
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "LANE",
@@ -34,20 +33,11 @@ __all__ = [
     "mode",
     "pad_to",
     "sublane",
-    "tpu_compiler_params",
 ]
 
 # VPU/MXU lane width: the minor-most tile dimension on every TPU
 # generation this library targets (pallas_guide: min tile (8,128) f32).
 LANE = 128
-
-
-def tpu_compiler_params(**kwargs):
-    """Pallas TPU compiler params across the API drift: the class is
-    ``CompilerParams`` on jax>=0.6.1 but ``TPUCompilerParams`` before —
-    the version-dispatch twin of ``collectives.shard_map_unchecked``."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
 
 
 def mode() -> str:

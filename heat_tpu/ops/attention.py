@@ -12,7 +12,7 @@ accumulator live in VMEM scratch across K steps.  Backward is a recompute
 (jnp) pass under ``jax.custom_vjp`` — XLA refuses nothing there, and the
 memory win of flash attention is in the forward residuals anyway.
 
-Dispatch mirrors ops.matmul: Pallas on TPU, jnp reference otherwise,
+Dispatch: Pallas on TPU, jnp reference otherwise,
 ``HEAT_TPU_PALLAS=interpret`` to exercise the kernel on CPU.
 """
 
@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ._pallas_common import tpu_compiler_params
 
 from ._pallas_common import mode as _mode
 
@@ -87,9 +85,9 @@ def _flash_kernel(
     jax.jit, static_argnames=("causal", "scale", "block_q", "block_k", "interpret")
 )
 def _flash_pallas(q, k, v, causal, scale, block_q=512, block_k=2048, interpret=False):
-    # block defaults from sweeps on v5e at s=4096, d=128: (512, 2048) hits
-    # ~126 TFLOP/s non-causal / ~73 effective causal (docs/PERFORMANCE.md);
-    # the (bq, bk) score tile must be large enough to amortize the per-block
+    # block defaults from a sweep on a v5e at s=4096, d=128 (an earlier
+    # runtime; not re-measured on this one): (512, 2048) beat every smaller
+    # pair; the (bq, bk) score tile must be large enough to amortize the per-block
     # softmax bookkeeping on the VPU, and beats finer blocks even causal
     # where finer granularity would skip more masked work
     bh, sq, d = q.shape
@@ -127,7 +125,7 @@ def _flash_pallas(q, k, v, causal, scale, block_q=512, block_k=2048, interpret=F
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(

@@ -7,7 +7,7 @@ kernel fuses the quadratic expansion  ``|x|² + |y|² − 2·x·yᵀ``  so the n
 terms ride along with the MXU matmul instead of separate HBM passes, and the
 sqrt happens before the tile leaves VMEM.
 
-Dispatch mirrors ops.matmul: Pallas on TPU, jnp expansion otherwise,
+Dispatch: Pallas on TPU, jnp expansion otherwise,
 ``HEAT_TPU_PALLAS=interpret`` for interpreter-mode testing.
 """
 
@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ._pallas_common import tpu_compiler_params
 
 from ._pallas_common import mode as _mode
 from ._pallas_common import pad_to as _pad_to
@@ -77,7 +75,7 @@ def _cdist_pallas(x, y, sqrt=True, block=256, interpret=False):
             pltpu.VMEM((bm, 1), jnp.float32),
             pltpu.VMEM((1, bn), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(

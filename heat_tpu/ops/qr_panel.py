@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_common import LANE, kernel_mode, pad_to, tpu_compiler_params
+from ._pallas_common import LANE, kernel_mode, pad_to
 
 __all__ = ["fused_gram_chol", "panel_mode"]
 
@@ -67,7 +67,8 @@ def panel_mode(m: int, n: int, dtype, mixed: bool, split, nshards: int) -> str:
 
     Safe declines: mixed precision (the bf16 pass-1 contract belongs to
     the classic path), non-f32 dtypes, sharded operands (the kernel is
-    a single-device program; replicated inputs are fine), degenerate
+    a single-device program; a replicated input on a multi-device mesh
+    is run per device under shard_map by the call site), degenerate
     panels, and leaf widths whose Gram working set overflows VMEM."""
     if mixed or jnp.dtype(dtype) != jnp.dtype(jnp.float32):
         return "off"
@@ -94,55 +95,54 @@ def _panel_kernel(n_true, a_ref, r_ref, rinv_ref, g_ref):
     a = a_ref[:].astype(jnp.float32)
     # syrk: contract the row-block dim; accumulates across grid steps
     g_ref[:] += jax.lax.dot_general(
-        a, a, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        a, a, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
 
     @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)
     def _():
         n = g_ref.shape[0]
         rows = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
         colr = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
         rowc = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
-        cols = rows.T
 
-        # right-looking Cholesky, one column per step: masked column
-        # extraction (2-D iota — TPU has no 1-D iota), rank-1 Schur
-        # update on the MXU.  Pad columns of G are zero and never
-        # touched (the loop stops at n_true); breakdown (d <= 0)
-        # NaN-latches through sqrt exactly like jnp.linalg.cholesky.
-        def chol_body(j, carry):
-            A, L = carry
-            colv = jnp.sum(jnp.where(cols == j, A, 0.0), axis=1, keepdims=True)
-            d = jnp.sum(jnp.where(rowc == j, colv, 0.0))
-            c = jnp.where(rowc >= j, colv / jnp.sqrt(d), 0.0)
-            A = A - jax.lax.dot_general(
-                c, c, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            ej = jnp.where(colr == j, 1.0, 0.0)
-            L = L + c * ej
-            return A, L
+        r_ref[:] = jnp.zeros_like(r_ref)
+        # rinv_ref holds B^T of the right-looking solve L·X = I (B starts
+        # as I and ends as X = L^-1), i.e. it ends as X^T = R^-1
+        rinv_ref[:] = jnp.where(rows == cols, 1.0, 0.0).astype(rinv_ref.dtype)
 
-        _, L = jax.lax.fori_loop(
-            0, n_true, chol_body,
-            (g_ref[:], jnp.zeros((n, n), jnp.float32)),
-        )
+        # One loop, one column per step, everything in VMEM refs (a
+        # loop-carried (n, n) value would not fit the vector registers).
+        # Right-looking Cholesky on the Schur complement in g_ref, with
+        # the triangular solve folded in: column j of L is final at step
+        # j, which is all the solve's step j needs.  Row j of the
+        # symmetric Schur complement is a dynamic sublane slice; its
+        # column form is a masked lane reduction (no transposes, no K=1
+        # matmuls).  Pad columns of G are zero and never touched (the
+        # loop stops at n_true); breakdown (d <= 0) NaN-latches through
+        # rsqrt exactly like jnp.linalg.cholesky.
+        def body(j, carry):
+            g = g_ref[:]
+            row = g_ref[pl.ds(j, 1), :]
+            col = jnp.sum(jnp.where(cols == j, g, 0.0), axis=1, keepdims=True)
+            d = jnp.sum(jnp.where(colr == j, row, 0.0), axis=1, keepdims=True)
+            inv = jax.lax.rsqrt(d)
+            rrow = jnp.where(colr >= j, row * inv, 0.0)   # R[j, :]
+            lcol = jnp.where(rowc >= j, col * inv, 0.0)   # L[:, j]
+            g_ref[:] = g - lcol * rrow
+            r_ref[pl.ds(j, 1), :] = rrow.astype(r_ref.dtype)
 
-        # forward substitution for X = L⁻¹, one row per step:
-        # X[j,:] = (e_j − L[j,:j] @ X[:j,:]) / L[j,j]
-        def fs_body(j, X):
-            lrow = jnp.sum(jnp.where(rows == j, L, 0.0), axis=0, keepdims=True)
-            d = jnp.sum(jnp.where(colr == j, lrow, 0.0))
-            lower = jnp.where(colr < j, lrow, 0.0)
-            prod = jnp.dot(lower, X, preferred_element_type=jnp.float32)
-            xrow = (jnp.where(colr == j, 1.0, 0.0) - prod) / d
-            return X + jnp.where(rows == j, xrow, 0.0)
+            bt = rinv_ref[:].astype(jnp.float32)
+            xcol = inv * jnp.sum(
+                jnp.where(cols == j, bt, 0.0), axis=1, keepdims=True
+            )                                             # X[j, :]^T
+            bt = bt - xcol * jnp.where(colr > j, rrow, 0.0)
+            rinv_ref[:] = jnp.where(cols == j, xcol, bt).astype(rinv_ref.dtype)
+            return carry
 
-        X = jax.lax.fori_loop(
-            0, n_true, fs_body, jnp.zeros((n, n), jnp.float32)
-        )
-        r_ref[:] = L.T.astype(r_ref.dtype)
-        rinv_ref[:] = X.T.astype(rinv_ref.dtype)
+        jax.lax.fori_loop(0, n_true, body, 0)
 
 
 def fused_gram_chol(x: jax.Array, *, interpret: bool = False):
@@ -172,12 +172,12 @@ def fused_gram_chol(x: jax.Array, *, interpret: bool = False):
             jax.ShapeDtypeStruct((n_pad, n_pad), x.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((n_pad, n_pad), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         cost_estimate=pl.CostEstimate(
             # syrk dominates; the in-VMEM factorization adds ~n³/3 + n³
-            flops=float(m_pad) * n_pad * n_pad + 2.0 * n_pad**3,
+            flops=m_pad * n_pad * n_pad + 2 * n_pad**3,
             # the fusion win: the panel is read ONCE, G never leaves
             # VMEM, only the two (n, n) factors are written
             bytes_accessed=(m_pad * n_pad + 2 * n_pad * n_pad)

@@ -322,14 +322,7 @@ def init_distributed(
     no-ops.  Returns ``(process_index, process_count)`` — the reference's
     ``(rank, size)``.
     """
-    already = False
-    try:
-        already = jax.distributed.is_initialized()
-    except AttributeError:  # pragma: no cover - older jax
-        from jax._src import distributed as _dist
-
-        already = getattr(_dist.global_state, "client", None) is not None
-    if not already:
+    if not jax.distributed.is_initialized():
         explicit = (
             coordinator_address is not None
             or num_processes is not None
@@ -344,13 +337,9 @@ def init_distributed(
                 **kwargs,
             )
         else:
-            try:
-                from jax._src import xla_bridge as _xla_bridge
+            from jax._src import xla_bridge as _xla_bridge
 
-                backend_up = _xla_bridge.backends_are_initialized()
-            except (ImportError, AttributeError):  # pragma: no cover
-                backend_up = True  # conservatively skip auto-init
-            if not backend_up:
+            if not _xla_bridge.backends_are_initialized():
                 try:
                     # jax's ClusterEnv chain detects Slurm/MPI/GCE/GKE and
                     # reads JAX_COORDINATOR_ADDRESS itself

@@ -1078,7 +1078,7 @@ def rechunk_plan(m_in, rowsz_in, m_out, rowsz_out, S):
 
 
 def _build_rechunk(mesh, axis_name, shape_in, shape_out, plan, chunk,
-                   repack="", wire=""):
+                   wire=""):
     """Flat rechunk: split-0 rows of ``shape_in[1:]`` → split-0 rows of
     ``shape_out[1:]`` following a host-computed :func:`rechunk_plan`.
 
@@ -1090,13 +1090,6 @@ def _build_rechunk(mesh, axis_name, shape_in, shape_out, plan, chunk,
     source slab is padded by one chunk so the final partial chunk's
     ``dynamic_slice`` never clamps (a clamped start would misalign the
     valid head).
-
-    ``repack`` (``""`` | ``"interpret"`` | ``"tpu"``) routes the final
-    local reshape through the lane-aware Pallas repack kernel
-    (``ops/repack.py``) — the narrow-minor ``kernel`` autotune arm that
-    writes the output at ~1x logical bytes instead of the padded
-    ~12.8x.  Bit-exact either way; the arm only changes physical
-    layout traffic.
 
     ``wire`` (``""`` | ``"int8"`` | ``"fp8"``) quantizes each permuted
     chunk on the absmax grid with ONE scalar f32 scale per chunk
@@ -1145,14 +1138,7 @@ def _build_rechunk(mesh, axis_name, shape_in, shape_out, plan, chunk,
                 acc = body(0, acc)
             else:
                 acc = lax.fori_loop(0, n_ch, body, acc)
-        loc_shape = (pb,) + tuple(shape_out[1:])
-        if repack:
-            from ..ops import repack as _repack_kernel
-
-            return _repack_kernel.repack(
-                acc, loc_shape, interpret=(repack == "interpret")
-            )
-        return acc.reshape(loc_shape)
+        return acc.reshape((pb,) + tuple(shape_out[1:]))
 
     return shard_map_unchecked(
         local,
@@ -1164,9 +1150,9 @@ def _build_rechunk(mesh, axis_name, shape_in, shape_out, plan, chunk,
 
 @lru_cache(maxsize=512)
 def _jit_rechunk(mesh, axis_name, shape_in, shape_out, plan, chunk, donate,
-                 repack="", wire=""):
+                 wire=""):
     fn = _build_rechunk(
-        mesh, axis_name, shape_in, shape_out, plan, chunk, repack, wire
+        mesh, axis_name, shape_in, shape_out, plan, chunk, wire
     )
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
@@ -1270,33 +1256,21 @@ def tiled_reshape(
         raise ValueError("rechunk plan out of shift budget")
     itemsize = max(int(jnp.dtype(phys.dtype).itemsize), 1)
 
-    def _mk_run(repack_arm, donate_arg, wm="", phys=phys):
+    def _mk_run(donate_arg, wm="", phys=phys):
         def run(tb):
             chunk = max(1, tb // itemsize)
             fn = _jit_rechunk(
                 comm.mesh, comm.split_axis, gin, gout, plan, chunk,
-                donate_arg, repack_arm, wm,
+                donate_arg, wm,
             )
             return fn(phys)
 
         return run
 
-    # narrow-minor kernel arm (ops/repack.py): eligible when the local
-    # output block has a < 128-lane minor dim and the Pallas tier is
-    # live; dispatched per fingerprint by the autotune table, measured
-    # against the classic lowering.  Safe decline: any ineligibility
-    # (layout, backend, kill switch, autotune off) keeps the classic
-    # path byte-for-byte, with no table entry created.
-    from ..ops import repack as _repack
-
-    pb_out = -(-gout[0] // S)
-    loc_out_shape = (pb_out,) + gout[1:]
-    kmode = _repack.repack_mode(loc_out_shape, phys.dtype)
-
     nelem = 1
     for d in gin:
         nelem *= d
-    fp = fp_k = None
+    fp = None
     if telemetry.ledger_enabled():
         fp = telemetry.fingerprint(
             ("reshape", gin, int(si), gout, int(so), S, str(phys.dtype)),
@@ -1306,20 +1280,6 @@ def tiled_reshape(
             hbm_bytes=2.0 * nelem * itemsize, mesh={"devices": S},
             dtype=str(phys.dtype),
         )
-        if kmode != "off":
-            # separate ledger row per arm: the roofline report must
-            # attribute the repack win (same logical bytes, higher
-            # achieved fraction) instead of averaging it into the
-            # classic row
-            fp_k = telemetry.fingerprint(
-                ("reshape_repack", gin, int(si), gout, int(so), S,
-                 str(phys.dtype)),
-            )
-            telemetry.ensure_program(
-                fp_k, kind="kernel_repack", ops=1, flops=0.0,
-                hbm_bytes=2.0 * nelem * itemsize, mesh={"devices": S},
-                dtype=str(phys.dtype),
-            )
 
     # on-wire byte model for the rechunk stage (exact, from the plan):
     # per nonzero shift, each shard ships n_ch chunk-sized blocks (the
@@ -1361,21 +1321,16 @@ def tiled_reshape(
             desc=f"rechunk {gin}->{gout} {phys.dtype} S={S}",
         )
 
-    arm = "classic"
-    key = None
     if wire_d is not None and wire_d.explore:
-        # wire explore round: every wire arm runs the classic lowering
-        # under measurement, f32 result returned.  The repack arm stays
-        # out of this round (one tuning axis per call keeps the explore
-        # unambiguous); it gets its own consult on later f32-arm calls.
+        # wire explore round: every wire arm runs under measurement, f32
+        # result returned
         def run_for(wm):
             fpx = fp if not wm else _wire_fp(wm)
             return _with_oom_backoff(
-                "reshape", _mk_run("", False, wm), tile_bytes, fp=fpx
+                "reshape", _mk_run(False, wm), tile_bytes, fp=fpx
             )
 
         phys = _wire.explore(wire_d, run_for)
-        arm = "wire"
     elif wire_arm != "wire_f32":
         wm = wire_arm[len("wire_"):]
         fpw = _wire_fp(wm)
@@ -1388,55 +1343,12 @@ def tiled_reshape(
             _wire.payload_nbytes(wire_elems, wire_scales, wm),
         )
         phys = _with_oom_backoff(
-            "reshape", _mk_run("", mid_owned, wm), tile_bytes, fp=fpw,
+            "reshape", _mk_run(mid_owned, wm), tile_bytes, fp=fpw,
             observer=observer,
         )
-        arm = "wire"
-    elif kmode != "off" and autotune.enabled():
-        key = autotune.kernel_key(
-            "reshape_repack", gin, int(si), gout, int(so), S,
-            str(phys.dtype),
-        )
-        d = autotune.decide(
-            key, "classic",
-            desc=f"reshape {gin}->{gout} minor={gout[-1]}",
-            arms=autotune.KERNEL_ARMS,
-        )
-        if d.explore:
-            # run BOTH arms under measurement; donation suppressed (the
-            # same source buffer feeds both runs).  The classic result
-            # is returned, so numerics never depend on tuning state
-            # (repack is bit-exact anyway — this keeps the invariant
-            # uniform across kernel sites).
-            out_c, t_c = autotune.timed(
-                lambda: _with_oom_backoff(
-                    "reshape", _mk_run("", False), tile_bytes, fp=fp
-                )
-            )
-            out_k, t_k = autotune.timed(
-                lambda: _with_oom_backoff(
-                    "reshape", _mk_run(kmode, False), tile_bytes, fp=fp_k
-                )
-            )
-            autotune.observe(key, "classic", t_c)
-            autotune.observe(key, "kernel", t_k)
-            memtrack.register_buffer(out_k, tag="staging", split=0)
-            phys = out_c
-            arm = "explore"
-        elif d.arm == "kernel":
-            arm = "kernel"
-    if arm == "kernel":
-        # steady state: the sampled observer keeps the degradation watch
-        # alive — a kernel winner gone >2x slower than its recorded best
-        # is sent back to explore (same guard as the ring matmul's)
+    else:
         phys = _with_oom_backoff(
-            "reshape", _mk_run(kmode, mid_owned), tile_bytes, fp=fp_k,
-            observer=functools.partial(autotune.observe, key, "kernel"),
-        )
-        memtrack.register_buffer(phys, tag="output", split=0)
-    elif arm == "classic":
-        phys = _with_oom_backoff(
-            "reshape", _mk_run("", mid_owned), tile_bytes, fp=fp
+            "reshape", _mk_run(mid_owned), tile_bytes, fp=fp
         )
 
     if so != 0:

@@ -3,7 +3,7 @@ autotune arms (ROADMAP item 6 — the sparse counterpart of the
 ring-vs-GSPMD and classic-vs-kernel consults).
 
 ``matmul(A, x)`` computes ``A @ x`` for a row-split :class:`DCSR_matrix`
-against a dense vector/matrix.  Three arms per (sparsity-geometry
+against a dense vector/matrix.  Two arms per (sparsity-geometry
 fingerprint, device kind):
 
 ``dense``
@@ -15,11 +15,7 @@ fingerprint, device kind):
     ``x[cols]``, scatter-add per-entry products into the row outputs) —
     runs on every backend, and is the static-dispatch default when the
     tuning plane is off (``HEAT_TPU_SPMV`` overrides: ``dense`` /
-    ``gather`` / ``kernel``).
-``kernel``
-    The lane-aware Pallas ELL SpMV (:mod:`heat_tpu.ops.spmv`) with safe
-    decline: non-TPU backends (unless interpret is forced), non-f32
-    data, and VMEM-exceeding row blocks never register the arm.
+    ``gather``).
 
 Each arm carries a telemetry cost-ledger row (``kind="spmv_*"`` with
 nnz-based FLOP/HBM models) so ``roofline_report()`` places the measured
@@ -34,88 +30,19 @@ from __future__ import annotations
 
 import os
 from functools import lru_cache, partial
-from typing import Optional, Tuple
-
-import numpy as np
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..core import autotune, telemetry, types
+from ..core import autotune, memtrack, telemetry, types
 from ..core.dndarray import DNDarray, _ensure_split
-from ..ops import spmv as spmv_kernel
 from ..parallel.collectives import shard_map_unchecked
 from ._operations import _expand_rows
 from .dcsr_matrix import DCSR_matrix
 
 __all__ = ["matmul", "matvec_program"]
-
-
-# ----------------------------------------------------------- geometry cache
-
-
-def _geometry(A: DCSR_matrix) -> dict:
-    """Per-matrix sparsity geometry for dispatch: the max row nnz (the
-    ELL width driver) read once off the row pointers and cached on the
-    matrix — the structure is immutable even when values mutate in
-    place (``astype(copy=False)`` keeps indices/indptr)."""
-    geom = getattr(A, "_spmv_geom_cache", None)
-    if geom is not None:
-        return geom
-    # one device→host fetch of the (S, rows_per+1) pointer slab; the
-    # row-extent stat is structural metadata, same export class as
-    # shard_csr (nnz/lnnz_all sync points are host metadata already)
-    ptrs = np.asarray(A._lindptr)
-    max_row = int(np.diff(ptrs, axis=1).max()) if ptrs.size else 0
-    geom = {
-        "max_row": max_row,
-        "width": spmv_kernel.ell_width(max_row),
-    }
-    A._spmv_geom_cache = geom
-    return geom
-
-
-def _ell_slabs(A: DCSR_matrix) -> Tuple[jax.Array, jax.Array]:
-    """The matrix's ELL slabs ``(vals (S, rows_pad, W), cols ditto)``,
-    built host-side per shard on first kernel-arm use and cached on the
-    matrix; placed with the same row sharding as the CSR slabs."""
-    cached = getattr(A, "_spmv_ell_cache", None)
-    if cached is not None:
-        return cached
-    width = _geometry(A)["width"]
-    nsh = A.nshards if A.split == 0 else 1
-    # every shard pads to ONE row count (the ragged last shard would
-    # otherwise sublane-pad shorter and break the stacked slab)
-    rows_target = A.rows_per_shard if nsh > 1 else A.shape[0]
-    rows_pad = -(-max(rows_target, 1) // 8) * 8
-    vals_l, cols_l = [], []
-    for r in range(nsh):
-        d, i, p = A.shard_csr(r)
-        v, c = spmv_kernel.ell_pack(d, i, p, width)
-        if v.shape[0] < rows_pad:
-            grow = rows_pad - v.shape[0]
-            v = np.pad(v, ((0, grow), (0, 0)))
-            c = np.pad(c, ((0, grow), (0, 0)), constant_values=-1)
-        vals_l.append(v)
-        cols_l.append(c)
-    vals = np.stack(vals_l)
-    cols = np.stack(cols_l)
-    comm = A.comm
-    if A.split == 0 and comm.size > 1:
-        sh3 = comm.sharding(0, 3)
-    else:
-        sh3 = comm.replicated(3)
-    out = (
-        jax.device_put(jnp.asarray(vals), sh3),
-        jax.device_put(jnp.asarray(cols), sh3),
-    )
-    from ..core import memtrack
-
-    for buf in out:
-        memtrack.register_buffer(buf, tag="staging", split=A.split)
-    A._spmv_ell_cache = out
-    return out
 
 
 # ------------------------------------------------------------- gather arm
@@ -166,54 +93,6 @@ def _run_gather(A: DCSR_matrix, x2: jax.Array) -> jax.Array:
     return y[:n]
 
 
-# ------------------------------------------------------------- kernel arm
-
-
-@lru_cache(maxsize=None)
-def _jit_kernel_sharded(mesh, axis_name, rows_per, interpret):
-    spec = P(axis_name, None, None)
-
-    def local(vals, cols, x2):
-        one = lambda xc: spmv_kernel.spmv_ell(
-            vals[0], cols[0], xc, interpret=interpret
-        )[:rows_per]
-        return jax.vmap(one, in_axes=1, out_axes=1)(x2)
-
-    return jax.jit(
-        shard_map_unchecked(
-            local, mesh,
-            in_specs=(spec, spec, P(None, None)),
-            out_specs=P(axis_name, None),
-        )
-    )
-
-
-@lru_cache(maxsize=None)
-def _jit_kernel_local(rows, interpret):
-    def fn(vals, cols, x2):
-        one = lambda xc: spmv_kernel.spmv_ell(
-            vals[0], cols[0], xc, interpret=interpret
-        )[:rows]
-        return jax.vmap(one, in_axes=1, out_axes=1)(x2)
-
-    return jax.jit(fn)
-
-
-def _run_kernel(A: DCSR_matrix, x2: jax.Array, kmode: str) -> jax.Array:
-    n = A.shape[0]
-    vals, cols = _ell_slabs(A)
-    interp = kmode == "interpret"
-    if A.is_distributed():
-        fn = _jit_kernel_sharded(
-            A.comm.mesh, A.comm.split_axis, A.rows_per_shard, interp
-        )
-        y = fn(vals, cols, x2.astype(jnp.float32))
-    else:
-        fn = _jit_kernel_local(n, interp)
-        y = fn(vals, cols, x2.astype(jnp.float32))
-    return y[:n]
-
-
 # -------------------------------------------------------------- dense arm
 
 
@@ -232,16 +111,16 @@ _ARM_RUNNERS = {"dense": _run_dense, "gather": _run_gather}
 
 def _static_arm() -> str:
     """Static dispatch when the tuning plane is off: ``HEAT_TPU_SPMV``
-    in ``dense`` / ``gather`` / ``kernel`` (default ``gather`` — the
+    in ``dense`` / ``gather`` (default ``gather`` — the
     every-backend sparse path); a malformed value raises, naming the
     variable (the env_bytes strictness contract)."""
     raw = os.environ.get("HEAT_TPU_SPMV", "").strip().lower()
     if raw in ("", "auto", "gather"):
         return "gather"
-    if raw in ("dense", "kernel"):
+    if raw == "dense":
         return raw
     raise ValueError(
-        f"HEAT_TPU_SPMV must be auto|dense|gather|kernel, got {raw!r}"
+        f"HEAT_TPU_SPMV must be auto|dense|gather, got {raw!r}"
     )
 
 
@@ -252,14 +131,20 @@ def _nnz_bucket(nnz: int) -> int:
     return int(nnz).bit_length()
 
 
-def _site_programs(A: DCSR_matrix, k: int, width: int, dt: str) -> dict:
+def _tuning_key(A: DCSR_matrix, k: int, dt: str):
+    n, ncols = A.shape
+    return autotune.spmv_key(
+        "spmv_csr", n, ncols, k, _nnz_bucket(A.nnz), A._data.shape[1],
+        dt, A.comm.size,
+    )
+
+
+def _site_programs(A: DCSR_matrix, k: int, dt: str) -> dict:
     """Ensure one cost-ledger program row per arm (``kind="spmv_*"``,
     nnz-based FLOP/HBM models) and return their fingerprints."""
     n, ncols = A.shape
     nnz = A.nnz
     mesh = {"devices": A.comm.size}
-    rows_pad = -(-A.rows_per_shard // 8) * 8
-    nsh = A.nshards if A.split == 0 else 1
     fps = {}
     fps["dense"] = telemetry.fingerprint(("spmv_dense", n, ncols, k, dt))
     telemetry.ensure_program(
@@ -275,47 +160,30 @@ def _site_programs(A: DCSR_matrix, k: int, width: int, dt: str) -> dict:
         hbm_bytes=float(nnz * 8 + ncols * k * 4 + n * k * 4),
         mesh=mesh, dtype=dt,
     )
-    fps["kernel"] = telemetry.fingerprint(
-        ("spmv_kernel", n, ncols, k, nnz, width, dt)
-    )
-    telemetry.ensure_program(
-        fps["kernel"], kind="spmv_kernel", ops=1,
-        flops=2.0 * nnz * k,
-        hbm_bytes=float(nsh * rows_pad * width * 8 + ncols * k * 4 + n * k * 4),
-        mesh=mesh, dtype=dt,
-    )
     return fps
 
 
 def _dispatch(A: DCSR_matrix, x2: jax.Array) -> jax.Array:
     n, ncols = A.shape
     k = x2.shape[1]
-    geom = _geometry(A)
-    kmode = spmv_kernel.spmv_mode(
-        A.rows_per_shard, ncols, geom["max_row"], x2.dtype
-    )
-    kmode = kmode if jnp.dtype(A.dtype.jax_type()) == jnp.float32 else "off"
-    arms = autotune.SPMV_ARMS if kmode != "off" else ("dense", "gather")
 
     if not autotune.enabled():
         # static dispatch, bit-for-bit: no table touch, no decisions
-        arm = _static_arm()
-        if arm == "kernel":
-            if kmode == "off":
-                arm = "gather"
-            else:
-                return _run_kernel(A, x2, kmode)
-        return _ARM_RUNNERS[arm](A, x2)
+        return _ARM_RUNNERS[_static_arm()](A, x2)
+
+    # the dense arm materializes the (n, ncols) operand: where measured
+    # free HBM says it cannot fit, there is nothing to explore
+    shards = A.comm.size if A.is_distributed() else 1
+    if memtrack.would_fit(n * ncols * x2.dtype.itemsize // shards) is False:
+        return _run_gather(A, x2)
 
     dt = str(x2.dtype)
-    fps = _site_programs(A, k, geom["width"], dt)
-    key = autotune.spmv_key(
-        "spmv_csr", n, ncols, k, _nnz_bucket(A.nnz), A._data.shape[1],
-        geom["width"], dt, A.comm.size,
-    )
+    fps = _site_programs(A, k, dt)
+    key = _tuning_key(A, k, dt)
     d = autotune.decide(
         key, "gather",
-        desc=f"spmv {n}x{ncols} nnz={A.nnz} k={k} {dt}", arms=arms,
+        desc=f"spmv {n}x{ncols} nnz={A.nnz} k={k} {dt}",
+        arms=autotune.SPMV_ARMS,
     )
     if d.explore:
         out_d, t_d = autotune.timed(_run_dense, A, x2)
@@ -324,20 +192,10 @@ def _dispatch(A: DCSR_matrix, x2: jax.Array) -> jax.Array:
         autotune.observe(key, "gather", t_g)
         telemetry.record_timing(fps["dense"], t_d)
         telemetry.record_timing(fps["gather"], t_g)
-        if "kernel" in arms:
-            _, t_k = autotune.timed(_run_kernel, A, x2, kmode)
-            autotune.observe(key, "kernel", t_k)
-            telemetry.record_timing(fps["kernel"], t_k)
         return out_d  # the reference arm's result, always
-    if d.arm == "kernel" and kmode != "off":
-        return telemetry.timed_call(
-            fps["kernel"], _run_kernel, A, x2, kmode,
-            observer=partial(autotune.observe, key, "kernel"),
-        )
-    arm = d.arm if d.arm in _ARM_RUNNERS else "gather"
     return telemetry.timed_call(
-        fps[arm], _ARM_RUNNERS[arm], A, x2,
-        observer=partial(autotune.observe, key, arm),
+        fps[d.arm], _ARM_RUNNERS[d.arm], A, x2,
+        observer=partial(autotune.observe, key, d.arm),
     )
 
 
@@ -347,7 +205,7 @@ def _dispatch(A: DCSR_matrix, x2: jax.Array) -> jax.Array:
 def matmul(A: DCSR_matrix, x, out: Optional[DNDarray] = None) -> DNDarray:
     """``A @ x`` for a DCSR matrix against a dense vector/matrix.  The
     result is a dense DNDarray (row-split when ``A`` is distributed);
-    dispatch is the three-arm autotune consult described in the module
+    dispatch is the two-arm autotune consult described in the module
     docstring."""
     if not isinstance(A, DCSR_matrix):
         raise TypeError(f"A must be a DCSR_matrix, got {type(A)}")
@@ -385,10 +243,6 @@ def matmul(A: DCSR_matrix, x, out: Optional[DNDarray] = None) -> DNDarray:
 # --------------------------------------------------- chain (Lanczos) consult
 
 
-def _matvec_gather_sharded_ops(A: DCSR_matrix):
-    return (A._data, A._indices, A._lindptr)
-
-
 @lru_cache(maxsize=None)
 def _matvec_gather_sharded(mesh, axis_name, rows_per, n):
     spec = P(axis_name, None)
@@ -416,85 +270,22 @@ def _matvec_gather_local(rows, n):
     return apply
 
 
-@lru_cache(maxsize=None)
-def _matvec_kernel_sharded(mesh, axis_name, rows_per, n, interpret):
-    spec = P(axis_name, None, None)
-
-    def local(vals, cols, v):
-        return spmv_kernel.spmv_ell(
-            vals[0], cols[0], v, interpret=interpret
-        )[:rows_per]
-
-    sm = shard_map_unchecked(
-        local, mesh,
-        in_specs=(spec, spec, P(None)), out_specs=P(axis_name),
-    )
-
-    def apply(operands, v):
-        return sm(*operands, v)[:n]
-
-    return apply
-
-
-@lru_cache(maxsize=None)
-def _matvec_kernel_local(n, interpret):
-    def apply(operands, v):
-        vals, cols = operands
-        return spmv_kernel.spmv_ell(
-            vals[0], cols[0], v, interpret=interpret
-        )[:n]
-
-    return apply
-
-
 def matvec_program(A: DCSR_matrix):
     """Jit-static ``(apply_fn, operands)`` for ``v ↦ A @ v`` inside a
-    fused loop.  The chain-consult contract (autotune module docstring):
-    a resolved ``kernel``/``gather`` winner is consumed, anything else
-    falls back to the ``gather`` prior with a recorded ``note_prior`` —
-    a fused solve never explores and never densifies, so the ``dense``
-    arm is deliberately unreachable here."""
-    n, ncols = A.shape
-    geom = _geometry(A)
-    kmode = spmv_kernel.spmv_mode(
-        A.rows_per_shard, ncols, geom["max_row"], jnp.float32
-    )
-    kmode = kmode if jnp.dtype(A.dtype.jax_type()) == jnp.float32 else "off"
-
-    arm = "gather"
+    fused loop: always the ``gather`` arm — a fused solve never explores
+    and never densifies, so the ``dense`` arm is deliberately
+    unreachable here.  An unresolved or ``dense`` table entry is recorded
+    as a ``note_prior`` so the tuning report shows the chain ran on the
+    prior."""
+    n = A.shape[0]
     if autotune.enabled():
-        key = autotune.spmv_key(
-            "spmv_csr", n, ncols, 1, _nnz_bucket(A.nnz), A._data.shape[1],
-            geom["width"], str(jnp.dtype(jnp.float32)), A.comm.size,
-        )
-        w = autotune.winner(key)
-        if w == "kernel" and kmode != "off":
-            arm = "kernel"
-        elif w == "gather":
-            arm = "gather"
-        else:
+        key = _tuning_key(A, 1, str(jnp.dtype(jnp.float32)))
+        if autotune.winner(key) != "gather":
             autotune.note_prior(key, "gather", site="lanczos")
-    else:
-        static = _static_arm()
-        if static == "kernel" and kmode != "off":
-            arm = "kernel"
-
-    if arm == "kernel":
-        operands = _ell_slabs(A)
-        if A.is_distributed():
-            fn = _matvec_kernel_sharded(
-                A.comm.mesh, A.comm.split_axis, A.rows_per_shard, n,
-                kmode == "interpret",
-            )
-        else:
-            fn = _matvec_kernel_local(n, kmode == "interpret")
-        return fn, operands
-    operands = _matvec_gather_sharded_ops(A)
     if A.is_distributed():
         fn = _matvec_gather_sharded(
             A.comm.mesh, A.comm.split_axis, A.rows_per_shard, n
         )
-    else:
-        fn = _matvec_gather_local(A.shape[0], n)
-        operands = (A._data[0], A._indices[0], A._lindptr[0])
-    return fn, operands
+        return fn, (A._data, A._indices, A._lindptr)
+    fn = _matvec_gather_local(A.shape[0], n)
+    return fn, (A._data[0], A._indices[0], A._lindptr[0])

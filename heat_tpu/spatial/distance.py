@@ -151,7 +151,7 @@ def _lazy_cdist(x: DNDarray, y: DNDarray, promoted, split, sqrt: bool):
 
 
 def _pallas_eligible(x: DNDarray, y: DNDarray, promoted) -> bool:
-    from ..ops.matmul import _mode
+    from ..ops._pallas_common import mode as _mode
 
     # only when the promoted dtype is f32: the kernel accumulates and returns
     # f32, and the GSPMD path must stay the dtype-authoritative fallback

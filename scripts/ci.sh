@@ -11,11 +11,14 @@
 #   3. parity   — scripts/parity_audit.py: fail on ANY public-name/signature
 #                 gap against the reference inventory
 #   4. dryrun   — __graft_entry__.py multi-chip dry-run (8 virtual devices)
-#   5. cbsmoke  — one fast cb workload end-to-end (CPU sizes) proving the
-#                 benchmark harness runs
+#   5. cbsmoke  — one fast cb workload end-to-end proving the benchmark
+#                 harness runs (a CPU REHEARSAL at CI sizes: every cb run
+#                 in this script is one, selected below by
+#                 HEAT_TPU_CB_REHEARSAL=cpu; without it the suite refuses
+#                 to run off a TPU.  Its walls are not device times)
 #   6. copycheck— scripts/copycheck.py (difflib vs reference, 0.6 bar)
-#   7. notes    — every committed cb row under 30% of its roofline must
-#                 carry a note naming the bound (no silent bad scores)
+#      (stage numbers are stable labels other files cite; 7 read the
+#      round records that were taken through a plug-in and are gone)
 #   8. fusecache— fusion retrace guard: the second invocation of each cb
 #                 benchmark chain must be a 100% compile-cache hit
 #   9. guardrails— guard/fault-injection tests (non-finite provenance, OOM
@@ -42,7 +45,8 @@
 #                 roofline/history test files, a Chrome-trace export
 #                 shape check (every event carries ph/ts/pid/tid, spans
 #                 nest as B/E pairs), the history.py --self-check gate
-#                 on the checked-in BENCH_cb_r*.json trajectory, and a
+#                 (vacuous until a round record exists; its laws run on a
+#                 fixture trajectory in tests/test_cb_history.py), and a
 #                 cb smoke run under --check-regression proving the
 #                 delta table lands in the --out document
 #  14. memtrack  — HBM residency ledger (ISSUE 10): the memtrack test
@@ -64,10 +68,10 @@
 #                 explores — and the perf-regression gate rerun with the
 #                 tuning plane on
 #  16. kernels   — Pallas kernel tier (ISSUE 12): the kernel test file at
-#                 meshes 8/4/1 (repack/qr-panel/lasso-sweep correctness in
+#                 meshes 8/4/1 (qr-panel/lasso-sweep correctness in
 #                 interpret mode, autotune arm registration, kill
 #                 switches, off-mode bit-for-bit equivalence), the cb
-#                 kernels suite end-to-end — its three rows must land
+#                 kernels suite end-to-end — its two rows must land
 #                 with an honest measured-arm field and its Prometheus
 #                 export must parse — and the perf-regression gate rerun
 #                 with the kernel arms enabled
@@ -111,11 +115,10 @@
 #                 sheds first in the per-class ledger, zero lost
 #                 futures, and the heat_tpu_router_* gauges must parse
 #  22. sparse    — sparse compute tier (ISSUE 19): the spmv test file at
-#                 meshes 8/4/1 (ELL layout laws, gather/kernel-vs-dense
-#                 bit parity incl. ragged + all-zero-rows shards,
-#                 explore-returns-dense bitwise, off-mode bit-for-bit
-#                 with zero table decisions, the HEAT_TPU_KERNEL_SPMV
-#                 kill switch, arm persistence, sparse-vs-dense Lanczos
+#                 meshes 8/4/1 (gather-vs-dense bit parity incl. ragged
+#                 + all-zero-rows shards, explore-returns-dense bitwise,
+#                 off-mode bit-for-bit with zero table decisions, arm
+#                 persistence, sparse-vs-dense Lanczos
 #                 parity, serving no-retrace), then the cb sparse suite
 #                 — its three rows must land with a measured arm AND
 #                 >=3x exact-ledger HBM residency vs the dense affinity
@@ -140,6 +143,9 @@ REPO="$(cd "$(dirname "$0")/.." && pwd)"
 cd "$REPO"
 export PYTHONPATH="$REPO${PYTHONPATH:+:$PYTHONPATH}"
 export JAX_PLATFORMS=cpu
+# every benchmarks/cb run below is a CPU rehearsal at CI sizes (counts,
+# bytes and control flow; never a device time) — benchmarks/cb/config.py
+export HEAT_TPU_CB_REHEARSAL=cpu
 QUICK="${1:-}"
 
 say() { printf '\n=== %s ===\n' "$*"; }
@@ -178,22 +184,6 @@ EOF
 
 say "6/23 copycheck"
 python scripts/copycheck.py
-
-say "7/23 roofline notes (every low-roofline cb row carries its bound story)"
-python - <<'EOF'
-import glob, json, sys
-bad = []
-for path in sorted(glob.glob("BENCH_cb_*.json")):
-    doc = json.load(open(path))
-    for row in doc.get("measurements", []):
-        frac = row.get("hbm_roofline_frac")
-        if frac is not None and frac < 0.3 and not row.get("note"):
-            bad.append(f"{path}: {row['name']} at {frac} lacks a note")
-if bad:
-    print("\n".join(bad))
-    sys.exit(1)
-print("all low-roofline rows annotated")
-EOF
 
 say "8/23 fusion retrace guard (second call must hit the compile cache)"
 ( cd benchmarks/cb && python fusion.py --verify-cache )
@@ -257,9 +247,9 @@ EOF
 say "13/23 roofline attribution + perf-regression gate"
 # measured per-program accounting, device peaks, trace export, and the
 # history gate: the test files first, then the live artifacts — a
-# Chrome-trace export from a real run must be Perfetto-shaped, the
-# checked-in trajectory must pass its own gate (proving the harness
-# bites without hardware), and a cb run under --check-regression must
+# Chrome-trace export from a real run must be Perfetto-shaped, the gate
+# must bite on a fixture trajectory (tests/test_cb_history.py; no round
+# record is checked in yet), and a cb run under --check-regression must
 # carry the delta table in its --out document
 python -m pytest -q -p no:cacheprovider \
   tests/test_roofline.py tests/test_cb_history.py 2>&1 | tee /tmp/ci_roofline.log
@@ -459,7 +449,7 @@ EOF
 say "16/23 Pallas kernel tier (interpret-mode laws + cb rows, meshes 8/4/1)"
 # the kernel-tier contracts (ISSUE 12) at three mesh sizes: each test
 # scopes HEAT_TPU_PALLAS=interpret itself, so plain pytest runs suffice —
-# repack bit-exactness (incl. the pad-lane regression), fused QR panel vs
+# the narrow-minor reshape pad-lane regression, fused QR panel vs
 # the classic three-launch chain (incl. NaN breakdown parity), fused lasso
 # sweep vs the classic sweep, explore-then-stick dispatch, kill switches,
 # and HEAT_TPU_AUTOTUNE=off bit-for-bit equivalence
@@ -469,7 +459,7 @@ HEAT_TEST_DEVICES=4 \
   python -m pytest -q -p no:cacheprovider tests/test_kernels.py
 HEAT_TEST_DEVICES=1 \
   python -m pytest -q -p no:cacheprovider tests/test_kernels.py
-# the cb kernels suite end-to-end: three rows through the
+# the cb kernels suite end-to-end: two rows through the
 # autotune-dispatched surfaces (never calling kernels directly), the
 # measured arm recorded per row (honest "classic" + decline note off
 # TPU), the regression gate green with the kernel arms enabled, and the
@@ -483,7 +473,7 @@ python - <<'EOF'
 import json
 doc = json.load(open("/tmp/ci_cb_kernels.json"))
 rows = {m["name"]: m for m in doc["measurements"]}
-for want in ("reshape_repack", "qr_panel_fused", "lasso_sweep_fused"):
+for want in ("qr_panel_fused", "lasso_sweep_fused"):
     assert want in rows, f"cb kernels suite missing row {want}"
     row = rows[want]
     assert row.get("arm") in ("classic", "kernel"), \
@@ -889,11 +879,10 @@ print(f"fault drill OK: served={served} shed_low={shed_terminal} "
 EOF
 
 say "22/23 sparse compute tier (SpMV laws meshes 8/4/1 + cb rows)"
-# the sparse contracts (ISSUE 19) at three mesh sizes: ELL pack layout
-# laws, gather/kernel(interpret)-vs-dense BIT parity incl. the ragged
-# last shard and an all-zero-rows shard, explore-returns-dense bitwise,
-# HEAT_TPU_AUTOTUNE=off bit-for-bit with zero table decisions, the
-# HEAT_TPU_KERNEL_SPMV kill switch, spmv arm save/load persistence,
+# the sparse contracts (ISSUE 19) at three mesh sizes: gather-vs-dense
+# BIT parity incl. the ragged last shard and an all-zero-rows shard,
+# explore-returns-dense bitwise, HEAT_TPU_AUTOTUNE=off bit-for-bit with
+# zero table decisions, spmv arm save/load persistence,
 # sparse-vs-dense Lanczos eigenvector parity with zero densifications,
 # and the serving no-retrace law under mixed concurrent requests
 python -m pytest -q -p no:cacheprovider \

@@ -1,6 +1,7 @@
 """Perf-regression harness (benchmarks/cb/history.py): tolerance model
-unit laws plus the self-check gate replayed on the real checked-in
-BENCH_cb_r*.json trajectory."""
+unit laws plus the self-check gate replayed on a BENCH_cb_r*.json
+trajectory the test writes (the repo holds no round records yet —
+ROADMAP S0 — so a live run reports every row ``no-history``)."""
 
 import importlib.util
 import json
@@ -21,6 +22,30 @@ def _load_history():
 
 
 history = _load_history()
+
+# a three-round TPU trajectory with the shape of a real one: rows appear
+# over time, walls wander inside tolerance, one tiny row jitters under
+# the absolute floor
+_TRAJECTORY = {
+    2: {"backend": "tpu", "measurements": [
+        {"name": "matmul_split_0", "wall_s": 0.00680},
+        {"name": "concatenate", "wall_s": 0.00090}]},
+    3: {"backend": "tpu", "measurements": [
+        {"name": "matmul_split_0", "wall_s": 0.00668},
+        {"name": "concatenate", "wall_s": 0.00110},
+        {"name": "lanczos", "wall_s": 0.0120}]},
+    4: {"backend": "tpu", "measurements": [
+        {"name": "matmul_split_0", "wall_s": 0.00671},
+        {"name": "concatenate", "wall_s": 0.00230},
+        {"name": "lanczos", "wall_s": 0.0310},
+        {"name": "kmeans_lloyd_iter", "wall_s": 0.00770}]},
+}
+
+
+def _write_trajectory(root, rounds=_TRAJECTORY):
+    for rnum, doc in rounds.items():
+        with open(os.path.join(root, f"BENCH_cb_r{rnum:02d}.json"), "w") as fh:
+            json.dump(doc, fh)
 
 
 class TestCompare(unittest.TestCase):
@@ -98,9 +123,11 @@ class TestHistoryLoading(unittest.TestCase):
         windowed = history.best_history(rounds, "tpu", before_round=3)
         self.assertEqual(windowed["a"]["best_wall_s"], 2.0)
 
-    def test_load_rounds_reads_checked_in_trajectory(self):
-        rounds = history.load_rounds(_ROOT)
-        self.assertGreaterEqual(len(rounds), 2)
+    def test_load_rounds_reads_trajectory_in_round_order(self):
+        with tempfile.TemporaryDirectory() as td:
+            _write_trajectory(td)
+            rounds = history.load_rounds(td)
+        self.assertEqual(len(rounds), 3)
         nums = [r for r, _p, _d in rounds]
         self.assertEqual(nums, sorted(nums))
         for _r, _p, doc in rounds:
@@ -118,30 +145,28 @@ class TestHistoryLoading(unittest.TestCase):
 
 
 class TestGate(unittest.TestCase):
-    def test_self_check_passes_on_checked_in_trajectory(self):
+    def test_self_check_passes_on_a_steady_trajectory(self):
         # the CI gate itself: latest round vs best of the earlier ones
-        self.assertEqual(history.self_check(_ROOT), [])
+        with tempfile.TemporaryDirectory() as td:
+            _write_trajectory(td)
+            self.assertEqual(history.self_check(td), [])
 
     def test_self_check_bites_on_a_planted_regression(self):
-        rounds = history.load_rounds(_ROOT)
-        latest_num, _p, latest = rounds[-1]
-        doctored = json.loads(json.dumps(latest))  # deep copy
-        for m in doctored["measurements"]:
+        doctored = json.loads(json.dumps(_TRAJECTORY))  # deep copy
+        doctored = {int(k): v for k, v in doctored.items()}
+        for m in doctored[4]["measurements"]:
             m["wall_s"] = m["wall_s"] * 10.0
         with tempfile.TemporaryDirectory() as td:
-            for rnum, path, doc in rounds[:-1]:
-                with open(os.path.join(td, os.path.basename(path)), "w") as fh:
-                    json.dump(doc, fh)
-            with open(os.path.join(td, f"BENCH_cb_r{latest_num:02d}.json"),
-                      "w") as fh:
-                json.dump(doctored, fh)
+            _write_trajectory(td, doctored)
             bad = history.self_check(td)
         self.assertTrue(bad)  # 10x everywhere must trip the gate
 
     def test_check_attaches_delta_table_to_doc(self):
         doc = {"backend": "tpu", "measurements": [
             {"name": "matmul_split_0", "wall_s": 1e9}]}
-        bad = history.check(doc, root=_ROOT)
+        with tempfile.TemporaryDirectory() as td:
+            _write_trajectory(td)
+            bad = history.check(doc, root=td)
         self.assertEqual(len(bad), 1)
         reg = doc["regression"]
         self.assertEqual(reg["backend"], "tpu")
@@ -153,9 +178,19 @@ class TestGate(unittest.TestCase):
         # a dev-machine CPU run is never judged against the TPU trajectory
         doc = {"backend": "cpu", "measurements": [
             {"name": "matmul_split_0", "wall_s": 1e9}]}
-        bad = history.check(doc, root=_ROOT)
+        with tempfile.TemporaryDirectory() as td:
+            _write_trajectory(td)
+            bad = history.check(doc, root=td)
         self.assertEqual(bad, [])
         self.assertEqual(doc["regression"]["rows"][0]["status"], "no-history")
+
+    def test_repo_without_records_reports_no_history(self):
+        # the truth today: no round record is checked in
+        doc = {"backend": "tpu", "measurements": [
+            {"name": "matmul_split_0", "wall_s": 1e9}]}
+        self.assertEqual(history.check(doc, root=_ROOT), [])
+        self.assertEqual(doc["regression"]["rows"][0]["status"], "no-history")
+        self.assertEqual(doc["regression"]["baseline_rounds"], [])
 
 
 if __name__ == "__main__":

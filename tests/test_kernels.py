@@ -1,5 +1,5 @@
-"""Pallas kernel tier (ISSUE 12): lane-aware repack, fused CholeskyQR2
-panel, fused lasso sweep — dispatched through autotune.
+"""Pallas kernel tier (ISSUE 12): fused CholeskyQR2 panel and fused lasso
+sweep — dispatched through autotune.
 
 Everything runs on the CPU mesh through Pallas interpret mode
 (``HEAT_TPU_PALLAS=interpret`` scoped per test), so kernel *logic* is
@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import heat_tpu as ht
 from heat_tpu.core import autotune, telemetry
 from heat_tpu.core.linalg.qr import _cholesky_qr2, orthogonality_defect
-from heat_tpu.ops import _pallas_common, lasso_sweep, qr_panel, repack
+from heat_tpu.ops import _pallas_common, lasso_sweep, qr_panel
 from heat_tpu.regression import lasso as lasso_mod
 from heat_tpu.regression.lasso import Lasso, _cd_sweep
 
@@ -98,7 +98,7 @@ class TestPallasCommon(TestCase):
             self.assertEqual(_pallas_common.mode(), "off")
 
     def test_kernel_kill_switches(self):
-        for name in ("repack", "qr", "lasso"):
+        for name in ("qr", "lasso"):
             knob = f"HEAT_TPU_KERNEL_{name.upper()}"
             self.assertTrue(_pallas_common.kernel_enabled(name))
             os.environ[knob] = "off"
@@ -109,7 +109,7 @@ class TestPallasCommon(TestCase):
             finally:
                 del os.environ[knob]
         with _Interpret("interpret"):
-            self.assertEqual(_pallas_common.kernel_mode("repack"), "interpret")
+            self.assertEqual(_pallas_common.kernel_mode("qr"), "interpret")
 
     def test_sublane_and_pad(self):
         self.assertEqual(_pallas_common.sublane(jnp.dtype(jnp.float32)), 8)
@@ -121,140 +121,31 @@ class TestPallasCommon(TestCase):
         np.testing.assert_array_equal(np.asarray(p[:5, :10]), np.asarray(x))
         self.assertEqual(float(jnp.sum(jnp.abs(p))), 50.0)
 
-    def test_matmul_reexports_shared_plumbing(self):
-        # back-compat: matmul's historical private names now come from
-        # _pallas_common — one copy of the boilerplate
-        from heat_tpu.ops import matmul as mm
 
-        self.assertIs(mm._mode, _pallas_common.mode)
-        self.assertIs(mm._pad_to, _pallas_common.pad_to)
-        self.assertIs(mm.tpu_compiler_params, _pallas_common.tpu_compiler_params)
-
-
-class TestRepackKernel(TestCase):
-    """Tentpole kernel 1: lane-aware repack for narrow-minor outputs —
-    pure data movement, bit-exact by contract."""
-
-    def test_bit_exact_direct(self):
-        rng = np.random.default_rng(11)
-        with _Interpret():
-            for shape, dtype in [
-                ((1998, 10), np.float32),
-                ((500, 13), np.float32),
-                ((64, 64), np.int32),
-                ((40, 17, 7), np.float32),
-                ((4096, 1), np.float32),
-            ]:
-                total = int(np.prod(shape))
-                if np.issubdtype(dtype, np.floating):
-                    flat = rng.standard_normal(total).astype(dtype)
-                else:
-                    flat = rng.integers(-1000, 1000, total).astype(dtype)
-                out = repack.repack(jnp.asarray(flat), shape, interpret=True)
-                np.testing.assert_array_equal(
-                    np.asarray(out), flat.reshape(shape)
-                )
-
-    def test_supported_and_mode_decline(self):
-        f32 = jnp.dtype(jnp.float32)
-        self.assertTrue(repack.repack_supported((100, 10), f32))
-        # minor >= LANE: classic already writes full lanes — decline
-        self.assertFalse(repack.repack_supported((100, 128), f32))
-        # rank-1: no minor axis to repack
-        self.assertFalse(repack.repack_supported((100,), f32))
-        with _Interpret(None):
-            # CPU backend, nothing forced: off
-            self.assertEqual(repack.repack_mode((100, 10), f32), "off")
-        with _Interpret():
-            self.assertEqual(repack.repack_mode((100, 10), f32), "interpret")
-            self.assertEqual(repack.repack_mode((100, 128), f32), "off")
-
-    @unittest.skipUnless(_MULTI, "needs a multi-device mesh")
-    def test_reshape_kernel_arm_explore_then_sticky(self):
-        x = np.arange(999 * 20, dtype=np.float32).reshape(999, 20)
-        want = x.reshape(1998, 10)
-        with _Interpret(), _Tuned():
-            for _ in range(8):
-                a = ht.array(x, split=0)
-                out = ht.reshape(a, (1998, 10))
-                self.assert_array_equal(out, want)
-            rows = [r for r in _table_rows() if r[2] == ("classic", "kernel")]
-            self.assertTrue(rows, _table_rows())
-            _, winner, arms, samples = rows[0]
-            self.assertIn(winner, ("classic", "kernel"))
-            self.assertEqual(samples, {"classic": 3, "kernel": 3})
+class TestNarrowMinorReshape(TestCase):
+    """The split-crossing reshape to a narrow minor dim: one lowering,
+    no kernel arm (Mosaic has no lane->sublane shape cast)."""
 
     @unittest.skipUnless(_MULTI, "needs a multi-device mesh")
     def test_pad_lane_regression_source_pads(self):
         """ISSUE 12 satellite: a narrow-minor reshape whose SOURCE shard
-        carries pad rows (999 % mesh != 0) must match eager exactly on
-        both arms — including with a fused elementwise tail, where chain
-        garbage on source-axis pad rows would cross the all_to_all."""
+        carries pad rows (999 % mesh != 0) must match eager exactly —
+        including with a fused elementwise tail, where chain garbage on
+        source-axis pad rows would cross the all_to_all."""
         x = (np.arange(999 * 20, dtype=np.float32).reshape(999, 20)
              % 37) / 11.0
         want = np.exp(x).reshape(1998, 10)
-
-        def run():
-            a = ht.array(x, split=0)
-            return ht.reshape(ht.exp(a), (1998, 10))
-
-        # classic arm (autotune off -> today's dispatch)
-        with _Interpret("off"):
-            classic = run()
-            self.assert_array_equal(classic, want, rtol=1e-5, atol=1e-6)
-        # kernel arm: pin the winner, then dispatch through it — the
-        # repack is pure data movement, so both arms must agree with
-        # the classic result BIT-FOR-BIT even on the pad-row shard
-        with _Interpret(), _Tuned():
-            for _ in range(7):
-                out = run()
-            rows = [r for r in _table_rows() if r[2] == ("classic", "kernel")]
-            self.assertTrue(rows)
-            np.testing.assert_array_equal(out.numpy(), classic.numpy())
-
-    @unittest.skipUnless(_MULTI, "needs a multi-device mesh")
-    def test_autotune_off_restores_dispatch_bit_for_bit(self):
-        x = np.arange(1000 * 10, dtype=np.float32).reshape(1000, 10)
-
-        def run():
-            a = ht.array(x, split=0)
-            return ht.reshape(a, (500, 20), new_split=0)
-
-        with _Interpret("off"):
-            base = run().numpy()
-        # interpret forced but autotune off: the kernel arm is never
-        # consulted — identical bytes, zero decisions
-        with _Interpret():
-            telemetry.set_level("events")
-            telemetry.clear_events()
-            try:
-                got = run().numpy()
-                decisions = [
-                    e for e in telemetry.events()
-                    if e["kind"] == "autotune_decision"
-                ]
-            finally:
-                telemetry.clear_events()
-                telemetry.set_level("counters")
-            self.assertEqual(decisions, [])
-        np.testing.assert_array_equal(base, got)
-        self.assertEqual(len(autotune._TABLE), 0)
-
-    @unittest.skipUnless(_MULTI, "needs a multi-device mesh")
-    def test_kill_switch_no_arm_registered(self):
-        x = np.arange(999 * 20, dtype=np.float32).reshape(999, 20)
-        os.environ["HEAT_TPU_KERNEL_REPACK"] = "off"
-        try:
-            with _Interpret(), _Tuned():
-                a = ht.array(x, split=0)
-                out = ht.reshape(a, (1998, 10))
-                self.assert_array_equal(out, x.reshape(1998, 10))
-                self.assertEqual(
-                    [r for r in _table_rows() if r[2] == ("classic", "kernel")],
-                    [],
-                )
-        finally:
-            del os.environ["HEAT_TPU_KERNEL_REPACK"]
+        a = ht.array(x, split=0)
+        out = ht.reshape(ht.exp(a), (1998, 10))
+        self.assert_array_equal(out, want, rtol=1e-5, atol=1e-6)
+        # tuned dispatch registers no classic/kernel entry at this site
+        with _Tuned():
+            for _ in range(4):
+                again = ht.reshape(ht.exp(ht.array(x, split=0)), (1998, 10))
+            np.testing.assert_array_equal(again.numpy(), out.numpy())
+            self.assertEqual(
+                [r for r in _table_rows() if r[2] == ("classic", "kernel")], []
+            )
 
 
 class TestQRPanelKernel(TestCase):
@@ -514,3 +405,62 @@ class TestKernelArmPersistence(TestCase):
             self.assertEqual(rows[0]["winner"], "kernel")
             self.assertIn("classic_min_s", rows[0])
             self.assertIn("kernel_min_s", rows[0])
+
+
+class TestMosaicLowering(TestCase):
+    """The half of "will Mosaic take it?" that the CPU can answer: the
+    Pallas -> Mosaic lowering for platform ``tpu`` runs here and raises
+    on illegal block shapes, primitives with no lowering, and a kernel
+    GSPMD would have to partition.  (The Mosaic compiler proper runs only
+    on the chip: ``chip_smoke.py``.)  32-bit types, as on the chip."""
+
+    @staticmethod
+    def _lower(fn, *avals):
+        with jax.enable_x64(False):
+            jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",))
+
+    @staticmethod
+    def _aval(shape, dtype=jnp.float32, sharding=None):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def test_every_kernel_lowers_for_tpu(self):
+        from heat_tpu.ops import attention, cdist
+
+        a = self._aval
+        self._lower(
+            lambda X, y, t: lasso_sweep.sweep(X, y, t, 0.1),
+            a((2048, 130)), a((2048,)), a((130,)),
+        )
+        self._lower(qr_panel.fused_gram_chol, a((4096, 100)))
+        self._lower(cdist._cdist_pallas, a((512, 64)), a((256, 64)))
+        for causal in (True, False):
+            self._lower(
+                lambda q, c=causal: attention._flash_pallas(q, q, q, c, 0.1),
+                a((2, 1024, 128), jnp.bfloat16),
+            )
+
+    @unittest.skipUnless(_MULTI, "needs a multi-device mesh")
+    def test_replicated_operands_on_a_mesh_run_per_device(self):
+        """GSPMD cannot partition a Mosaic custom call, even over
+        replicated operands: the call sites go through shard_map."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from heat_tpu.core.linalg.qr import _fact_on_each_device
+        from heat_tpu.parallel.collectives import jit_shard_map_cached
+
+        mesh = ht.parallel.get_comm().mesh
+        rep = NamedSharding(mesh, P())
+        a = lambda shape: self._aval(shape, sharding=rep)  # noqa: E731
+        with self.assertRaisesRegex(NotImplementedError, "automatically partitioned"):
+            self._lower(qr_panel.fused_gram_chol, a((4096, 100)))
+        self._lower(
+            jit_shard_map_cached(lasso_mod._cd_fit_on_each_device, mesh, "tpu"),
+            a((2048, 130)), a((2048,)), a((130,)), 0.1, 5, 1e-6,
+        )
+        for tall, shape in ((True, (4096, 100)), (False, (256, 256))):
+            self._lower(
+                jit_shard_map_cached(
+                    _fact_on_each_device, mesh, tall, True, False, "tpu"
+                ),
+                a(shape),
+            )

@@ -25,20 +25,6 @@ class _InterpretMode:
             os.environ["HEAT_TPU_PALLAS"] = self._old
 
 
-class TestPallasMatmul(TestCase):
-    def test_matches_numpy_odd_shapes(self):
-        import jax.numpy as jnp
-        from heat_tpu.ops import pallas_matmul
-
-        rng = np.random.default_rng(0)
-        for m, k, n in [(37, 53, 41), (128, 128, 128), (1, 7, 300)]:
-            a = rng.standard_normal((m, k)).astype(np.float32)
-            b = rng.standard_normal((k, n)).astype(np.float32)
-            with _InterpretMode():
-                out = np.asarray(pallas_matmul(jnp.array(a), jnp.array(b)))
-            np.testing.assert_allclose(out, a @ b, atol=1e-4, rtol=1e-4)
-
-
 class TestFusedCdist(TestCase):
     def test_matches_dense_reference(self):
         import jax.numpy as jnp

@@ -1,18 +1,16 @@
-"""Sparse compute tier (ISSUE 19): lane-aware Pallas SpMV/SpMM behind
-the autotune plane, sparse Lanczos end-to-end, k-NN-graph serving.
+"""Sparse compute tier (ISSUE 19): SpMV/SpMM behind the autotune
+plane, sparse Lanczos end-to-end, k-NN-graph serving.
 
 Laws under test, at every mesh size (``scripts/ci.sh`` stage 22 re-runs
 this file at ``HEAT_TEST_DEVICES=1/4/8``):
 
-- **bit-parity**: on exactly-representable data the ``gather`` and
-  ``kernel`` (interpret) arms reproduce the ``todense()`` reference
-  matmul bit-for-bit — including a ragged last shard and a shard of
-  all-zero rows;
+- **bit-parity**: on exactly-representable data the ``gather`` arm
+  reproduces the ``todense()`` reference matmul bit-for-bit — including
+  a ragged last shard and a shard of all-zero rows;
 - **explore returns dense**: the first tuned call runs every arm but
   always answers with the dense reference result, bitwise;
 - **static dispatch**: ``HEAT_TPU_AUTOTUNE=off`` restores today's
-  env-knob dispatch bit-for-bit with ZERO tuning-table decisions, and
-  ``HEAT_TPU_KERNEL_SPMV=off`` removes the kernel arm entirely;
+  env-knob dispatch bit-for-bit with ZERO tuning-table decisions;
 - **warm start**: spmv arm entries survive a ``save``/``load``
   round-trip and are consumed by the Lanczos chain consult;
 - **sparse Lanczos**: the recurrence over the tuned SpMV program agrees
@@ -38,7 +36,6 @@ from heat_tpu.core import autotune, telemetry, types
 from heat_tpu.core.dndarray import DNDarray
 from heat_tpu.core.linalg import solver
 from heat_tpu.graph import laplacian_sparse
-from heat_tpu.ops import spmv as spmv_mod
 from heat_tpu.sparse import knn_graph
 # NOTE: `import heat_tpu.sparse.matmul as spmm` would bind the matmul
 # FUNCTION (the package re-export shadows the module attribute); the
@@ -95,10 +92,6 @@ class _Env:
         return False
 
 
-def _interpret():
-    return _Env("HEAT_TPU_PALLAS", "interpret")
-
-
 def _spmv_rows():
     """Tuning-table rows carrying the spmv arm sets."""
     return [
@@ -132,55 +125,8 @@ def _int_vec(m, k=None, seed=1):
     return rng.integers(-4, 5, size=shape).astype(np.float32)
 
 
-class TestEllPack(TestCase):
-    """The host-side ELL repack feeding the kernel arm."""
-
-    def test_width_is_lane_aligned(self):
-        self.assertEqual(spmv_mod.ell_width(0), 128)
-        self.assertEqual(spmv_mod.ell_width(1), 128)
-        self.assertEqual(spmv_mod.ell_width(128), 128)
-        self.assertEqual(spmv_mod.ell_width(129), 256)
-
-    def test_pack_layout(self):
-        sp = _int_csr(13, 20, density=0.3, seed=2, zero_rows=(4,))
-        vals, cols = spmv_mod.ell_pack(
-            sp.data, sp.indices, sp.indptr, spmv_mod.ell_width(int(np.diff(sp.indptr).max()))
-        )
-        self.assertEqual(vals.shape, cols.shape)
-        self.assertEqual(vals.shape[0] % 8, 0)  # sublane-padded rows
-        self.assertEqual(vals.shape[1] % 128, 0)  # lane-aligned width
-        # pad slots: zero value, -1 column (the lane mask)
-        live = cols >= 0
-        self.assertEqual(int(live.sum()), sp.nnz)
-        self.assertTrue(np.all(vals[~live] == 0.0))
-        # row 4 (all-zero) packs as an empty lane row
-        self.assertTrue(np.all(cols[4] == -1))
-        # gather-back reproduces the dense matrix
-        dense = np.zeros((vals.shape[0], 20), np.float32)
-        r, s = np.nonzero(live)
-        dense[r, cols[r, s]] = vals[r, s]
-        np.testing.assert_array_equal(dense[:13], sp.toarray())
-
-    def test_supported_declines(self):
-        f32, f64 = jnp.dtype(jnp.float32), jnp.dtype(jnp.float64)
-        self.assertTrue(spmv_mod.spmv_supported(512, 512, 128, f32))
-        self.assertFalse(spmv_mod.spmv_supported(512, 512, 128, f64))
-        # a VMEM-overflowing row block declines safely
-        self.assertFalse(spmv_mod.spmv_supported(4096, 100_000, 4096, f32))
-
-    def test_kernel_interpret_matches_scipy(self):
-        sp = _int_csr(40, 64, density=0.15, seed=3)
-        w = spmv_mod.ell_width(int(np.diff(sp.indptr).max()))
-        vals, cols = spmv_mod.ell_pack(sp.data, sp.indices, sp.indptr, w)
-        x = _int_vec(64, seed=4)
-        y = spmv_mod.spmv_ell(
-            jnp.asarray(vals), jnp.asarray(cols), jnp.asarray(x), interpret=True
-        )
-        np.testing.assert_array_equal(np.asarray(y)[:40], sp @ x)
-
-
 class TestArmBitParity(TestCase):
-    """gather and kernel(interpret) vs the todense() reference — bitwise
+    """gather vs the todense() reference — bitwise
     on exact data, including ragged last shard + all-zero-rows shard."""
 
     # 37 rows: ragged last shard on any mesh size; the trailing rows
@@ -212,14 +158,6 @@ class TestArmBitParity(TestCase):
     def test_gather_bitwise_replicated(self):
         self._check("gather", None)
 
-    def test_kernel_interpret_bitwise_split0(self):
-        with _interpret():
-            self._check("kernel", 0)
-
-    def test_kernel_interpret_bitwise_replicated(self):
-        with _interpret():
-            self._check("kernel", None)
-
     def test_matmul_validates(self):
         A = ht.sparse.sparse_csr_matrix(_int_csr(8, 8, seed=8), split=0)
         with self.assertRaisesRegex(ValueError, "dimension mismatch"):
@@ -243,7 +181,7 @@ class TestArmBitParity(TestCase):
 
 class TestStaticDispatch(TestCase):
     """HEAT_TPU_AUTOTUNE=off is today's dispatch bit-for-bit: zero table
-    decisions, zero table entries; the env knob and kill switch rule."""
+    decisions, zero table entries; the env knob rules."""
 
     def test_off_is_bitwise_with_zero_decisions(self):
         sp = _int_csr(37, 40, seed=13, zero_rows=(36,))
@@ -264,34 +202,8 @@ class TestStaticDispatch(TestCase):
             with self.assertRaisesRegex(ValueError, "HEAT_TPU_SPMV"):
                 ht.sparse.matmul(A, np.ones(8, np.float32))
 
-    def test_kernel_knob_falls_back_when_unsupported(self):
-        # kernel requested but Pallas is off on CPU: gather serves
-        sp = _int_csr(10, 10, seed=16)
-        A = ht.sparse.sparse_csr_matrix(sp, split=0)
-        x = _int_vec(10, seed=17)
-        with _Env("HEAT_TPU_PALLAS", None), _Env("HEAT_TPU_SPMV", "kernel"):
-            y = ht.sparse.matmul(A, x)
-        np.testing.assert_array_equal(y.numpy(), sp @ x)
-
-    def test_kill_switch_removes_kernel_arm(self):
-        with _interpret():
-            self.assertNotEqual(spmv_mod.spmv_mode(64, 64, 4, jnp.float32), "off")
-            with _Env("HEAT_TPU_KERNEL_SPMV", "off"):
-                self.assertEqual(spmv_mod.spmv_mode(64, 64, 4, jnp.float32), "off")
-                sp = _int_csr(24, 24, seed=18)
-                A = ht.sparse.sparse_csr_matrix(sp, split=0)
-                x = _int_vec(24, seed=19)
-                with _Tuned():
-                    for _ in range(7):
-                        ht.sparse.matmul(A, x)
-                    rows = _spmv_rows()
-                    self.assertTrue(rows)
-                    # the kernel arm never registered: two-arm entry only
-                    self.assertEqual(rows[0][2], ("dense", "gather"))
-
-
 class TestSpmvArms(TestCase):
-    """The tuned three-arm consult: explore-then-sticky, the round-15
+    """The tuned two-arm consult: explore-then-sticky, the round-15
     explore contract, and the save/load warm start."""
 
     def _problem(self, seed=20):
@@ -303,34 +215,32 @@ class TestSpmvArms(TestCase):
         A, sp, x = self._problem()
         with _Env("HEAT_TPU_SPMV", "dense"):
             ref = ht.sparse.matmul(A, x).numpy()  # autotune off: pure dense
-        with _interpret(), _Tuned():
+        with _Tuned():
             got = ht.sparse.matmul(A, x).numpy()  # first call: explore round
         np.testing.assert_array_equal(got, ref)
 
-    def test_explore_then_sticky_three_arms(self):
+    def test_explore_then_sticky_two_arms(self):
         A, sp, x = self._problem(seed=22)
-        with _interpret(), _Tuned():
+        with _Tuned():
             for _ in range(7):
                 y = ht.sparse.matmul(A, x)
             rows = _spmv_rows()
             self.assertTrue(rows)
-            self.assertEqual(rows[0][2], ("dense", "gather", "kernel"))
-            self.assertEqual(rows[0][3], {"dense": 3, "gather": 3, "kernel": 3})
-            self.assertIn(rows[0][1], ("dense", "gather", "kernel"))
+            self.assertEqual(rows[0][2], ("dense", "gather"))
+            self.assertEqual(rows[0][3], {"dense": 3, "gather": 3})
+            self.assertIn(rows[0][1], ("dense", "gather"))
             np.testing.assert_array_equal(y.numpy(), sp @ x)
             # each arm owns a cost-ledger row
             kinds = {p["kind"] for p in telemetry.programs()}
-            self.assertLessEqual(
-                {"spmv_dense", "spmv_gather", "spmv_kernel"}, kinds
-            )
+            self.assertLessEqual({"spmv_dense", "spmv_gather"}, kinds)
 
     def test_save_load_roundtrip_of_spmv_entries(self):
         A, sp, x = self._problem(seed=24)
-        with _interpret(), _Tuned():
+        with _Tuned():
             for _ in range(7):
                 ht.sparse.matmul(A, x)
             table = {k: e for k, e in autotune.table().items()
-                     if set(e["arms"]) == {"dense", "gather", "kernel"}}
+                     if set(e["arms"]) == {"dense", "gather"}}
             self.assertTrue(table)
             (key, entry), = table.items()
             self.assertIsNotNone(entry["winner"])
@@ -403,7 +313,7 @@ class TestSparseLanczos(TestCase):
         sym = sp.maximum(sp.T).tocsr()
         A = ht.sparse.sparse_csr_matrix(sym, split=0)
         x = _int_vec(32, seed=29)
-        with _interpret(), _Tuned():
+        with _Tuned():
             for _ in range(7):
                 ht.sparse.matmul(A, x)  # resolve the (k=1) winner
             rows = _spmv_rows()
@@ -412,8 +322,8 @@ class TestSparseLanczos(TestCase):
             fn, operands = matvec_program(A)
             y = fn(operands, jnp.asarray(x))
             np.testing.assert_array_equal(np.asarray(y), sym @ x)
-            # a resolved gather/kernel winner is a served chain decision
-            if rows[0][1] in ("gather", "kernel"):
+            # a resolved gather winner is a served chain decision
+            if rows[0][1] == "gather":
                 self.assertGreater(autotune.stats()["cache_hits"], hits)
 
 
