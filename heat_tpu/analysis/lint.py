@@ -10,9 +10,11 @@ this repo has already paid for once:
   (the r14 ``RING_MIN_BYTES`` fix).
 * **HT002** — host syncs (``.item()``, ``block_until_ready``,
   ``float()/int()/bool()`` of a device value) outside
-  ``telemetry.timed_call``-wrapped sites.  An unmeasured sync in an
-  engine hot path stalls the dispatch pipeline AND mis-attributes its
-  wall to whatever the roofline timed next.
+  ``telemetry.timed_call``-wrapped sites and outside a
+  ``with telemetry.sync("<site>"):`` block (which counts the sync and
+  names it in the flight recorder and the profiler trace).  An
+  unmeasured sync in an engine hot path stalls the dispatch pipeline AND
+  mis-attributes its wall to whatever the roofline timed next.
 * **HT003** — data-dependent Python ``if``/``while`` on sharded values
   gating a collective call.  Under SPMD every rank must reach every
   collective in the same order; a rank-divergent branch around one is a
@@ -241,11 +243,19 @@ def _functions(tree: ast.Module):
 
 
 def _inside_timed_call(ancestors: Sequence[ast.AST]) -> bool:
+    """Whether a node sits at a measured site: an argument of
+    ``timed_call``/``autotune.timed``, or the body of a
+    ``with telemetry.sync(...)`` block."""
     for anc in ancestors:
         if isinstance(anc, ast.Call):
             name = _dotted(anc.func)
             if name.endswith("timed_call") or name.endswith(".timed"):
                 return True
+        elif isinstance(anc, ast.With):
+            for item in anc.items:
+                ctx = item.context_expr
+                if isinstance(ctx, ast.Call) and _dotted(ctx.func).split(".")[-1] == "sync":
+                    return True
     return False
 
 
@@ -285,7 +295,7 @@ def _rule_ht001(tree: ast.Module, ctx: _Ctx) -> List[Finding]:
 
 
 def _rule_ht002(tree: ast.Module, ctx: _Ctx) -> List[Finding]:
-    """Host syncs outside telemetry.timed_call-wrapped sites."""
+    """Host syncs outside telemetry.timed_call / telemetry.sync sites."""
     out = []
     taint_by_fn = {}
     for fn in _functions(tree):
@@ -327,8 +337,9 @@ def _rule_ht002(tree: ast.Module, ctx: _Ctx) -> List[Finding]:
             continue
         f = ctx.finding(
             "HT002", node,
-            f"{hit} outside a telemetry.timed_call-wrapped site — wrap it "
-            "or justify with '# ht: HT002 ok — <reason>'",
+            f"{hit} outside a telemetry.timed_call-wrapped site — put it "
+            "under 'with telemetry.sync(\"<site>\"):' or justify with "
+            "'# ht: HT002 ok — <reason>'",
         )
         if f:
             out.append(f)

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from ..core import random as ht_random
 from ..core.base import BaseEstimator, ClusteringMixin
 from ..core.dndarray import DNDarray, _ensure_split
-from ..core import types
+from ..core import telemetry, types
 from ..ops.cdist import cdist as ops_cdist
 
 __all__ = ["_KCluster"]
@@ -231,7 +231,8 @@ class _KCluster(ClusteringMixin, BaseEstimator):
         if return_inertia:
             inertia = statistics.min(distances, axis=1).sum()
             fusion.materialize(labels, inertia)
-            inertia_val = float(jnp.asarray(inertia.larray).reshape(()))  # ht: HT002 ok — end-of-fit inertia readback, one scalar per fit
+            with telemetry.sync("kcluster.inertia"):  # one scalar per fit
+                inertia_val = float(jnp.asarray(inertia.larray).reshape(()))
         if labels.split != x.split:
             out = DNDarray(
                 labels.larray, labels.gshape, labels.dtype, x.split, x.device, x.comm
@@ -264,7 +265,8 @@ class _KCluster(ClusteringMixin, BaseEstimator):
             arr, centers, self.n_clusters, self.max_iter, self.tol,
             snap_to_sample=snap_to_sample,
         )
-        self._n_iter = int(n_iter)  # ht: HT002 ok — end-of-fit n_iter readback, one scalar per fit
+        with telemetry.sync("kcluster.n_iter"):  # one scalar per fit
+            self._n_iter = int(n_iter)
         self._cluster_centers = DNDarray(
             centers, tuple(centers.shape),
             types.canonical_heat_type(centers.dtype), None, x.device, x.comm,

@@ -51,10 +51,16 @@ def _lloyd_while(step, centers, max_iter, tol):
     # come out of f32 distance accumulation, and a bf16 carry would both
     # mismatch the while_loop types and quantize the tol comparison
     init = (centers, jnp.array(jnp.inf, jnp.float32), jnp.array(0.0, jnp.float32), 0)
-    return jax.lax.while_loop(cond, body, init)
+    with jax.named_scope("ht.kmeans.lloyd"):
+        return jax.lax.while_loop(cond, body, init)
+
+
+# jitted functions whose device scopes (``jax.named_scope``) a reader of
+# traces relies on carry a module name of their own: telemetry.module_name
 
 
 @partial(jax.jit, static_argnames=("k",))
+@telemetry.module_name("ht_lloyd_loop")
 def _lloyd_loop(x, centers, k: int, max_iter, tol):
     """Lloyd iterations over unpacked data (see :func:`_lloyd_while`)."""
     return _lloyd_while(
@@ -63,29 +69,33 @@ def _lloyd_loop(x, centers, k: int, max_iter, tol):
 
 
 @partial(jax.jit, static_argnames=("k",))
+@telemetry.module_name("ht_lloyd_step")
 def _lloyd_step(x, centers, k: int):
     """One fused Lloyd iteration: returns (new_centers, shift², inertia).
 
     With ``x`` row-sharded and ``centers`` replicated, XLA compiles this to
     local MXU matmuls plus a single psum of the (k, f) sums and (k,) counts.
     """
-    d2 = ops_cdist(x, centers, sqrt=False)
-    labels = jnp.argmin(d2, axis=1)
-    onehot = (labels[:, None] == jnp.arange(k)[None, :]).astype(x.dtype)
-    # counts/sums accumulate in f32 whatever the data dtype: a bf16
-    # accumulator drops counts by ~0.2% at 4e5 members and skews centroids
-    # (the 0/1 products are exact, only the accumulator needs width)
-    counts = jnp.sum(onehot, axis=0, dtype=jnp.float32)
-    sums = jax.lax.dot_general(
-        onehot, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    new_centers = jnp.where(
-        counts[:, None] > 0, sums / jnp.maximum(counts, 1)[:, None], centers.astype(jnp.float32)
-    ).astype(centers.dtype)
-    shift = jnp.sum((new_centers - centers).astype(jnp.float32) ** 2)
-    # distance to the assigned (= nearest) centroid is the row minimum; a
-    # take_along_axis gather here costs ~20x the rest of the step on TPU
-    inertia = jnp.sum(jnp.min(d2, axis=1))
+    with jax.named_scope("ht.kmeans.assign"):
+        d2 = ops_cdist(x, centers, sqrt=False)
+        labels = jnp.argmin(d2, axis=1)
+    with jax.named_scope("ht.kmeans.update"):
+        onehot = (labels[:, None] == jnp.arange(k)[None, :]).astype(x.dtype)
+        # counts/sums accumulate in f32 whatever the data dtype: a bf16
+        # accumulator drops counts by ~0.2% at 4e5 members and skews centroids
+        # (the 0/1 products are exact, only the accumulator needs width)
+        counts = jnp.sum(onehot, axis=0, dtype=jnp.float32)
+        sums = jax.lax.dot_general(
+            onehot, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        new_centers = jnp.where(
+            counts[:, None] > 0, sums / jnp.maximum(counts, 1)[:, None], centers.astype(jnp.float32)
+        ).astype(centers.dtype)
+        shift = jnp.sum((new_centers - centers).astype(jnp.float32) ** 2)
+    with jax.named_scope("ht.kmeans.assign"):
+        # distance to the assigned (= nearest) centroid is the row minimum; a
+        # take_along_axis gather here costs ~20x the rest of the step on TPU
+        inertia = jnp.sum(jnp.min(d2, axis=1))
     return new_centers, shift, inertia
 
 
@@ -115,6 +125,7 @@ def _stream_lloyd_stats(x, valid, centers, k: int):
 
 
 @partial(jax.jit, static_argnames=("k", "p", "with_inertia"))
+@telemetry.module_name("ht_lloyd_loop_packed")
 def _lloyd_loop_packed(x2, sq, valid, centers, k: int, p: int, max_iter, tol,
                        with_inertia: bool = True):
     """Lloyd loop over lane-packed data.
@@ -134,45 +145,49 @@ def _lloyd_loop_packed(x2, sq, valid, centers, k: int, p: int, max_iter, tol,
     f = x2.shape[1] // p
 
     def step(centers):
-        cT = centers.astype(x2.dtype).T  # (f, k)
-        w = jnp.zeros((p * f, p * k), x2.dtype)
-        for s in range(p):
-            w = jax.lax.dynamic_update_slice(w, cT, (s * f, s * k))
-        # (n/p, p*k): slot s's distances live in columns [s*k, (s+1)*k)
-        cross = jax.lax.dot_general(
-            x2, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        cn2 = jnp.sum(centers.astype(jnp.float32) ** 2, axis=1)
-        # all slots at once: (n/p, p, k) distances, slot-major one-hots.
-        # |x|^2 shifts every cluster equally, so the argmin only needs
-        # m2 = |c|^2 - 2<x,c>; the full d2 (clamped at 0 like ops_cdist —
-        # f32 rounding near centroids can dip negative) is built only
-        # when the caller wants the per-iteration inertia
-        m2 = cn2[None, None, :] - 2.0 * cross.reshape(-1, p, k)
-        labels = jnp.argmin(m2, axis=2)  # (n/p, p)
-        vf = valid[..., None].astype(x2.dtype)
-        oh = (labels[..., None] == jnp.arange(k)[None, None, :]).astype(x2.dtype) * vf
-        counts = jnp.sum(oh, axis=(0, 1), dtype=jnp.float32)
-        if with_inertia:
-            d2min = jnp.maximum(sq + jnp.min(m2, axis=2), 0.0)
-            inertia = jnp.sum(d2min * valid)
-        else:
-            inertia = jnp.array(0.0, jnp.float32)
-        # ONE masked-sum matmul for every slot: a per-slot dot would read
-        # x2 p times and hand the traffic win straight back
-        all_sums = jax.lax.dot_general(
-            oh.reshape(-1, p * k), x2, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (p*k, p*f); slot s's contribution is its diagonal block
-        sums = jnp.zeros((k, f), jnp.float32)
-        for s in range(p):
-            sums = sums + jax.lax.dynamic_slice(all_sums, (s * k, s * f), (k, f))
-        new_centers = jnp.where(
-            counts[:, None] > 0,
-            sums / jnp.maximum(counts, 1)[:, None],
-            centers.astype(jnp.float32),
-        ).astype(centers.dtype)
-        shift = jnp.sum((new_centers - centers).astype(jnp.float32) ** 2)
+        with jax.named_scope("ht.kmeans.assign"):
+            cT = centers.astype(x2.dtype).T  # (f, k)
+            w = jnp.zeros((p * f, p * k), x2.dtype)
+            for s in range(p):
+                w = jax.lax.dynamic_update_slice(w, cT, (s * f, s * k))
+            # (n/p, p*k): slot s's distances live in columns [s*k, (s+1)*k)
+            cross = jax.lax.dot_general(
+                x2, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            cn2 = jnp.sum(centers.astype(jnp.float32) ** 2, axis=1)
+            # all slots at once: (n/p, p, k) distances, slot-major one-hots.
+            # |x|^2 shifts every cluster equally, so the argmin only needs
+            # m2 = |c|^2 - 2<x,c>; the full d2 (clamped at 0 like ops_cdist —
+            # f32 rounding near centroids can dip negative) is built only
+            # when the caller wants the per-iteration inertia
+            m2 = cn2[None, None, :] - 2.0 * cross.reshape(-1, p, k)
+            labels = jnp.argmin(m2, axis=2)  # (n/p, p)
+        with jax.named_scope("ht.kmeans.update"):
+            vf = valid[..., None].astype(x2.dtype)
+            oh = (labels[..., None] == jnp.arange(k)[None, None, :]).astype(x2.dtype) * vf
+            counts = jnp.sum(oh, axis=(0, 1), dtype=jnp.float32)
+        with jax.named_scope("ht.kmeans.assign"):
+            if with_inertia:
+                d2min = jnp.maximum(sq + jnp.min(m2, axis=2), 0.0)
+                inertia = jnp.sum(d2min * valid)
+            else:
+                inertia = jnp.array(0.0, jnp.float32)
+        with jax.named_scope("ht.kmeans.update"):
+            # ONE masked-sum matmul for every slot: a per-slot dot would read
+            # x2 p times and hand the traffic win straight back
+            all_sums = jax.lax.dot_general(
+                oh.reshape(-1, p * k), x2, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (p*k, p*f); slot s's contribution is its diagonal block
+            sums = jnp.zeros((k, f), jnp.float32)
+            for s in range(p):
+                sums = sums + jax.lax.dynamic_slice(all_sums, (s * k, s * f), (k, f))
+            new_centers = jnp.where(
+                counts[:, None] > 0,
+                sums / jnp.maximum(counts, 1)[:, None],
+                centers.astype(jnp.float32),
+            ).astype(centers.dtype)
+            shift = jnp.sum((new_centers - centers).astype(jnp.float32) ** 2)
         return new_centers, shift, inertia
 
     return _lloyd_while(step, centers, max_iter, tol)
@@ -200,60 +215,63 @@ def _lloyd_loop_packed_blocked_impl(x2, centers, k: int, p: int, n: int, blk: in
     nb = -(-rows // blk)
 
     def step(centers):
-        cT = centers.astype(x2.dtype).T
-        w = jnp.zeros((p * f, p * k), x2.dtype)
-        for s in range(p):
-            w = jax.lax.dynamic_update_slice(w, cT, (s * f, s * k))
-        cn2 = jnp.sum(centers.astype(jnp.float32) ** 2, axis=1)
+        with jax.named_scope("ht.kmeans.assign"):
+            cT = centers.astype(x2.dtype).T
+            w = jnp.zeros((p * f, p * k), x2.dtype)
+            for s in range(p):
+                w = jax.lax.dynamic_update_slice(w, cT, (s * f, s * k))
+            cn2 = jnp.sum(centers.astype(jnp.float32) ** 2, axis=1)
 
         def body(i, carry):
             sums, counts = carry
-            # dynamic_slice clamps the start: the last block re-reads
-            # earlier rows, so mask rows below this block's true start
-            start = jnp.minimum(i * blk, rows - blk)
-            xb = jax.lax.dynamic_slice_in_dim(x2, start, blk, 0)
-            # NO optimization barrier here: with the slimmed body the
-            # layout solver keeps the payload's natural orientation and
-            # fuses the slice into its consumers (compile-reported temps
-            # 0.02 GB); the earlier fuller body needed a barrier to stop
-            # a transpose-hoist of the whole payload — re-probe if ops
-            # are added back
-            gsl = (start * p) + jnp.arange(blk * p)
-            vb = ((gsl < n) & (gsl >= i * blk * p)).astype(jnp.float32)
-            vb = vb.reshape(blk, p)
-            # m2[j] = |c_j|^2 - 2<x, c_j> has the same argmin as d^2: the
-            # per-sample |x|^2 shifts every cluster equally, so neither
-            # the labels nor the convergence check need it — the profiled
-            # per-iteration |x|^2 pass (convert+square+reduce, ~59 ms of
-            # a 169 ms iteration at n=1e8) is gone; fit computes the
-            # final inertia once in the labels pass
-            cross = jax.lax.dot_general(
-                xb, w, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ).reshape(blk, p, k)
-            m2 = cn2[None, None, :] - 2.0 * cross
-            labels = jnp.argmin(m2, axis=2)
-            oh = (labels[..., None] == jnp.arange(k)[None, None, :]).astype(
-                x2.dtype
-            ) * vb[..., None].astype(x2.dtype)
-            counts = counts + jnp.sum(
-                oh.astype(jnp.float32), axis=(0, 1), dtype=jnp.float32
-            )
-            # transpose the BLOCK explicitly: contracting the row dim of
-            # the slice directly makes layout assignment want the whole
-            # x2 payload transposed — a wish that penetrates optimization
-            # barriers and lands as an 11.9 GB relayout copy (verified
-            # both ways); a per-block transposed temp satisfies the GEMM
-            # locally
-            xbT = jnp.swapaxes(xb, 0, 1)
-            all_sums = jax.lax.dot_general(
-                oh.reshape(blk, p * k), xbT, (((0,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            for s in range(p):
-                sums = sums + jax.lax.dynamic_slice(
-                    all_sums, (s * k, s * f), (k, f)
+            with jax.named_scope("ht.kmeans.assign"):
+                # dynamic_slice clamps the start: the last block re-reads
+                # earlier rows, so mask rows below this block's true start
+                start = jnp.minimum(i * blk, rows - blk)
+                xb = jax.lax.dynamic_slice_in_dim(x2, start, blk, 0)
+                # NO optimization barrier here: with the slimmed body the
+                # layout solver keeps the payload's natural orientation and
+                # fuses the slice into its consumers (compile-reported temps
+                # 0.02 GB); the earlier fuller body needed a barrier to stop
+                # a transpose-hoist of the whole payload — re-probe if ops
+                # are added back
+                gsl = (start * p) + jnp.arange(blk * p)
+                vb = ((gsl < n) & (gsl >= i * blk * p)).astype(jnp.float32)
+                vb = vb.reshape(blk, p)
+                # m2[j] = |c_j|^2 - 2<x, c_j> has the same argmin as d^2: the
+                # per-sample |x|^2 shifts every cluster equally, so neither
+                # the labels nor the convergence check need it — the profiled
+                # per-iteration |x|^2 pass (convert+square+reduce, ~59 ms of
+                # a 169 ms iteration at n=1e8) is gone; fit computes the
+                # final inertia once in the labels pass
+                cross = jax.lax.dot_general(
+                    xb, w, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ).reshape(blk, p, k)
+                m2 = cn2[None, None, :] - 2.0 * cross
+                labels = jnp.argmin(m2, axis=2)
+            with jax.named_scope("ht.kmeans.update"):
+                oh = (labels[..., None] == jnp.arange(k)[None, None, :]).astype(
+                    x2.dtype
+                ) * vb[..., None].astype(x2.dtype)
+                counts = counts + jnp.sum(
+                    oh.astype(jnp.float32), axis=(0, 1), dtype=jnp.float32
                 )
+                # transpose the BLOCK explicitly: contracting the row dim of
+                # the slice directly makes layout assignment want the whole
+                # x2 payload transposed — a wish that penetrates optimization
+                # barriers and lands as an 11.9 GB relayout copy (verified
+                # both ways); a per-block transposed temp satisfies the GEMM
+                # locally
+                xbT = jnp.swapaxes(xb, 0, 1)
+                all_sums = jax.lax.dot_general(
+                    oh.reshape(blk, p * k), xbT, (((0,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                for s in range(p):
+                    sums = sums + jax.lax.dynamic_slice(
+                        all_sums, (s * k, s * f), (k, f)
+                    )
             return sums, counts
 
         sums, counts = jax.lax.fori_loop(
@@ -268,12 +286,13 @@ def _lloyd_loop_packed_blocked_impl(x2, centers, k: int, p: int, n: int, blk: in
         # the loop reports inertia 0: its true value is only needed once,
         # after convergence — _fit_packed computes it in the labels pass
         inertia = jnp.array(0.0, jnp.float32)
-        new_centers = jnp.where(
-            counts[:, None] > 0,
-            sums / jnp.maximum(counts, 1)[:, None],
-            centers.astype(jnp.float32),
-        ).astype(centers.dtype)
-        shift = jnp.sum((new_centers - centers).astype(jnp.float32) ** 2)
+        with jax.named_scope("ht.kmeans.update"):
+            new_centers = jnp.where(
+                counts[:, None] > 0,
+                sums / jnp.maximum(counts, 1)[:, None],
+                centers.astype(jnp.float32),
+            ).astype(centers.dtype)
+            shift = jnp.sum((new_centers - centers).astype(jnp.float32) ** 2)
         return new_centers, shift, inertia
 
     return _lloyd_while(step, centers, max_iter, tol)
@@ -295,13 +314,13 @@ def _blocked_loop_compiled(rows, pf, dtype_str, k, p, n, blk, x2_format):
     mi_s = jax.ShapeDtypeStruct((), jnp.int32)
     tol_s = jax.ShapeDtypeStruct((), jnp.float32)
 
-    def fn(x2, centers, max_iter, tol):
+    def ht_lloyd_loop_blocked(x2, centers, max_iter, tol):
         return _lloyd_loop_packed_blocked_impl(
             x2, centers, k, p, n, blk, max_iter, tol
         )
 
     jitted = jax.jit(
-        fn,
+        ht_lloyd_loop_blocked,
         in_shardings=(
             x2_format,
             _AUTO_FMT,
@@ -465,7 +484,8 @@ class KMeans(_KCluster):
         sanitation.sanitize_in(x)
         if x.ndim != 2:
             raise ValueError(f"input needs to be 2-D, but was {x.ndim}-D")
-        self._initialize_cluster_centers(x)
+        with telemetry.span("kmeans.init"):
+            self._initialize_cluster_centers(x)
 
         arr = x.larray
         if not jnp.issubdtype(arr.dtype, jnp.floating):
@@ -483,14 +503,17 @@ class KMeans(_KCluster):
             centers, _, inertia, n_iter = _lloyd_loop(
                 arr, centers, self.n_clusters, self.max_iter, self.tol
             )
-        self._n_iter = int(n_iter)  # ht: HT002 ok — end-of-fit n_iter readback, one scalar per fit
+        with telemetry.sync("kmeans.n_iter"):  # one scalar per fit
+            self._n_iter = int(n_iter)
 
         self._cluster_centers = DNDarray(
             centers, tuple(centers.shape), types.canonical_heat_type(centers.dtype),
             None, x.device, x.comm,
         )
-        self._labels = self._assign_to_cluster(x)
-        self._inertia = float(inertia)  # ht: HT002 ok — end-of-fit inertia readback, one scalar per fit
+        with telemetry.span("kmeans.labels"):
+            self._labels = self._assign_to_cluster(x)
+        with telemetry.sync("kmeans.inertia"):  # one scalar per fit
+            self._inertia = float(inertia)
         return self
 
     # ------------------------------------------------------ packed-ingest path
@@ -536,7 +559,8 @@ class KMeans(_KCluster):
         # the PHYSICAL payload: even row chunks over the mesh (trailing
         # pad rows' slots are >= n, so the validity masks drop them)
         x2 = packed.x2.parray
-        centers = self._init_centers_packed(packed).astype(x2.dtype)
+        with telemetry.span("kmeans.init"):
+            centers = self._init_centers_packed(packed).astype(x2.dtype)
         if _use_blocked(x2):
             blk = min(x2.shape[0], _BLOCK_ROWS)
             centers, _, inertia, n_iter = _lloyd_loop_packed_blocked(
@@ -557,7 +581,8 @@ class KMeans(_KCluster):
                 self.n_clusters, packed.p, self.max_iter, self.tol,
                 with_inertia=False,
             )
-        self._n_iter = int(n_iter)  # ht: HT002 ok — end-of-fit n_iter readback, one scalar per fit
+        with telemetry.sync("kmeans.n_iter"):  # one scalar per fit
+            self._n_iter = int(n_iter)
         self._cluster_centers = DNDarray(
             centers, tuple(centers.shape),
             types.canonical_heat_type(centers.dtype), None, packed.device,
@@ -569,8 +594,10 @@ class KMeans(_KCluster):
         # (The dense path keeps the reference's definition: the last
         # iteration's assignment distances, pre-update centers.)
         del inertia
-        self._labels, inertia = self._predict_packed(packed, with_inertia=True)
-        self._inertia = float(inertia)  # ht: HT002 ok — end-of-fit inertia readback, one scalar per fit
+        with telemetry.span("kmeans.labels"):
+            self._labels, inertia = self._predict_packed(packed, with_inertia=True)
+        with telemetry.sync("kmeans.inertia"):  # one scalar per fit
+            self._inertia = float(inertia)
         return self
 
     def _predict_packed(self, packed, with_inertia: bool = False):
@@ -637,7 +664,8 @@ class KMeans(_KCluster):
         if isinstance(self.init, str) and self.init == "random":
             width = max(n // k, 1)
             lo = np.arange(k) * (n // k)
-            off = (np.asarray(us) * width).astype(np.int64)  # ht: HT002 ok — k uniforms read once at init
+            with telemetry.sync("kmeans.stream_init"):  # k uniforms, once
+                off = (np.asarray(us) * width).astype(np.int64)
             idx = np.minimum(lo + off, n - 1)
             rows = np.concatenate([src.read(int(i), int(i) + 1) for i in idx])
             return jnp.asarray(rows, jnp.float32)
@@ -728,11 +756,13 @@ class KMeans(_KCluster):
                     sums / jnp.maximum(counts, 1)[:, None],
                     centers.astype(jnp.float32),
                 ).astype(centers.dtype)
-                shift = float(  # ht: HT002 ok — one convergence scalar per full-data pass
-                    jnp.sum((new_centers - centers).astype(jnp.float32) ** 2)
-                )
+                shift2 = jnp.sum((new_centers - centers).astype(jnp.float32) ** 2)
+                # one convergence scalar per full-data pass; the last pass's
+                # inertia rides the same wait
+                with telemetry.sync("kmeans.stream_shift"):
+                    shift = float(shift2)
+                    inertia = float(pass_inertia)
                 centers = new_centers
-                inertia = float(pass_inertia)  # ht: HT002 ok — rides the shift sync, last pass's value is inertia_
                 self._n_iter += 1
                 if shift <= self.tol:
                     break

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from ..core.base import BaseEstimator, ClusteringMixin
 from ..core.dndarray import DNDarray, _ensure_split
-from ..core import types
+from ..core import telemetry, types
 from ..core.linalg import solver
 from ..graph.laplacian import Laplacian
 from ..sparse.dcsr_matrix import DCSR_matrix
@@ -133,7 +133,8 @@ class Spectral(ClusteringMixin, BaseEstimator):
         if self.n_clusters is None:
             # largest eigen-gap heuristic (reference: spectral.py:166)
             gaps = jnp.diff(evals)
-            self.n_clusters = int(jnp.argmax(gaps)) + 1  # ht: HT002 ok — eigen-gap model selection needs the host-side cluster count
+            with telemetry.sync("spectral.eigen_gap"):  # the host-side cluster count
+                self.n_clusters = int(jnp.argmax(gaps)) + 1
             self._cluster.n_clusters = self.n_clusters
 
         components = evecs[:, : self.n_clusters]

@@ -423,6 +423,7 @@ class Decision(NamedTuple):
     key: Tuple[str, str]
 
 
+@telemetry.span("autotune.decide")
 def decide(
     key: Tuple[str, str],
     prior_arm: str,
@@ -529,7 +530,8 @@ def timed(fn: Callable, *args) -> Tuple[Any, float]:
     out = fn(*args)
     # an asynchronous device error (OOM, runtime fault) surfaces here and
     # must propagate: a poisoned result is not a timing
-    jax.block_until_ready(out)  # ht: HT002 ok — this IS the measured-arm timing barrier (autotune.timed)
+    with telemetry.sync("autotune.timed"):  # the measured arm's fence
+        jax.block_until_ready(out)
     return out, time.perf_counter() - t0
 
 

@@ -8,6 +8,7 @@ from __future__ import annotations
 import inspect
 from typing import Any, Dict, List, Optional, TypeVar
 
+from . import telemetry
 from .dndarray import DNDarray
 
 __all__ = [
@@ -88,7 +89,8 @@ class ClassificationMixin:
     def score(self, x: DNDarray, y: DNDarray, sample_weight=None) -> float:
         """Mean accuracy of ``predict(x)`` vs ``y``."""
         pred = self.predict(x)
-        return float((pred.larray.reshape(-1) == y.larray.reshape(-1)).mean())  # ht: HT002 ok — user-facing scalar metric API; the sync IS the contract
+        with telemetry.sync("estimator.score"):  # scalar metric API: the sync is the contract
+            return float((pred.larray.reshape(-1) == y.larray.reshape(-1)).mean())
 
 
 class ClusteringMixin:
@@ -123,7 +125,8 @@ class RegressionMixin:
         yv = y.larray.reshape(-1)
         ss_res = jnp.sum((yv - pred) ** 2)
         ss_tot = jnp.sum((yv - jnp.mean(yv)) ** 2)
-        return float(1.0 - ss_res / ss_tot)  # ht: HT002 ok — user-facing scalar metric API; the sync IS the contract
+        with telemetry.sync("estimator.score"):
+            return float(1.0 - ss_res / ss_tot)
 
 
 class TransformMixin:

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import jax
 
+from . import telemetry
 from ..parallel.mesh import (
     Communication,
     MeshComm,
@@ -52,7 +53,8 @@ class MPIRequest:
 
     def wait(self):
         if self.value is not None:
-            jax.block_until_ready(self.value)  # ht: HT002 ok — MPIRequest.wait() compat: blocking is the documented semantic
+            with telemetry.sync("comm.wait"):  # MPIRequest.wait(): blocking is the contract
+                jax.block_until_ready(self.value)
         return self.value
 
     Wait = wait

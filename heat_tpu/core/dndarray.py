@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from . import devices, memtrack, types
+from . import devices, memtrack, telemetry, types
 from .devices import Device
 from ..analysis import sanitize
 from ..parallel import transport
@@ -411,7 +411,9 @@ class DNDarray:
         """The single element of a size-1 array (reference: dndarray.py:1097)."""
         if self.size != 1:
             raise ValueError("only one-element arrays can be converted to Python scalars")
-        return self.larray.reshape(()).item()  # ht: HT002 ok — scalar-conversion protocol (__int__ et al) requires the host value
+        scalar = self.larray.reshape(())
+        with telemetry.sync("dndarray.item"):  # the protocol needs the host value
+            return scalar.item()
 
     def __bool__(self) -> bool:
         return bool(self.__cast(bool))
@@ -430,7 +432,9 @@ class DNDarray:
         — a Bcast there; a host read here)."""
         if self.size != 1:
             raise TypeError("only size-1 arrays can be converted to Python scalars")
-        return cast_function(self.larray.reshape(()).item())  # ht: HT002 ok — scalar cast protocol requires the host value
+        scalar = self.larray.reshape(())
+        with telemetry.sync("dndarray.cast"):  # the protocol needs the host value
+            return cast_function(scalar.item())
 
     # ----------------------------------------------------------- distribution
     def is_distributed(self) -> bool:
@@ -1030,7 +1034,9 @@ class DNDarray:
         m_log = m_log.astype(jnp.bool_)
         # phase 1: the count — ONE scalar readback fixes the static output
         # extent (the reference pays the same sync in its count Allgather)
-        n_sel = int(jnp.sum(m_log))  # ht: HT002 ok — documented one-scalar sync fixing the static output extent
+        count = jnp.sum(m_log)
+        with telemetry.sync("dndarray.mask_count"):
+            n_sel = int(count)
         if flatten:
             gshape, out_split = (n_sel,), 0
             n_axis = int(np.prod(self.__gshape))

@@ -491,7 +491,7 @@ def _build_program(
     measured ~10x the acceptable tax on the CPU CI mesh).  Guard-off
     programs are byte-identical to the unguarded build."""
 
-    def program(*vals):
+    def ht_fused(*vals):  # names the XLA module: jit_ht_fused
         env = []
         for ins in instrs:
             if ins[0] == "L":
@@ -502,14 +502,18 @@ def _build_program(
                 env.append(v)
             else:
                 _, fn, kw, ch = ins
-                env.append(fn(*[env[c] for c in ch], **dict(kw or ())))
+                # one device scope per op node (trace-time only), so the
+                # profiler's device events carry the library's op names
+                with jax.named_scope("ht.fused/" + op_name(fn)):
+                    env.append(fn(*[env[c] for c in ch], **dict(kw or ())))
         outs = []
         flag = jnp.asarray(True) if with_guard else None
         for out_slot, gshape, split, target in zip(out_slots, gshapes, splits, targets):
             out = env[out_slot]
             if with_guard and jnp.issubdtype(jnp.result_type(out), jnp.inexact):
                 # on the logical (pre-pad) output: pad zeros are always finite
-                flag = jnp.logical_and(flag, jnp.all(jnp.isfinite(out)))
+                with jax.named_scope("ht.fused/guard.isfinite"):
+                    flag = jnp.logical_and(flag, jnp.all(jnp.isfinite(out)))
             if split is not None and gshape:
                 n = gshape[split]
                 pn = _physical_dim(n, nshards)
@@ -521,7 +525,7 @@ def _build_program(
             outs.append(out)
         return tuple(outs) + ((flag,) if with_guard else ())
 
-    return program
+    return ht_fused
 
 
 # --------------------------------------------------------- chain terminators
@@ -802,11 +806,13 @@ def _guard_check(outs, instrs, sites, leaves, lshapes, out_slots, fast_flag=None
     list, so a shared node is evaluated and blamed once — to name the
     first op whose finite inputs went non-finite, plus every program
     output its subtree feeds."""
-    if (
-        bool(fast_flag)
-        if fast_flag is not None
-        else all(_host_finite(o) for o in outs)
-    ):
+    if fast_flag is not None:
+        with telemetry.sync("guard.flag"):  # the folded allfinite scalar
+            finite = bool(fast_flag)
+    else:
+        with telemetry.sync("guard.host_finite"):  # small outputs, fetched
+            finite = all(_host_finite(o) for o in outs)
+    if finite:
         return
     vals = [lf.value for lf in leaves]
     if not all(_finite(v) for v in vals):

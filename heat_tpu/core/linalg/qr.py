@@ -81,35 +81,41 @@ def _build_tsqr(mesh, axis, calc_q: bool = True):
     dominant FLOPs — is skipped entirely (the reference's ``calc_q``
     contract, qr.py:17)."""
 
-    def kernel(block):
+    def ht_tsqr(block):
         # block: (m_local, n) — local panel factorization on the MXU
         n = block.shape[1]
-        q1, r1 = jnp.linalg.qr(block, mode="reduced")
-        # gather the small R factors: (nshards*n, n); one ICI all-gather
-        rs = lax.all_gather(r1, axis_name=axis, axis=0, tiled=True)
-        q2, r = jnp.linalg.qr(rs, mode="reduced")
-        # normalize signs so R has non-negative diagonal (deterministic across
-        # merge orders, matching the reference's comparability guarantees)
-        signs = jnp.sign(jnp.diagonal(r))
-        signs = jnp.where(signs == 0, 1.0, signs).astype(r.dtype)
-        r = r * signs[:, None]
+        with jax.named_scope("ht.tsqr.leaf"):
+            q1, r1 = jnp.linalg.qr(block, mode="reduced")
+        with jax.named_scope("ht.tsqr.gather"):
+            # gather the small R factors: (nshards*n, n); one ICI all-gather
+            rs = lax.all_gather(r1, axis_name=axis, axis=0, tiled=True)
+        with jax.named_scope("ht.tsqr.merge"):
+            q2, r = jnp.linalg.qr(rs, mode="reduced")
+            # normalize signs so R has non-negative diagonal (deterministic
+            # across merge orders, matching the reference's comparability
+            # guarantees)
+            signs = jnp.sign(jnp.diagonal(r))
+            signs = jnp.where(signs == 0, 1.0, signs).astype(r.dtype)
+            r = r * signs[:, None]
         if not calc_q:
             return r
-        q2 = q2 * signs[None, :]
-        idx = lax.axis_index(axis)
-        q2_block = lax.dynamic_slice_in_dim(q2, idx * n, n, axis=0)
-        # HIGHEST precision: the MXU's default bf16 passes would cost ~3
-        # digits of orthogonality in Q
-        q = jnp.matmul(q1, q2_block, precision=jax.lax.Precision.HIGHEST)
+        with jax.named_scope("ht.tsqr.apply"):
+            q2 = q2 * signs[None, :]
+            idx = lax.axis_index(axis)
+            q2_block = lax.dynamic_slice_in_dim(q2, idx * n, n, axis=0)
+            # HIGHEST precision: the MXU's default bf16 passes would cost ~3
+            # digits of orthogonality in Q
+            q = jnp.matmul(q1, q2_block, precision=jax.lax.Precision.HIGHEST)
         return q, r
 
     return _shard_map(
-        kernel, mesh,
+        ht_tsqr, mesh,
         in_specs=(P(axis, None),),
         out_specs=(P(axis, None), P(None, None)) if calc_q else P(None, None),
     )
 
 
+@telemetry.span("qr.tsqr")
 def _tsqr(a: DNDarray, calc_q: bool = True):
     """One-level TSQR tree over the split axis."""
     comm = a.comm
@@ -128,6 +134,7 @@ def _tsqr(a: DNDarray, calc_q: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("calc_q", "mixed", "kernel"))
+@telemetry.module_name("ht_cholesky_qr2")
 def _cholesky_qr2(arr, calc_q: bool = True, mixed: bool = False, kernel: str = ""):
     """CholeskyQR2: tall-skinny QR as pure MXU matmuls.
 
@@ -155,58 +162,70 @@ def _cholesky_qr2(arr, calc_q: bool = True, mixed: bool = False, kernel: str = "
     ``qr_panel.panel_mode`` — the autotune ``kernel`` arm in :func:`qr`."""
     eye = jnp.eye(arr.shape[1], dtype=arr.dtype)
 
-    def gram_chol(x, lowp):
+    # device scopes ht.qr.gram<i> / chol<i> / apply<i> name the stages of
+    # pass i (trace-time only); the fused panel kernel, which holds the
+    # Gram, its Cholesky and the inverse in one launch, sits under gram<i>
+    scope = jax.named_scope
+
+    def gram_chol(x, lowp, i):
         # contract dim 0 directly — an explicit x.T would materialize a full
         # transposed copy of the tall operand in HBM
-        if lowp:
-            xb = x.astype(jnp.bfloat16)
-            g = jax.lax.dot_general(
-                xb, xb, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ).astype(x.dtype)
-        else:
-            g = jax.lax.dot_general(
-                x, x, (((0,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST
-            )
-        return jnp.linalg.cholesky(g)
+        with scope(f"ht.qr.gram{i}"):
+            if lowp:
+                xb = x.astype(jnp.bfloat16)
+                g = jax.lax.dot_general(
+                    xb, xb, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ).astype(x.dtype)
+            else:
+                g = jax.lax.dot_general(
+                    x, x, (((0,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST
+                )
+        with scope(f"ht.qr.chol{i}"):
+            return jnp.linalg.cholesky(g)
 
-    def chol_step(x, lowp=False):
+    def fused_panel(x, i):
+        # fused panel pass: one launch, G stays in VMEM
+        with scope(f"ht.qr.gram{i}"):
+            return qr_panel.fused_gram_chol(x, interpret=(kernel == "interpret"))
+
+    def chol_step(x, i, lowp=False):
         if kernel and not lowp:
-            # fused panel pass: one launch, G stays in VMEM
-            r, rinv = qr_panel.fused_gram_chol(
-                x, interpret=(kernel == "interpret")
-            )
-            q = jnp.matmul(x, rinv, precision=jax.lax.Precision.HIGHEST)
+            r, rinv = fused_panel(x, i)
+            with scope(f"ht.qr.apply{i}"):
+                q = jnp.matmul(x, rinv, precision=jax.lax.Precision.HIGHEST)
             return q, r
-        l = gram_chol(x, lowp)
-        rinv = jax.lax.linalg.triangular_solve(l, eye, lower=True, left_side=True).T
-        if lowp:
-            q = jnp.matmul(
-                x.astype(jnp.bfloat16), rinv.astype(jnp.bfloat16),
-                preferred_element_type=jnp.float32,
-            ).astype(x.dtype)
-        else:
-            q = jnp.matmul(x, rinv, precision=jax.lax.Precision.HIGHEST)
+        l = gram_chol(x, lowp, i)
+        with scope(f"ht.qr.chol{i}"):
+            rinv = jax.lax.linalg.triangular_solve(l, eye, lower=True, left_side=True).T
+        with scope(f"ht.qr.apply{i}"):
+            if lowp:
+                q = jnp.matmul(
+                    x.astype(jnp.bfloat16), rinv.astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32,
+                ).astype(x.dtype)
+            else:
+                q = jnp.matmul(x, rinv, precision=jax.lax.Precision.HIGHEST)
         return q, l.T
 
-    q1, r1 = chol_step(arr, lowp=mixed)
+    q1, r1 = chol_step(arr, 1, lowp=mixed)
     if calc_q:
-        q, r2 = chol_step(q1)
+        q, r2 = chol_step(q1, 2)
     else:
         # R-only: the second pass still needs R2 = chol(Q1ᵀQ1)ᵀ for the
         # orthogonality-corrected R, but the tall Q1·R2⁻¹ GEMM is skipped
         if kernel:
-            r2 = qr_panel.fused_gram_chol(
-                q1, interpret=(kernel == "interpret")
-            )[0]
+            r2 = fused_panel(q1, 2)[0]
             q = None
         else:
-            q, r2 = None, gram_chol(q1, False).T
-    r = jnp.matmul(r2, r1, precision=jax.lax.Precision.HIGHEST)
+            q, r2 = None, gram_chol(q1, False, 2).T
+    with scope("ht.qr.chol2"):
+        r = jnp.matmul(r2, r1, precision=jax.lax.Precision.HIGHEST)
     return q, r
 
 
 @functools.partial(jax.jit, static_argnames=("mixed", "calc_q", "kernel"))
+@telemetry.module_name("ht_blocked_qr")
 def _blocked_qr(arr, mixed: bool = False, calc_q: bool = True, kernel: str = ""):
     """Blocked QR for square-ish matrices (m >= n) as pure GEMMs.
 
@@ -239,17 +258,19 @@ def _blocked_qr(arr, mixed: bool = False, calc_q: bool = True, kernel: str = "")
         )
 
     hi = jax.lax.Precision.HIGHEST
-    t1 = proj(q1, a2)
-    a2 = a2 - jnp.matmul(q1, t1, precision=hi)
-    t2 = proj(q1, a2)  # reorthogonalize: CGS2
-    a2 = a2 - jnp.matmul(q1, t2, precision=hi)
-    r12 = t1 + t2
+    with jax.named_scope("ht.qr.panel"):
+        t1 = proj(q1, a2)
+        a2 = a2 - jnp.matmul(q1, t1, precision=hi)
+        t2 = proj(q1, a2)  # reorthogonalize: CGS2
+        a2 = a2 - jnp.matmul(q1, t2, precision=hi)
+        r12 = t1 + t2
     q2, r22 = _blocked_qr(a2, mixed=mixed, calc_q=calc_q, kernel=kernel)
-    q = jnp.concatenate([q1, q2], axis=1) if calc_q else None
-    r = jnp.block([
-        [r11, r12],
-        [jnp.zeros((r22.shape[0], n1), r11.dtype), r22],
-    ])
+    with jax.named_scope("ht.qr.panel"):
+        q = jnp.concatenate([q1, q2], axis=1) if calc_q else None
+        r = jnp.block([
+            [r11, r12],
+            [jnp.zeros((r22.shape[0], n1), r11.dtype), r22],
+        ])
     return q, r
 
 
@@ -320,9 +341,18 @@ def qr(
         raise ValueError(f'precision must be "float32" or "mixed", got {precision!r}')
 
     m, n = a.shape
+    with telemetry.span("linalg.qr", m=m, n=n) as sp:
+        return _qr(a, calc_q, check, precision, sp)
+
+
+def _qr(a: DNDarray, calc_q: bool, check: str, precision: str, sp) -> QR:
+    """:func:`qr` after validation; notes the path taken (``tsqr`` /
+    ``cholqr2`` / ``blocked`` / ``householder``) on the span ``sp``."""
+    m, n = a.shape
     nshards = a.comm.size
     # TSQR needs each local block to have at least n rows: m/nshards >= n
     if a.split == 0 and nshards > 1 and m >= n * nshards:
+        sp.note(path="tsqr")
         return QR(*_tsqr(a, calc_q=calc_q))
 
     arr = a.larray
@@ -365,8 +395,9 @@ def qr(
                 arms=autotune.KERNEL_ARMS,
             )
             if d.explore:
-                (q, r), t_c = autotune.timed(fact)
-                _, t_k = autotune.timed(fact, kmode)
+                with telemetry.span("autotune.explore", site="qr_panel"):
+                    (q, r), t_c = autotune.timed(fact)
+                    _, t_k = autotune.timed(fact, kmode)
                 autotune.observe(key, "classic", t_c)
                 autotune.observe(key, "kernel", t_k)
                 telemetry.record_timing(fp_k, t_k)
@@ -387,9 +418,15 @@ def qr(
         # compiled program and its HBM high-water mark (the 4 GB head room
         # matters: see the 1e5x1e4 OOM margin in the commit history).
         # "defer" skips the sync; breakdown stays NaN-latched in Q/R.
-        if check == "defer" or bool(jnp.all(jnp.isfinite(r))):  # ht: HT002 ok — documented breakdown check; check='defer' skips it
+        ok = True
+        if check != "defer":
+            finite = jnp.all(jnp.isfinite(r))
+            with telemetry.sync("qr.breakdown_check"):  # the documented one
+                ok = bool(finite)
+        if ok:
             # chol succeeded; diagonal is positive by construction, no sign
             # pass needed
+            sp.note(path="cholqr2" if m >= 2 * n else "blocked")
             r_ht = DNDarray(
                 r, tuple(r.shape), types.canonical_heat_type(r.dtype),
                 1 if a.split == 1 else None, a.device, a.comm,
@@ -401,6 +438,7 @@ def qr(
                 a.split, a.device, a.comm,
             )
             return QR(_ensure_split(q_ht, a.split), _ensure_split(r_ht, r_ht.split))
+    sp.note(path="householder")
     q, r = jnp.linalg.qr(arr, mode="reduced")
     signs = jnp.sign(jnp.diagonal(r))
     signs = jnp.where(signs == 0, 1.0, signs).astype(r.dtype)

@@ -4,7 +4,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from . import _operations, types
+from . import _operations, telemetry, types
 from .dndarray import DNDarray
 
 __all__ = [
@@ -35,7 +35,8 @@ def allclose(x, y, rtol: float = 1e-05, atol: float = 1e-08, equal_nan: bool = F
     """Global closeness verdict (reference: logical.py:~100)."""
     a = x.larray if isinstance(x, DNDarray) else jnp.asarray(x)
     b = y.larray if isinstance(y, DNDarray) else jnp.asarray(y)
-    return bool(jnp.allclose(a, b, rtol=rtol, atol=atol, equal_nan=equal_nan))  # ht: HT002 ok — allclose returns a Python bool by NumPy-parity contract
+    with telemetry.sync("logical.allclose"):  # a Python bool by NumPy parity
+        return bool(jnp.allclose(a, b, rtol=rtol, atol=atol, equal_nan=equal_nan))
 
 
 def any(x, axis=None, out=None, keepdims=False) -> DNDarray:

@@ -40,11 +40,12 @@ registrations are expected to die by exit; survivors — plus fusion pins
 whose owning Expr is gone (``fusion.pin_leaks``) — surface through
 :func:`leaks`.
 
-Gating: the ledger registers at ``events`` level and above (``off`` and
-``counters`` pay one integer compare per would-be registration, matching
-telemetry's documented idle cost); watermark sampling rides
+Gating: the ledger registers at ``events`` level only (``off``,
+``counters`` and ``trace`` pay one integer compare per would-be
+registration, matching telemetry's documented idle cost: a traced run
+walks no stack that the timed run does not); watermark sampling rides
 ``timed_call``'s existing gate (every call at ``events``, every Nth at
-``counters``).
+``counters`` and ``trace``).
 """
 
 from __future__ import annotations
@@ -208,7 +209,7 @@ def _format_sharding(s) -> Optional[str]:
 
 
 def register_buffer(value, *, tag: str = "leaf", split=None) -> Optional[int]:
-    """Ledger one device buffer (gated: ``events`` level and above; the
+    """Ledger one device buffer (gated: ``events`` level only; the
     idle cost is the one integer compare below).  The creation site is
     the nearest user frame (guard's caller-attribution walk); lifetime is
     tracked by ``weakref.finalize`` on the buffer itself, so the entry
@@ -216,7 +217,7 @@ def register_buffer(value, *, tag: str = "leaf", split=None) -> Optional[int]:
     live buffer (an alias wrapped into a second DNDarray) keeps the first
     entry — the true creation site — and counts a rebind.  Returns the
     ledger key (``id(value)``) or ``None`` when not ledgered."""
-    if telemetry._LEVEL < telemetry._EVENTS or not _ENABLED[0]:
+    if telemetry._LEVEL != telemetry._EVENTS or not _ENABLED[0]:
         return None
     try:
         # itemsize * prod(shape), not value.nbytes: jax rederives the
@@ -267,8 +268,8 @@ def register_buffer(value, *, tag: str = "leaf", split=None) -> Optional[int]:
 def tag_buffer(value, tag: str) -> None:
     """Retag a live ledger entry (e.g. a leaf about to be DONATED to a
     destructive resplit, or one newly PINNED by a pending lazy DAG).
-    No-op below ``events`` level or for unledgered buffers."""
-    if telemetry._LEVEL < telemetry._EVENTS or not _ENABLED[0]:
+    No-op off ``events`` level or for unledgered buffers."""
+    if telemetry._LEVEL != telemetry._EVENTS or not _ENABLED[0]:
         return
     rec = _LEDGER.get(id(value))
     if rec is not None and tag in TAGS and tag != rec["tag"]:
@@ -522,7 +523,7 @@ def sample_bytes() -> Tuple[Optional[int], Optional[str]]:
             if used is not None and used > _DEVICE_PEAKS.get(name, -1):
                 _DEVICE_PEAKS[name] = used
         return worst, "device"
-    if telemetry._LEVEL >= telemetry._EVENTS:
+    if telemetry._LEVEL == telemetry._EVENTS:
         _COUNTERS["mem_samples"] += 1
         return _LIVE_BYTES[0], "ledger"
     return None, None

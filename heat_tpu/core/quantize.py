@@ -403,13 +403,14 @@ def tuned_arm(
         arms=autotune.QUANT_ARMS,
     )
     if decision.explore:
-        out, bf16_s = autotune.timed(bf16_fn)
+        with telemetry.span("autotune.explore", site="quantize"):
+            out, bf16_s = autotune.timed(bf16_fn)
+            try:
+                _, int8_s = autotune.timed(int8_fn)
+            except Exception:
+                # an arm that cannot run loses by forfeit (bounded explore)
+                int8_s = float("inf")
         autotune.observe(key, "bf16", bf16_s)
-        try:
-            _, int8_s = autotune.timed(int8_fn)
-        except Exception:
-            # an arm that cannot run loses by forfeit (bounded explore)
-            int8_s = float("inf")
         autotune.observe(key, "int8", int8_s)
         _STATS["by_arm"]["bf16"] += 1
         return out

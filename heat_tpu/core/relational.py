@@ -4,7 +4,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from . import _operations
+from . import _operations, telemetry
 from .dndarray import DNDarray
 
 __all__ = ["eq", "equal", "ge", "greater", "greater_equal", "gt", "le", "less", "less_equal", "lt", "ne", "not_equal"]
@@ -21,11 +21,13 @@ def equal(x, y) -> bool:
     if isinstance(x, DNDarray) and isinstance(y, DNDarray):
         if tuple(x.shape) != tuple(y.shape):
             return False
-        return bool(jnp.all(x.larray == y.larray))  # ht: HT002 ok — equal() returns a Python bool by NumPy-parity contract
+        with telemetry.sync("relational.equal"):  # a Python bool by NumPy parity
+            return bool(jnp.all(x.larray == y.larray))
     a = x.larray if isinstance(x, DNDarray) else x
     b = y.larray if isinstance(y, DNDarray) else y
     try:
-        return bool(jnp.all(jnp.equal(a, b)))  # ht: HT002 ok — equal() returns a Python bool by NumPy-parity contract
+        with telemetry.sync("relational.equal"):
+            return bool(jnp.all(jnp.equal(a, b)))
     except (ValueError, TypeError):
         return False
 

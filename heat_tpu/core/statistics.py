@@ -16,7 +16,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from . import _operations, sanitation, types
+from . import _operations, sanitation, telemetry, types
 from .dndarray import DNDarray, _ensure_split
 from .stride_tricks import sanitize_axis
 
@@ -179,8 +179,9 @@ def histc(input, bins: int = 100, min: float = 0.0, max: float = 0.0, out=None) 
     sanitation.sanitize_in(input)
     lo, hi = float(min), float(max)
     if lo == 0.0 and hi == 0.0:
-        lo = float(jnp.min(input.larray))  # ht: HT002 ok — histogram range needs host bounds (NumPy parity)
-        hi = float(jnp.max(input.larray))  # ht: HT002 ok — histogram range needs host bounds (NumPy parity)
+        with telemetry.sync("statistics.histc_range"):  # host bounds (NumPy parity)
+            lo = float(jnp.min(input.larray))
+            hi = float(jnp.max(input.larray))
     hist, _ = jnp.histogram(input.larray, bins=bins, range=(lo, hi))
     hist = hist.astype(input.dtype.jax_type())
     wrapped = DNDarray(hist, tuple(hist.shape), input.dtype, None, input.device, input.comm)

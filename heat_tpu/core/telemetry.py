@@ -30,11 +30,19 @@ replay/blame, ring-vs-GSPMD dispatch decisions with their cost-model
 inputs, stall heartbeats.  Gated by ``HEAT_TPU_TELEMETRY``:
 
     ``off``       record nothing (no events, no ledger, no spans)
-    ``counters``  cost ledger on; no events (the default)
-    ``events``    + flight recorder + span events
-    ``trace``     + ``jax.profiler.TraceAnnotation`` per span, so spans
-                  land in Perfetto traces captured via
-                  ``monitor.profile_trace``
+    ``counters``  cost ledger on, 1 executable call in 16 wall-clocked;
+                  no events (the default)
+    ``events``    + flight recorder + span events, EVERY executable call
+                  wall-clocked (a ``block_until_ready`` and two
+                  ``memory_stats()`` reads each) and memtrack's residency
+                  ledger on: the debugging level, it changes the timing
+    ``trace``     ``counters`` + flight recorder + span events + one
+                  ``jax.profiler.TraceAnnotation`` per span (named
+                  ``ht:<span>``), so spans land in profiler traces
+                  (``monitor.profile_trace``, ``perf/run.py --trace 1``).
+                  It adds NO host sync, ``memory_stats()`` read or stack
+                  walk that ``counters`` does not make: the traced program
+                  is the timed program plus annotations
 
 :func:`events` reads the buffer, :func:`dump` writes a postmortem
 document, and :func:`postmortem` is invoked automatically on a guard
@@ -43,12 +51,31 @@ document, and :func:`postmortem` is invoked automatically on a guard
 disk unprompted.
 
 **Span tracing.**  :func:`span` is a context manager *and* decorator
-with nesting (parent ids ride the events) wired into
-``materialize``/``materialize_all``, the transport kernels, ring
-dispatch, and estimator ``.fit`` loops.  In ``trace`` mode each span
-also enters ``jax.profiler.TraceAnnotation``, so the same names appear
-in Perfetto.  Open spans are visible across threads
-(:func:`open_spans`) — a stall postmortem shows what was in flight.
+with nesting (parent ids ride the events, and ``root``, the id of the
+outermost open span of the thread, is shared by all spans of one user
+call) wired into ``materialize``/``materialize_all``, the transport
+kernels, ring dispatch, ``linalg.qr``, ``autotune.decide``/``explore``
+and estimator ``.fit`` loops.  In ``trace`` mode each span also enters
+``jax.profiler.TraceAnnotation("ht:" + name)``: the prefix tells the
+program's spans from the runtime's own host events (``PjitFunction``,
+``DevicePut``, ``np.asarray(jax.Array)``); the flight recorder keeps the
+bare names.  Open spans are visible across threads (:func:`open_spans`)
+— a stall postmortem shows what was in flight.
+
+**Sync spans.**  :func:`sync` marks a place where the host waits for the
+device (a scalar readback, a fence): ``with telemetry.sync("kmeans.n_iter"):
+n = int(n_iter)``.  It counts in the ``sync`` group (``count``,
+``by_site``) from ``counters`` up and is a span named ``sync:<site>``
+from ``events`` up, so a profiler trace shows the wait under the
+program's name (``ht:sync:kmeans.n_iter``) around the runtime's
+``np.asarray(jax.Array)``.
+
+**Device scopes.**  The jitted programs name their stages with
+``jax.named_scope`` (trace-time only): ``ht.kmeans.lloyd`` / ``.assign``
+/ ``.update``, ``ht.cdist``, ``ht.fused/<op>``, ``ht.qr.gram1`` …
+``ht.qr.apply2``, ``ht.qr.panel``, ``ht.tsqr.leaf`` / ``.gather`` /
+``.merge`` / ``.apply``.  The profiler's device events carry the scope
+path; ``perf/span_reduce.py`` sums device time by it.
 
 **Cost ledger.**  At fusion compile time the op DAG is walked once to
 estimate FLOPs and HBM bytes (elementwise: one FLOP per output element;
@@ -68,7 +95,6 @@ from __future__ import annotations
 
 import copy
 import hashlib
-import io
 import itertools
 import json
 import os
@@ -96,6 +122,7 @@ __all__ = [
     "level",
     "live_buffers",
     "memwatch",
+    "module_name",
     "open_spans",
     "postmortem",
     "program_hit",
@@ -117,6 +144,7 @@ __all__ = [
     "snapshot",
     "snapshot_group",
     "span",
+    "sync",
     "telemetry_level",
     "timed_call",
     "timing_active",
@@ -581,12 +609,13 @@ def postmortem(reason: str, **fields) -> None:
 # ------------------------------------------------------------- span tracing
 
 class _SpanState:
-    __slots__ = ("id", "name", "parent", "t0")
+    __slots__ = ("id", "name", "parent", "root", "t0")
 
-    def __init__(self, sid, name, parent, t0):
+    def __init__(self, sid, name, parent, root, t0):
         self.id = sid
         self.name = name
         self.parent = parent
+        self.root = root
         self.t0 = t0
 
 
@@ -595,6 +624,9 @@ _TLS = threading.local()
 # thread ident -> that thread's open-span stack; lets the stall watchdog
 # (a different thread) see what the workload had in flight
 _ALL_STACKS: Dict[int, List[_SpanState]] = {}
+# what a program span's profiler annotation is named: the prefix tells the
+# program's spans from the runtime's own host events in a profiler trace
+ANNOTATION_PREFIX = "ht:"
 
 
 def _span_stack() -> List[_SpanState]:
@@ -635,47 +667,64 @@ class span:
         def fit(self, x): ...
 
     At ``events`` level, entry/exit append ``span_begin``/``span_end``
-    events carrying the span id, its parent id (nesting), the ``attrs``,
-    and the wall duration; every event recorded inside the region carries
-    the span's id.  At ``trace`` level the region additionally enters
-    ``jax.profiler.TraceAnnotation(name)`` so it lands in Perfetto traces
-    (``monitor.profile_trace``).  Below ``events`` level enter/exit are a
-    single integer compare each — spans stay wired on hot paths at zero
-    steady-state cost."""
+    events carrying the span id, its parent id (nesting), ``root`` (the
+    id of the outermost open span of the thread: all spans of one user
+    call share it), the ``attrs``, and the wall duration; every event
+    recorded inside the region carries the span's id.  :meth:`note` adds
+    attributes that are only known inside the region (the path a
+    dispatcher took) to the ``span_end`` event.  At ``trace`` level the
+    region additionally enters ``jax.profiler.TraceAnnotation("ht:" +
+    name)`` so it lands in profiler traces (``monitor.profile_trace``).
+    Below ``events`` level enter/exit are a single integer compare each —
+    spans stay wired on hot paths at zero steady-state cost."""
 
-    __slots__ = ("name", "attrs", "_state", "_annot")
+    __slots__ = ("name", "attrs", "_state", "_annot", "_noted")
 
     def __init__(self, name: str, **attrs):
         self.name = name
         self.attrs = attrs
         self._state = None
         self._annot = None
+        self._noted = None
 
     def __call__(self, fn: Callable) -> Callable:
         import functools
 
-        name, attrs = self.name, self.attrs
+        fresh = self._fresh
 
         @functools.wraps(fn)
         def wrapped(*args, **kwargs):
-            with span(name, **attrs):
+            with fresh():
                 return fn(*args, **kwargs)
 
         return wrapped
+
+    def _fresh(self) -> "span":
+        """A new span like this one (the decorator opens one per call)."""
+        return span(self.name, **self.attrs)
+
+    def note(self, **attrs) -> None:
+        """Attach ``attrs`` to this span's ``span_end`` event (no-op
+        while the span records nothing)."""
+        if self._state is not None:
+            self._noted = {**(self._noted or {}), **attrs}
 
     def __enter__(self) -> "span":
         if _LEVEL < _EVENTS:
             return self
         stack = _span_stack()
         parent = stack[-1].id if stack else None
-        st = _SpanState(next(_SPAN_IDS), self.name, parent, time.monotonic())
+        sid = next(_SPAN_IDS)
+        st = _SpanState(
+            sid, self.name, parent, stack[0].root if stack else sid,
+            time.monotonic(),
+        )
         # record_event BEFORE pushing, so span_begin carries the PARENT id
         # in its own `span` field (the begin belongs to the enclosing span)
-        seq = record_event(
+        record_event(
             "span_begin", id=st.id, name=self.name, parent=parent,
-            **self.attrs,
+            root=st.root, **self.attrs,
         )
-        del seq
         stack.append(st)
         _ALL_STACKS[threading.get_ident()] = stack
         self._state = st
@@ -683,7 +732,9 @@ class span:
             try:
                 import jax
 
-                self._annot = jax.profiler.TraceAnnotation(self.name)
+                self._annot = jax.profiler.TraceAnnotation(
+                    ANNOTATION_PREFIX + self.name
+                )
                 self._annot.__enter__()
             except Exception:
                 self._annot = None
@@ -706,13 +757,77 @@ class span:
             stack.pop()
         if not stack:
             _ALL_STACKS.pop(threading.get_ident(), None)
+        noted, self._noted = self._noted, None
         record_event(
             "span_end", id=st.id, name=st.name, parent=st.parent,
-            dur_s=round(time.monotonic() - st.t0, 6),
+            root=st.root, dur_s=round(time.monotonic() - st.t0, 6),
+            **(noted or {}),
             **({"status": "error", "error": exc_type.__name__}
                if exc_type is not None else {}),
         )
         return False
+
+
+# host syncs: every place the library makes the host wait for the device
+_SYNC = register_group("sync", {"count": 0, "by_site": {}})
+SYNC_PREFIX = "sync:"
+
+
+class sync(span):
+    """A :class:`span` around a place where the host waits for the device:
+    a scalar readback (``int(x)``, ``float(x)``, ``bool(x)``, ``.item()``,
+    ``np.asarray``) or a fence (``block_until_ready``)::
+
+        with telemetry.sync("kmeans.n_iter"):
+            self._n_iter = int(n_iter)
+
+    At ``counters`` level and above it counts the wait in the ``sync``
+    group (``count`` and ``by_site[site]``: plain dict increments, no
+    clock, no lock); from ``events`` up it is a span named
+    ``sync:<site>``, so the flight recorder times the wait and, at
+    ``trace``, the profiler shows it as ``ht:sync:<site>`` on the caller's
+    line around the runtime's own readback event.  ``perf/span_reduce.py``
+    counts these spans per call and gives them the device's idle time
+    they cover.  The lint rule HT002 takes a sync inside this helper as a
+    measured site."""
+
+    __slots__ = ("site",)
+
+    def __init__(self, site: str, **attrs):
+        span.__init__(self, SYNC_PREFIX + site, **attrs)
+        self.site = site
+
+    def _fresh(self) -> "sync":
+        return sync(self.site, **self.attrs)
+
+    def __enter__(self) -> "sync":
+        if _LEVEL >= _COUNTERS:
+            _SYNC["count"] += 1
+            by_site = _SYNC["by_site"]
+            by_site[self.site] = by_site.get(self.site, 0) + 1
+        return span.__enter__(self)
+
+
+def module_name(name: str) -> Callable:
+    """Decorator under ``jax.jit``: the XLA module is named ``jit_<name>``
+    and not after the Python function, which keeps its own name for its
+    callers.  A module's name is part of its key in JAX's persistent
+    compilation cache, the ``jax.named_scope`` paths inside it are not
+    (``jax_compilation_cache_include_metadata_in_key`` is off): a jitted
+    function whose scopes change gets a new module name with them, or a
+    warm cache hands back the executable with the old scopes (measured,
+    PERF.md section 6, PR 25)::
+
+        @partial(jax.jit, static_argnames=("k",))
+        @telemetry.module_name("ht_lloyd_loop")
+        def _lloyd_loop(x, centers, k, max_iter, tol): ...
+    """
+
+    def rename(fn: Callable) -> Callable:
+        fn.__name__ = name
+        return fn
+
+    return rename
 
 
 # --------------------------------------------------------------- cost ledger
@@ -850,13 +965,14 @@ def set_sample_every(n: int) -> int:
 
 def timing_active() -> bool:
     """Whether THIS executable call should be wall-clocked: never below
-    ``counters``, every call at ``events`` and above, every Nth call at
-    ``counters`` — a sampled ``block_until_ready`` keeps the default-level
-    tax under the cb ``telemetry_overhead`` bar while still accumulating
-    honest steady-state samples."""
+    ``counters``, every call at ``events``, every Nth call at ``counters``
+    and at ``trace`` — a sampled ``block_until_ready`` keeps the
+    default-level tax under the cb ``telemetry_overhead`` bar while still
+    accumulating honest steady-state samples, and a traced run stays the
+    program that ``counters`` runs (no fence of telemetry's own per call)."""
     if _LEVEL < _COUNTERS:
         return False
-    if _LEVEL >= _EVENTS:
+    if _LEVEL == _EVENTS:
         return True
     return next(_TICK) % _SAMPLE_EVERY == 0
 
@@ -947,7 +1063,8 @@ def timed_call(fp: Optional[str], fn: Callable, *args, observer=None):
     out = fn(*args)
     # an asynchronous device error (OOM, runtime fault) surfaces here and
     # must propagate: a poisoned result is neither timed nor returned
-    jax.block_until_ready(out)  # ht: HT002 ok — this IS timed_call's measurement barrier
+    with sync("telemetry.timed_call"):
+        jax.block_until_ready(out)
     dur = time.perf_counter() - t0
     record_timing(fp, dur)
     if observer is not None:
@@ -1171,16 +1288,3 @@ register_group(
         "programs": len(_PROGRAMS),
     },
 )
-
-
-# ------------------------------------------------------------- convenience
-
-def describe() -> str:
-    """One human-readable status block (debugging aid)."""
-    buf = io.StringIO()
-    buf.write(f"telemetry level={level()} capacity={_RING.maxlen} "
-              f"events={len(_RING)} dropped={_DROPPED[0]} "
-              f"programs={len(_PROGRAMS)}\n")
-    for name in _GROUPS:
-        buf.write(f"  [{name}] {snapshot_group(name)}\n")
-    return buf.getvalue()

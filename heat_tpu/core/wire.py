@@ -284,6 +284,7 @@ def consume(site: str, geometry: tuple) -> str:
     return ""
 
 
+@telemetry.span("autotune.explore", site="wire")
 def explore(decision, run_for) -> object:
     """One explore round at a wire site: run every arm under measurement
     — ``run_for(wire_mode)`` with ``""`` (f32), ``"int8"``, ``"fp8"`` —

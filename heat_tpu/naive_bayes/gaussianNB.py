@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from ..core.base import BaseEstimator, ClassificationMixin
 from ..core.dndarray import DNDarray, _ensure_split
-from ..core import types
+from ..core import telemetry, types
 
 __all__ = ["GaussianNB"]
 
@@ -108,7 +108,8 @@ class GaussianNB(ClassificationMixin, BaseEstimator):
         self._counts, self._means, self._vars = n_tot, mu_tot, var_tot
 
         # finalize public attributes
-        self.epsilon_ = self.var_smoothing * float(jnp.max(jnp.var(xv, axis=0)))  # ht: HT002 ok — one scalar readback finalizing fit; epsilon_ is a host hyperparameter
+        with telemetry.sync("gaussiannb.epsilon"):  # one scalar finalizing fit
+            self.epsilon_ = self.var_smoothing * float(jnp.max(jnp.var(xv, axis=0)))
         self.class_count_ = DNDarray(
             n_tot, tuple(n_tot.shape), types.canonical_heat_type(n_tot.dtype), None, x.device, x.comm
         )
@@ -151,7 +152,7 @@ class GaussianNB(ClassificationMixin, BaseEstimator):
         per-class stats (law of total variance), matching what a single
         in-memory call computes from the whole batch — NOT the last
         slab's variance."""
-        from ..core import factories, stream, telemetry
+        from ..core import factories, stream
         from ..parallel.mesh import sanitize_comm
 
         comm = sanitize_comm(comm)
@@ -231,7 +232,8 @@ class GaussianNB(ClassificationMixin, BaseEstimator):
         total_var = jnp.sum(
             n_c[:, None] * (var_c + (mu_c - mu[None, :]) ** 2), axis=0
         ) / tot
-        self.epsilon_ = self.var_smoothing * float(jnp.max(total_var))  # ht: HT002 ok — one scalar readback finalizing fit
+        with telemetry.sync("gaussiannb.epsilon"):  # one scalar finalizing fit
+            self.epsilon_ = self.var_smoothing * float(jnp.max(total_var))
         return self
 
     def _joint_log_likelihood(self, x: DNDarray):

@@ -88,6 +88,7 @@ def _cdist_pallas(x, y, sqrt=True, block=256, interpret=False):
     return out[:m, :n]
 
 
+@jax.named_scope("ht.cdist")
 def cdist(x: jax.Array, y: jax.Array, *, sqrt: bool = True) -> jax.Array:
     """Pairwise (squared if ``sqrt=False``) Euclidean distances, (m,d)×(n,d)→(m,n)."""
     if x.ndim != 2 or y.ndim != 2:

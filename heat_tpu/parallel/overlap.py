@@ -797,14 +797,15 @@ def matmul_raw(comm, a, b, lshape_a, lshape_b, a_split, b_split,
             # call, K calls per geometry, then the winner runs alone.
             if hit:
                 telemetry.program_hit(ring_fp)
-            out, ring_s = autotune.timed(fn, a, b, *extras)
+            with telemetry.span("autotune.explore", site="ring_" + case):
+                out, ring_s = autotune.timed(fn, a, b, *extras)
+                gfn = _gspmd_reference(comm.mesh, spec)
+                _, gspmd_s = autotune.timed(gfn, a, b, *extras)
             if hit:
                 # keep the roofline ledger's convention: the build call's
                 # wall (trace+compile) stays out of min/p50
                 telemetry.record_timing(ring_fp, ring_s)
             autotune.observe(tune.key, "ring", ring_s)
-            gfn = _gspmd_reference(comm.mesh, spec)
-            _, gspmd_s = autotune.timed(gfn, a, b, *extras)
             autotune.observe(tune.key, "gspmd", gspmd_s)
         elif wire_d is not None and wire_d.explore:
             # wire explore round: the f32 ring (this `fn` — wm is "")

@@ -134,7 +134,8 @@ class Lasso(RegressionMixin, BaseEstimator):
 
     def rmse(self, gt: DNDarray, yest: DNDarray) -> float:
         """Root mean squared error (reference: lasso.py:109)."""
-        return float(jnp.sqrt(jnp.mean((gt.larray - yest.larray) ** 2)))  # ht: HT002 ok — user-facing scalar metric API; the sync IS the contract
+        with telemetry.sync("lasso.rmse"):  # scalar metric API: the sync is the contract
+            return float(jnp.sqrt(jnp.mean((gt.larray - yest.larray) ** 2)))
 
     @telemetry.span("lasso.fit")
     def fit(self, x: DNDarray, y: DNDarray) -> "Lasso":
@@ -187,8 +188,9 @@ class Lasso(RegressionMixin, BaseEstimator):
                 arms=autotune.KERNEL_ARMS,
             )
             if d.explore:
-                out_c, t_c = autotune.timed(fit_fn)
-                _, t_k = autotune.timed(fit_fn, kmode)
+                with telemetry.span("autotune.explore", site="lasso_sweep"):
+                    out_c, t_c = autotune.timed(fit_fn)
+                    _, t_k = autotune.timed(fit_fn, kmode)
                 autotune.observe(key, "classic", t_c)
                 autotune.observe(key, "kernel", t_k)
                 telemetry.record_timing(fp_k, t_k)
@@ -202,7 +204,8 @@ class Lasso(RegressionMixin, BaseEstimator):
                 theta, _, n_iter = fit_fn()
         else:
             theta, _, n_iter = fit_fn()
-        self.n_iter = int(n_iter)
+        with telemetry.sync("lasso.n_iter"):  # one scalar per fit
+            self.n_iter = int(n_iter)
 
         self.__theta = DNDarray(
             theta.reshape(-1, 1), (theta.shape[0], 1),

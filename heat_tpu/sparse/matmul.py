@@ -186,8 +186,9 @@ def _dispatch(A: DCSR_matrix, x2: jax.Array) -> jax.Array:
         arms=autotune.SPMV_ARMS,
     )
     if d.explore:
-        out_d, t_d = autotune.timed(_run_dense, A, x2)
-        _, t_g = autotune.timed(_run_gather, A, x2)
+        with telemetry.span("autotune.explore", site="spmv"):
+            out_d, t_d = autotune.timed(_run_dense, A, x2)
+            _, t_g = autotune.timed(_run_gather, A, x2)
         autotune.observe(key, "dense", t_d)
         autotune.observe(key, "gather", t_g)
         telemetry.record_timing(fps["dense"], t_d)

@@ -118,6 +118,7 @@ def _stream_topk_merge(q, slab, valid, base, best_d, best_i, k: int):
     return -neg, jnp.take_along_axis(cat_i, pos, axis=1)
 
 
+@jax.named_scope("ht.cdist")
 def _euclid_kernel(xv, yv, dtype=None, sqrt=True):
     """Composite cdist kernel for the fusion engine: dtype promotion, the
     quadratic expansion, and the optional sqrt all inside one traced body so
@@ -179,8 +180,11 @@ def _build_rowsplit(mesh, spec, sqrt: bool):
     from ..parallel.collectives import shard_map_unchecked
     from jax.sharding import PartitionSpec as P
 
+    def ht_cdist_rowsplit(xs, ys):  # names the XLA module (telemetry.module_name)
+        return _fused(xs, ys, sqrt=sqrt)
+
     return shard_map_unchecked(
-        lambda xs, ys: _fused(xs, ys, sqrt=sqrt),
+        ht_cdist_rowsplit,
         mesh,
         in_specs=(spec, P()),
         out_specs=spec,

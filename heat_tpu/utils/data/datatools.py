@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core import random as ht_random
-from ...core import types
+from ...core import telemetry, types
 from ...core.dndarray import DNDarray, _ensure_split
 
 __all__ = ["Dataset", "DataLoader", "dataset_shuffle", "dataset_ishuffle", "dataset_irecv"]
@@ -193,4 +193,5 @@ def dataset_irecv(dataset: Dataset) -> None:
     import jax
 
     for a in dataset.arrays:
-        jax.block_until_ready(a.larray)  # ht: HT002 ok — ingest barrier before epoch timing starts
+        with telemetry.sync("data.ingest_barrier"):  # before epoch timing starts
+            jax.block_until_ready(a.larray)

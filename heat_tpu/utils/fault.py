@@ -560,7 +560,8 @@ def run_elastic(
             new_state, metrics = step_fn(state, batch_fn(step))
             # surface device-side NaN/Inf (and deferred XLA errors) now,
             # while recovery is still possible
-            jax.block_until_ready(metrics)  # ht: HT002 ok — health check needs materialized metrics while recovery is possible
+            with telemetry.sync("fault.health_check"):
+                jax.block_until_ready(metrics)
             if not health_check(metrics):
                 raise _UnhealthyStep(f"health check failed at step {step}")
         except Exception as exc:  # noqa: BLE001 — any step failure recovers

@@ -69,7 +69,8 @@ def monitor(name: Optional[str] = None, emit: bool = True) -> Callable:
                 raise
             # drain async dispatch so the clock covers the device work;
             # an asynchronous device error surfaces here and propagates
-            jax.block_until_ready(out)  # ht: HT002 ok — benchmark drain: the sync IS the measurement barrier
+            with telemetry.sync("monitor.drain"):  # the measurement barrier
+                jax.block_until_ready(out)
             wall = time.perf_counter() - t0
             mem1 = _device_memory()
             entry = {"name": label, "wall_s": round(wall, 6)}

@@ -589,5 +589,316 @@ class TestCostLedger(TestCase):
         )
 
 
+class TestSyncSpans(TestCase):
+    """``telemetry.sync``: a counter at ``counters``, a span from ``events``
+    up (ISSUE 25)."""
+
+    def setUp(self):
+        telemetry.reset_group("sync")
+
+    def test_counts_at_counters_level_without_events(self):
+        with _EventsLevel("counters"):
+            x = ht.arange(6, dtype=ht.float32, split=0).sum()
+            with telemetry.sync("probe.readback"):
+                float(x.larray)
+            int(x)  # the scalar-conversion protocol is a sync site of its own
+            got = telemetry.snapshot_group("sync")
+            self.assertEqual(got["by_site"]["probe.readback"], 1)
+            self.assertEqual(got["by_site"]["dndarray.cast"], 1)
+            self.assertEqual(got["count"], sum(got["by_site"].values()))
+            self.assertEqual(telemetry.events(), [])
+
+    def test_off_counts_nothing(self):
+        with _EventsLevel("off"):
+            with telemetry.sync("probe.readback"):
+                pass
+        self.assertEqual(telemetry.snapshot_group("sync")["count"], 0)
+        self.assertEqual(telemetry.snapshot_group("sync")["by_site"], {})
+
+    def test_span_at_events_nests_and_carries_root(self):
+        with _EventsLevel():
+            with telemetry.span("user.call"):
+                with telemetry.span("user.inner"):
+                    with telemetry.sync("probe.readback"):
+                        pass
+            begins = {e["name"]: e for e in telemetry.events("span_begin")}
+            ends = {e["name"]: e for e in telemetry.events("span_end")}
+            self.assertIn("sync:probe.readback", begins)
+            self.assertEqual(
+                begins["sync:probe.readback"]["parent"], begins["user.inner"]["id"]
+            )
+            root = begins["user.call"]["id"]
+            self.assertEqual(begins["user.call"]["root"], root)
+            for name in ("user.inner", "sync:probe.readback"):
+                self.assertEqual(begins[name]["root"], root)
+                self.assertEqual(ends[name]["root"], root)
+            self.assertGreaterEqual(ends["sync:probe.readback"]["dur_s"], 0.0)
+            # the next user call is another request
+            with telemetry.span("user.call"):
+                pass
+            self.assertNotEqual(telemetry.events("span_begin")[-1]["root"], root)
+
+    def test_decorator_form_counts_each_call(self):
+        @telemetry.sync("probe.decorated")
+        def read(v):
+            return v + 1
+
+        with _EventsLevel():
+            self.assertEqual(read(1), 2)
+            self.assertEqual(read(2), 3)
+            names = [e["name"] for e in telemetry.events("span_begin")]
+        self.assertEqual(names, ["sync:probe.decorated"] * 2)
+        self.assertEqual(
+            telemetry.snapshot_group("sync")["by_site"]["probe.decorated"], 2
+        )
+
+    def test_note_lands_on_span_end(self):
+        with _EventsLevel():
+            with telemetry.span("probe.dispatch", m=3) as sp:
+                sp.note(path="left")
+            end = telemetry.events("span_end")[-1]
+            self.assertEqual(end["path"], "left")
+            self.assertEqual(telemetry.events("span_begin")[-1]["m"], 3)
+        with telemetry.span("probe.dispatch") as sp:  # counters: records nothing
+            sp.note(path="left")
+        self.assertEqual(telemetry.events(), [])
+
+    def test_trace_annotation_carries_the_prefix(self):
+        seen = []
+
+        class Annotation:
+            def __init__(self, name):
+                seen.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        real = jax.profiler.TraceAnnotation
+        jax.profiler.TraceAnnotation = Annotation
+        try:
+            with _EventsLevel("trace"):
+                with telemetry.span("probe.outer"):
+                    with telemetry.sync("probe.readback"):
+                        pass
+                names = [e["name"] for e in telemetry.events("span_begin")]
+        finally:
+            jax.profiler.TraceAnnotation = real
+        self.assertEqual(seen, ["ht:probe.outer", "ht:sync:probe.readback"])
+        # the flight recorder keeps the bare names
+        self.assertEqual(names, ["probe.outer", "sync:probe.readback"])
+
+
+class TestTracedRunIsTheTimedRun(TestCase):
+    """At ``trace`` no code path of telemetry or memtrack adds a host sync, a
+    ``memory_stats()`` read or a stack walk that ``counters`` does not make
+    (ISSUE 25, point 4)."""
+
+    def _hits(self, level, n=32):
+        """``(fences, memory reads, ledgered buffers)`` of ``n`` cache hits of
+        one fused program at ``level``."""
+        from heat_tpu.core import memtrack
+
+        fusion.reset_cache()
+        x = ht.arange(64, dtype=ht.float32, split=0)
+        _ = ((x + 1.0) * 2.0).larray  # the miss: compiled, never timed
+        fences, reads = [], []
+        real_fence, real_sample = jax.block_until_ready, memtrack.sample_bytes
+
+        def fence(out):
+            fences.append(1)
+            return real_fence(out)
+
+        def sample():
+            reads.append(1)
+            return real_sample()
+
+        jax.block_until_ready, memtrack.sample_bytes = fence, sample
+        prev_every = telemetry.set_sample_every(16)
+        try:
+            with _EventsLevel(level):
+                registered = telemetry.snapshot_group("memtrack")["registered"]
+                for _ in range(n):
+                    _ = ((x + 1.0) * 2.0).larray
+                registered = telemetry.snapshot_group("memtrack")["registered"] - registered
+        finally:
+            jax.block_until_ready, memtrack.sample_bytes = real_fence, real_sample
+            telemetry.set_sample_every(prev_every)
+        return len(fences), len(reads), registered
+
+    @unittest.skipUnless(fusion.enabled(), "fusion engine disabled")
+    def test_trace_keeps_the_counters_cadence(self):
+        fences_c, reads_c, registered_c = self._hits("counters")
+        fences_t, reads_t, registered_t = self._hits("trace")
+        self.assertEqual((fences_c, reads_c), (2, 4))   # 1 execution in 16
+        self.assertEqual((fences_t, reads_t), (fences_c, reads_c))
+        self.assertEqual(registered_c, 0)
+        self.assertEqual(registered_t, 0)               # no stack walk per output
+
+    @unittest.skipUnless(fusion.enabled(), "fusion engine disabled")
+    def test_events_level_still_times_every_call(self):
+        fences, reads, registered = self._hits("events")
+        self.assertEqual((fences, reads), (32, 64))
+        self.assertGreater(registered, 0)
+
+    def test_timing_active_by_level(self):
+        prev_every = telemetry.set_sample_every(4)
+        try:
+            for level, want in (("off", 0), ("counters", 2), ("events", 8), ("trace", 2)):
+                with _EventsLevel(level):
+                    self.assertEqual(
+                        sum(telemetry.timing_active() for _ in range(8)), want, level
+                    )
+        finally:
+            telemetry.set_sample_every(prev_every)
+
+    def test_timed_call_fence_is_a_sync_site(self):
+        telemetry.reset_group("sync")
+        with _EventsLevel("events"):
+            telemetry.timed_call("probe-fp", lambda: jax.numpy.ones(4))
+            names = [e["name"] for e in telemetry.events("span_begin")]
+        self.assertEqual(names, ["sync:telemetry.timed_call"])
+        self.assertEqual(
+            telemetry.snapshot_group("sync")["by_site"], {"telemetry.timed_call": 1}
+        )
+
+
+class TestLayerSpans(TestCase):
+    """Spans where a layer begins: ``linalg.qr``, ``autotune.decide`` /
+    ``explore``, ``kmeans.init`` / ``labels``, ``qr.tsqr`` (ISSUE 25)."""
+
+    @staticmethod
+    def _tree():
+        begins = telemetry.events("span_begin")
+        by_id = {e["id"]: e["name"] for e in begins}
+        return {e["name"]: by_id.get(e["parent"]) for e in begins}
+
+    def test_kmeans_fit_spans_and_syncs(self):
+        rng = np.random.default_rng(3)
+        x = ht.array(rng.normal(size=(64, 4)).astype(np.float32), split=0)
+        telemetry.reset_group("sync")
+        with _EventsLevel():
+            est = ht.cluster.KMeans(n_clusters=3, max_iter=4, random_state=0).fit(x)
+            _ = est.labels_.larray
+            tree = self._tree()
+            roots = {e["root"] for e in telemetry.events("span_begin")
+                     if e["name"].startswith("kmeans.")}
+        self.assertEqual(tree["kmeans.init"], "kmeans.fit")
+        self.assertEqual(tree["kmeans.labels"], "kmeans.fit")
+        self.assertEqual(tree["sync:kmeans.n_iter"], "kmeans.fit")
+        self.assertEqual(tree["sync:kmeans.inertia"], "kmeans.fit")
+        self.assertEqual(len(roots), 1)  # one request, one identifier
+        sites = telemetry.snapshot_group("sync")["by_site"]
+        self.assertEqual(sites["kmeans.n_iter"], 1)
+        self.assertEqual(sites["kmeans.inertia"], 1)
+
+    def test_linalg_qr_span_names_the_path(self):
+        rng = np.random.default_rng(4)
+        tall = ht.array(rng.normal(size=(64, 4)).astype(np.float32), split=None)
+        squarish = ht.array(rng.normal(size=(6, 4)).astype(np.float32), split=None)
+        wide = ht.array(rng.normal(size=(3, 5)).astype(np.float32), split=None)
+        split0 = ht.array(rng.normal(size=(64 * self.comm.size, 4)).astype(np.float32), split=0)
+        with _EventsLevel():
+            for a in (tall, squarish, wide, split0):
+                ht.linalg.qr(a)
+            ends = [e for e in telemetry.events("span_end") if e["name"] == "linalg.qr"]
+            begins = [e for e in telemetry.events("span_begin") if e["name"] == "linalg.qr"]
+            tree = self._tree()
+        want = ["cholqr2", "blocked", "householder",
+                "tsqr" if self.comm.size > 1 else "cholqr2"]
+        self.assertEqual([e["path"] for e in ends], want)
+        self.assertEqual([(e["m"], e["n"]) for e in begins][:2], [(64, 4), (6, 4)])
+        self.assertEqual(tree["sync:qr.breakdown_check"], "linalg.qr")
+        if self.comm.size > 1:
+            self.assertEqual(tree["qr.tsqr"], "linalg.qr")
+
+    def test_autotune_decide_and_explore_spans(self):
+        from heat_tpu.core import autotune
+
+        prev = autotune.set_enabled(True)
+        try:
+            with _EventsLevel():
+                key = autotune.kernel_key("probe_site", 8, 8, "float32")
+                with telemetry.span("probe.caller"):
+                    d = autotune.decide(key, "classic", arms=autotune.KERNEL_ARMS)
+                    self.assertTrue(d.explore)
+                    with telemetry.span("autotune.explore", site="probe_site"):
+                        autotune.timed(lambda: jax.numpy.ones(4))
+                tree = self._tree()
+        finally:
+            autotune.set_enabled(prev)
+            autotune.reset()
+        self.assertEqual(tree["autotune.decide"], "probe.caller")
+        self.assertEqual(tree["autotune.explore"], "probe.caller")
+        self.assertEqual(tree["sync:autotune.timed"], "autotune.explore")
+
+
+class TestDeviceScopes(TestCase):
+    """The jitted programs name their stages (``jax.named_scope``) and carry
+    a module name of their own (ISSUE 25, point 3)."""
+
+    @staticmethod
+    def _lowered(fn, *args, **kwargs):
+        return fn.lower(*args, **kwargs).as_text(debug_info=True)
+
+    def test_lloyd_loop_scopes(self):
+        from heat_tpu.cluster import kmeans
+
+        x, c = jax.numpy.ones((64, 4)), jax.numpy.ones((3, 4))
+        text = self._lowered(kmeans._lloyd_loop, x, c, 3, 5, 0.0)
+        self.assertIn("module @jit_ht_lloyd_loop", text)
+        for scope in ("ht.kmeans.lloyd/while", "ht.kmeans.assign/ht.cdist",
+                      "ht.kmeans.update/dot_general"):
+            self.assertIn(scope, text)
+        self.assertEqual(kmeans._lloyd_loop.__name__, "ht_lloyd_loop")
+
+    def test_packed_lloyd_loop_scopes(self):
+        from heat_tpu.cluster import kmeans
+
+        x2 = jax.numpy.ones((32, 128), jax.numpy.bfloat16)
+        valid = jax.numpy.ones((32, 2), jax.numpy.float32)
+        c = jax.numpy.ones((3, 64), jax.numpy.bfloat16)
+        text = self._lowered(
+            kmeans._lloyd_loop_packed, x2, jax.numpy.zeros((1, 1)), valid, c, 3, 2, 5, 0.0,
+            with_inertia=False,
+        )
+        self.assertIn("module @jit_ht_lloyd_loop_packed", text)
+        for scope in ("ht.kmeans.lloyd", "ht.kmeans.assign", "ht.kmeans.update"):
+            self.assertIn(scope, text)
+
+    def test_cholesky_qr2_and_blocked_scopes(self):
+        import importlib
+
+        qr = importlib.import_module("heat_tpu.core.linalg.qr")
+        text = self._lowered(qr._cholesky_qr2, jax.numpy.ones((64, 4)))
+        self.assertIn("module @jit_ht_cholesky_qr2", text)
+        for stage in ("gram1", "chol1", "apply1", "gram2", "chol2", "apply2"):
+            self.assertIn(f"ht.qr.{stage}/", text)
+        text = self._lowered(qr._blocked_qr, jax.numpy.ones((6, 4)))
+        self.assertIn("module @jit_ht_blocked_qr", text)
+        self.assertIn("ht.qr.panel/", text)
+
+    def test_tsqr_scopes(self):
+        from heat_tpu.core.linalg.qr import _build_tsqr
+
+        fn = jax.jit(_build_tsqr(self.comm.mesh, self.comm.split_axis, True))
+        text = self._lowered(fn, jax.numpy.ones((32 * self.comm.size, 4)))
+        self.assertIn("module @jit_ht_tsqr", text)
+        for stage in ("leaf", "gather", "merge", "apply"):
+            self.assertIn(f"ht.tsqr.{stage}/", text)
+
+    @unittest.skipUnless(fusion.enabled(), "fusion engine disabled")
+    def test_fused_program_scopes_name_the_ops(self):
+        fusion.reset_cache()
+        x = ht.arange(32, dtype=ht.float32, split=0)
+        _ = ((x + 1.0) * 2.0).larray
+        text = fusion.last_hlo()
+        self.assertIn("jit_ht_fused", text)
+        for op in ("add", "mul"):
+            self.assertIn(f"ht.fused/{op}/", text)
+
+
 if __name__ == "__main__":
     unittest.main()
