@@ -28,6 +28,7 @@ from jax.sharding import PartitionSpec as P
 from .. import autotune, sanitation, telemetry, types
 from ..dndarray import DNDarray, _ensure_split
 from ...ops import qr_panel
+from ...ops._pallas_common import LANE
 from ...parallel.collectives import jit_shard_map_cached, on_each_device
 from ...parallel.collectives import shard_map_unchecked as _shard_map
 
@@ -133,19 +134,92 @@ def _tsqr(a: DNDarray, calc_q: bool = True):
     return _ensure_split(q_ht, 0), r_ht
 
 
+# Widths of the column blocks in which :func:`_cholesky_qr2` computes its
+# tall GEMMs; PERF.md section 6 (PR 26) holds the chip readings behind them.
+# Blocks are about LANE columns wide, at most _MAX_BLOCKS a stage (compile
+# time), and their edges are whole tiles of the tall operand's layout, so
+# that XLA slices without a relayout and writes each block in place: on the
+# TPU a tall float32 array is rows-minor, tiled T(8, 128) with the columns
+# in eights, unless its width is a multiple of 128, where the columns lie
+# on the lanes.
+_SUBLANES = 8
+_MAX_BLOCKS = 8
+
+
+def _block_edges(n: int) -> tuple:
+    """Column offsets ``(0, c_1, ..., n)`` of the blocks in which
+    :func:`_cholesky_qr2` computes its Gram and apply GEMMs at width ``n``.
+    Under two blocks' width it is ``(0, n)``: one block, the dense GEMMs
+    (the saving did not pay there).  Else ``ceil(n / 128)`` blocks, at most
+    ``_MAX_BLOCKS``, of one width rounded up to the layout's tile; the last
+    block takes what is left."""
+    if n < 2 * LANE:
+        return (0, n)
+    nb = min(_MAX_BLOCKS, -(-n // LANE))
+    tile = _SUBLANES if n % LANE else LANE
+    width = -(-n // (nb * tile)) * tile
+    return tuple(range(0, n, width)) + (n,)
+
+
+def _gram_upper(x, edges, **dot_kw):
+    """``xᵀx`` from its upper block triangle: block row ``i`` is one GEMM
+    ``x[:, c_i:c_{i+1}]ᵀ · x[:, c_i:]`` (contracting dim 0 — an explicit
+    ``x.T`` would materialize a transposed copy of the tall operand), and
+    the blocks left of the diagonal are the mirror image, never computed.
+    With more than one block the result is exactly symmetric."""
+    dot = functools.partial(
+        jax.lax.dot_general, dimension_numbers=(((0,), (0,)), ((), ())), **dot_kw
+    )
+    if len(edges) == 2:
+        return dot(x, x)
+    strips = [dot(x[:, lo:hi], x[:, lo:]) for lo, hi in zip(edges, edges[1:])]
+    u = jnp.concatenate(
+        [jnp.pad(s, ((0, 0), (lo, 0))) for lo, s in zip(edges, strips)], axis=0
+    )
+    return jnp.triu(u) + jnp.triu(u, 1).T
+
+
+def _apply_upper(x, rinv, edges, **dot_kw):
+    """``x · rinv`` for an upper-triangular ``rinv``: column block ``j`` is
+    one GEMM ``x[:, :c_{j+1}] · rinv[:c_{j+1}, c_j:c_{j+1}]``; the rows of
+    ``rinv`` below the block are exact zeros and are never multiplied."""
+    if len(edges) == 2:
+        return jnp.matmul(x, rinv, **dot_kw)
+    blocks = [
+        jnp.matmul(x[:, :hi], rinv[:hi, lo:hi], **dot_kw)
+        for lo, hi in zip(edges, edges[1:])
+    ]
+    # each block is written in place into one uninitialized buffer (XLA
+    # fuses the GEMM with its dynamic-update-slice); a concatenate costs a
+    # second read and write of the whole result, zeros a memset of it
+    q = jax.lax.empty((x.shape[0], rinv.shape[1]), blocks[0].dtype)
+    for lo, block in zip(edges, blocks):
+        q = jax.lax.dynamic_update_slice(q, block, (0, lo))
+    return q
+
+
 @functools.partial(jax.jit, static_argnames=("calc_q", "mixed", "kernel"))
 @telemetry.module_name("ht_cholesky_qr2")
 def _cholesky_qr2(arr, calc_q: bool = True, mixed: bool = False, kernel: str = ""):
     """CholeskyQR2: tall-skinny QR as pure MXU matmuls.
 
     XLA's Householder QR runs at ~0.1 TFLOP/s on TPU (sequential panel
-    updates); CholeskyQR2 spends ~3x the FLOPs but they are all GEMMs:
+    updates); CholeskyQR2 is all GEMMs:
     ``G = AᵀA; R = chol(G)ᵀ; Q = A·R⁻¹``, repeated once to restore
     orthogonality to machine precision (Yamamoto et al. 2015 — stable for
     cond(A) up to ~1/√eps).  The triangular solve is materialized as
     ``A @ R⁻¹`` so the big operand rides the MXU.  Ill-conditioned inputs
     overflow the Gram matrix and surface as NaNs; :func:`qr` checks and
     falls back to Householder eagerly.
+
+    ``G`` is symmetric and ``R⁻¹`` upper triangular, so both tall GEMMs
+    are computed block-triangularly (:func:`_gram_upper`,
+    :func:`_apply_upper`) over the column blocks :func:`_block_edges`
+    derives from the width: with ``nb`` blocks ``(nb + 1) / (2 nb)`` of the
+    dense multiply-adds, the same arithmetic on the entries that are kept.
+    Dense it is ``8mn²`` FLOPs; towards ``4mn²`` as ``nb`` grows, which is
+    Householder's count with explicit Q (and twice its ``2mn²`` for R
+    alone).  One block is the dense program.
 
     ``mixed=True`` runs the FIRST pass's two tall GEMMs in bf16 with f32
     accumulation (bf16 shares f32's exponent range, so the cast cannot
@@ -161,26 +235,27 @@ def _cholesky_qr2(arr, calc_q: bool = True, mixed: bool = False, kernel: str = "
     (``mixed``) always stays classic.  Callers gate on
     ``qr_panel.panel_mode`` — the autotune ``kernel`` arm in :func:`qr`."""
     eye = jnp.eye(arr.shape[1], dtype=arr.dtype)
+    edges = _block_edges(arr.shape[1])
 
     # device scopes ht.qr.gram<i> / chol<i> / apply<i> name the stages of
-    # pass i (trace-time only); the fused panel kernel, which holds the
-    # Gram, its Cholesky and the inverse in one launch, sits under gram<i>
+    # pass i (trace-time only; every block of a stage sits under the stage's
+    # scope); the fused panel kernel, which holds the Gram, its Cholesky and
+    # the inverse in one launch, sits under gram<i>
     scope = jax.named_scope
 
+    def operands(lowp, *xs):
+        # the precision of a pass's tall GEMMs: bf16 operands with f32
+        # accumulation (``mixed`` pass 1), else f32 at HIGHEST
+        if lowp:
+            return [x.astype(jnp.bfloat16) for x in xs], {
+                "preferred_element_type": jnp.float32
+            }
+        return xs, {"precision": jax.lax.Precision.HIGHEST}
+
     def gram_chol(x, lowp, i):
-        # contract dim 0 directly — an explicit x.T would materialize a full
-        # transposed copy of the tall operand in HBM
         with scope(f"ht.qr.gram{i}"):
-            if lowp:
-                xb = x.astype(jnp.bfloat16)
-                g = jax.lax.dot_general(
-                    xb, xb, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ).astype(x.dtype)
-            else:
-                g = jax.lax.dot_general(
-                    x, x, (((0,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST
-                )
+            (xo,), kw = operands(lowp, x)
+            g = _gram_upper(xo, edges, **kw).astype(x.dtype)
         with scope(f"ht.qr.chol{i}"):
             return jnp.linalg.cholesky(g)
 
@@ -192,21 +267,15 @@ def _cholesky_qr2(arr, calc_q: bool = True, mixed: bool = False, kernel: str = "
     def chol_step(x, i, lowp=False):
         if kernel and not lowp:
             r, rinv = fused_panel(x, i)
-            with scope(f"ht.qr.apply{i}"):
-                q = jnp.matmul(x, rinv, precision=jax.lax.Precision.HIGHEST)
-            return q, r
-        l = gram_chol(x, lowp, i)
-        with scope(f"ht.qr.chol{i}"):
-            rinv = jax.lax.linalg.triangular_solve(l, eye, lower=True, left_side=True).T
+        else:
+            l = gram_chol(x, lowp, i)
+            with scope(f"ht.qr.chol{i}"):
+                rinv = jax.lax.linalg.triangular_solve(l, eye, lower=True, left_side=True).T
+            r = l.T
         with scope(f"ht.qr.apply{i}"):
-            if lowp:
-                q = jnp.matmul(
-                    x.astype(jnp.bfloat16), rinv.astype(jnp.bfloat16),
-                    preferred_element_type=jnp.float32,
-                ).astype(x.dtype)
-            else:
-                q = jnp.matmul(x, rinv, precision=jax.lax.Precision.HIGHEST)
-        return q, l.T
+            (xo, ro), kw = operands(lowp, x, rinv)
+            q = _apply_upper(xo, ro, edges, **kw).astype(x.dtype)
+        return q, r
 
     q1, r1 = chol_step(arr, 1, lowp=mixed)
     if calc_q:
@@ -363,6 +432,10 @@ def _qr(a: DNDarray, calc_q: bool, check: str, precision: str, sp) -> QR:
         # CholeskyQR2 panels (round 5 — the jnp.linalg.qr fallback ran the
         # reference-CI square shape at 2.4% MFU, ~10x below the GEMM path)
         mx = precision == "mixed"
+        # how far the block-triangular GEMMs engage at this shape (1: dense);
+        # for the blocked path, in its widest CholeskyQR2 leaf
+        leaf = n if m >= 2 * n else qr_panel._leaf_panel_n(m, n)
+        sp.note(blocks=len(_block_edges(leaf)) - 1)
 
         def fact(km: str = ""):
             if km and nshards > 1:
@@ -415,9 +488,11 @@ def _qr(a: DNDarray, calc_q: bool, check: str, precision: str, sp) -> QR:
         # scalar readback, traded against never silently returning garbage
         # for ill-conditioned inputs.  An on-device lax.cond over a
         # Householder fallback would keep dispatch async but doubles the
-        # compiled program and its HBM high-water mark (the 4 GB head room
-        # matters: see the 1e5x1e4 OOM margin in the commit history).
-        # "defer" skips the sync; breakdown stays NaN-latched in Q/R.
+        # compiled program and its HBM high-water mark (BASELINE's 1e5x1e4
+        # has no head room to give: on one chip it does not even compile,
+        # the triangular solve alone takes 15.79 GB of temporaries, PERF.md
+        # section 7).  "defer" skips the sync; breakdown stays NaN-latched
+        # in Q/R.
         ok = True
         if check != "defer":
             finite = jnp.all(jnp.isfinite(r))

@@ -1,10 +1,20 @@
 """Linear algebra tests (reference models: heat/core/linalg/tests/
 test_basics.py — full matmul split matrix — and test_qr.py)."""
 
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
 import numpy as np
+import pytest
 
 import heat_tpu as ht
 from .base import TestCase
+
+# ``heat_tpu.core.linalg.qr`` as an attribute is the function; the module
+# holds the jitted factorization and its helper pair
+_qr = importlib.import_module("heat_tpu.core.linalg.qr")
 
 
 class TestMatmul(TestCase):
@@ -298,3 +308,178 @@ class TestQROptions(TestCase):
         # R upper-triangular with nonnegative diagonal
         self.assertTrue(np.allclose(rn, np.triu(rn)))
         self.assertTrue((np.diag(rn) >= 0).all())
+
+
+# --------------------------------------------------------------------------
+# CholeskyQR2's block-triangular GEMMs (ISSUE 26): G = AᵀA from its upper
+# block triangle, Q = A·R⁻¹ without the zero blocks of R⁻¹
+# --------------------------------------------------------------------------
+_HI = jax.lax.Precision.HIGHEST
+_CONTRACT_ROWS = (((0,), (0,)), ((), ()))
+
+
+def _dense_cholesky_qr2(a, calc_q, mixed):
+    """The factorization as the parent of ISSUE 26 computed it: every
+    GEMM dense, written out stage by stage."""
+    eye = jnp.eye(a.shape[1], dtype=a.dtype)
+
+    def gram(x, lowp):
+        if lowp:
+            xb = x.astype(jnp.bfloat16)
+            return jax.lax.dot_general(
+                xb, xb, _CONTRACT_ROWS, preferred_element_type=jnp.float32
+            ).astype(x.dtype)
+        return jax.lax.dot_general(x, x, _CONTRACT_ROWS, precision=_HI)
+
+    def step(x, lowp):
+        l = jnp.linalg.cholesky(gram(x, lowp))
+        rinv = jax.lax.linalg.triangular_solve(l, eye, lower=True, left_side=True).T
+        if lowp:
+            q = jnp.matmul(
+                x.astype(jnp.bfloat16), rinv.astype(jnp.bfloat16),
+                preferred_element_type=jnp.float32,
+            ).astype(x.dtype)
+        else:
+            q = jnp.matmul(x, rinv, precision=_HI)
+        return q, l.T
+
+    q1, r1 = step(a, mixed)
+    if calc_q:
+        q, r2 = step(q1, False)
+    else:
+        q, r2 = None, jnp.linalg.cholesky(gram(q1, False)).T
+    return q, jnp.matmul(r2, r1, precision=_HI)
+
+
+@pytest.mark.parametrize("calc_q", [True, False], ids=["q", "r_only"])
+@pytest.mark.parametrize("mixed", [False, True], ids=["float32", "mixed"])
+@pytest.mark.parametrize(
+    "shape", [(4096, 1000), (2048, 520), (1024, 264), (8192, 64)],
+    ids=lambda s: f"{s[0]}x{s[1]}",
+)
+def test_cholesky_qr2_block_triangular(shape, mixed, calc_q):
+    m, n = shape
+    edges = _qr._block_edges(n)
+    nb = len(edges) - 1
+    # the rule: tile-aligned cuts, an uneven last block, one block where
+    # the width has nothing worth skipping
+    assert edges[0] == 0 and edges[-1] == n
+    assert all(lo < hi for lo, hi in zip(edges, edges[1:]))
+    assert all(c % 8 == 0 for c in edges[1:-1])
+    assert nb == {1000: 8, 520: 5, 264: 3, 64: 1}[n]
+
+    rng = np.random.default_rng(260 + n)
+    a = jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+    if mixed:
+        x, kw = a.astype(jnp.bfloat16), {"preferred_element_type": jnp.float32}
+    else:
+        x, kw = a, {"precision": _HI}
+    eps = float(np.finfo(np.float32).eps)
+
+    # the Gram from its upper block triangle: the dense product to float32
+    # round-off (the same products, summed strip by strip), and no entry
+    # differs from its mirror image
+    g = np.asarray(_qr._gram_upper(x, edges, **kw))
+    g_dense = np.asarray(jax.lax.dot_general(x, x, _CONTRACT_ROWS, **kw))
+    assert g.dtype == np.float32 and g.shape == (n, n)
+    assert np.abs(g - g_dense).max() <= 8 * eps * np.abs(g_dense).max()
+    if nb > 1:
+        assert np.array_equal(g, g.T)
+
+    # what the skipped blocks rest on: forward substitution against the
+    # identity leaves exact zeros below R⁻¹'s diagonal
+    l = jnp.linalg.cholesky(jnp.asarray(g))
+    rinv = jax.lax.linalg.triangular_solve(
+        l, jnp.eye(n, dtype=l.dtype), lower=True, left_side=True
+    ).T
+    assert np.all(np.tril(np.asarray(rinv), -1) == 0.0)
+
+    # the apply without the zero blocks: the dense product against the
+    # same R⁻¹ (every dropped multiply-add is x·0)
+    ro = rinv.astype(x.dtype)
+    q = np.asarray(_qr._apply_upper(x, ro, edges, **kw))
+    q_dense = np.asarray(jnp.matmul(x, ro, **kw))
+    assert q.dtype == np.float32 and q.shape == (m, n)
+    assert np.abs(q - q_dense).max() <= 8 * eps * np.abs(q_dense).max()
+
+    # the whole factorization against the dense program: bit for bit where
+    # the width takes one block, to round-off where it takes several (in
+    # ``mixed`` a last-bit difference in G can flip a bfloat16 rounding of
+    # R⁻¹, so its round-off is the bfloat16 working precision)
+    tol = float(jnp.finfo(jnp.bfloat16).eps) if mixed else 1e-4
+    q, r = _qr._cholesky_qr2(a, calc_q=calc_q, mixed=mixed)
+    q_ref, r_ref = _dense_cholesky_qr2(a, calc_q, mixed)
+    assert (q is None) == (not calc_q)
+    pairs = [(r, r_ref)] + ([(q, q_ref)] if calc_q else [])
+    for got, want in pairs:
+        got, want = np.asarray(got), np.asarray(want)
+        if nb == 1:
+            assert np.array_equal(got, want)
+        else:
+            assert np.abs(got - want).max() <= tol * np.abs(want).max()
+
+    # the benchmark cell's own limits (perf/workloads/qr_tall.json); its
+    # control, ``mixed``, must still read over them
+    rn = np.asarray(r, np.float64)
+    assert np.all(np.tril(rn, -1) == 0.0) and np.all(np.diag(rn) > 0)
+    if calc_q:
+        qn, an = np.asarray(q, np.float64), np.asarray(a, np.float64)
+        resid = np.linalg.norm(an - qn @ rn) / np.linalg.norm(an)
+        orth = np.abs(qn.T @ qn - np.eye(n)).max()
+        assert orth <= 1e-4
+        assert (resid > 1e-4) if mixed else (resid <= 1.5e-6)
+
+
+def test_cholesky_qr2_block_edges_follow_the_layout():
+    """Where the width is a multiple of the 128 lanes the tall operand has
+    its columns on the lanes, and the cuts are whole lanes; nowhere more
+    than eight blocks; one block under two blocks' width."""
+    for n in (2, 64, 128, 255):
+        assert _qr._block_edges(n) == (0, n)
+    assert _qr._block_edges(256) == (0, 128, 256)
+    assert _qr._block_edges(384) == (0, 128, 256, 384)
+    assert _qr._block_edges(1536) == (0, 256, 512, 768, 1024, 1280, 1536)
+    assert _qr._block_edges(1000) == (0, 128, 256, 384, 512, 640, 768, 896, 1000)
+    for n in range(2, 4200, 7):
+        edges = _qr._block_edges(n)
+        assert edges[0] == 0 and edges[-1] == n and len(edges) - 1 <= 8
+        assert all(lo < hi for lo, hi in zip(edges, edges[1:]))
+        assert all(c % (8 if n % 128 else 128) == 0 for c in edges[1:-1])
+
+
+def test_cholesky_qr2_breakdown_at_a_blocked_width():
+    """cond(A)² overflows the float32 Gram matrix at a width that takes
+    several blocks: the NaN latch still reaches R, and ``qr`` still falls
+    back to Householder."""
+    rng = np.random.default_rng(261)
+    m, n = 2048, 520
+    assert len(_qr._block_edges(n)) > 2
+    u, _ = np.linalg.qr(rng.standard_normal((m, n)))
+    v, _ = np.linalg.qr(rng.standard_normal((n, n)))
+    data = ((u * np.logspace(0, -7, n)) @ v.T).astype(np.float32)  # cond 1e7
+    _, r = _qr._cholesky_qr2(jnp.asarray(data))
+    assert not np.isfinite(np.asarray(r)).all()
+    q, r = ht.linalg.qr(ht.array(data))
+    qn, rn = q.numpy().astype(np.float64), r.numpy().astype(np.float64)
+    np.testing.assert_allclose(qn @ rn, data, rtol=1e-3, atol=1e-5)
+    np.testing.assert_allclose(qn.T @ qn, np.eye(n), atol=1e-3)
+
+
+def test_cholesky_qr2_issues_the_flops_it_keeps():
+    """The saving is in the program, not in a timing: the compiled
+    (16384, 1000) float32 factorization counts at most 0.66 of the dense
+    program's 8mn² FLOPs, and no tall GEMM of it has a whole (n, n) or
+    (m, n) output."""
+    m, n = 16384, 1000
+    assert len(_qr._block_edges(n)) > 2
+    lowered = _qr._cholesky_qr2.lower(jax.ShapeDtypeStruct((m, n), jnp.float32))
+    flops = lowered.compile().cost_analysis()["flops"]
+    assert 0.5 * 8 * m * n * n <= flops <= 0.66 * 8 * m * n * n
+    tall = [
+        line for line in lowered.as_text().splitlines()
+        if "stablehlo.dot_general" in line and f"tensor<{m}x" in line
+    ]
+    assert len(tall) == 4 * (len(_qr._block_edges(n)) - 1)
+    for line in tall:
+        out = re.search(r"-> tensor<(\d+)x(\d+)xf32>", line).groups()
+        assert out not in {(str(n), str(n)), (str(m), str(n))}, line

@@ -8,6 +8,8 @@ recorder must witness the production OOM-backoff and eager-fallback paths
 exactly as they ran.
 """
 
+import importlib
+import re
 import threading
 import unittest
 import warnings
@@ -800,15 +802,22 @@ class TestLayerSpans(TestCase):
         squarish = ht.array(rng.normal(size=(6, 4)).astype(np.float32), split=None)
         wide = ht.array(rng.normal(size=(3, 5)).astype(np.float32), split=None)
         split0 = ht.array(rng.normal(size=(64 * self.comm.size, 4)).astype(np.float32), split=0)
+        tall1000 = ht.array(rng.normal(size=(2048, 1000)).astype(np.float32), split=None)
         with _EventsLevel():
-            for a in (tall, squarish, wide, split0):
+            for a in (tall, squarish, wide, split0, tall1000):
                 ht.linalg.qr(a)
             ends = [e for e in telemetry.events("span_end") if e["name"] == "linalg.qr"]
             begins = [e for e in telemetry.events("span_begin") if e["name"] == "linalg.qr"]
             tree = self._tree()
         want = ["cholqr2", "blocked", "householder",
-                "tsqr" if self.comm.size > 1 else "cholqr2"]
+                "tsqr" if self.comm.size > 1 else "cholqr2", "cholqr2"]
         self.assertEqual([e["path"] for e in ends], want)
+        # how far the block-triangular GEMMs engaged: dense at width 4,
+        # several blocks at width 1000, no note where no GEMM path ran
+        qr_mod = importlib.import_module("heat_tpu.core.linalg.qr")
+        self.assertEqual([e.get("blocks") for e in ends[:3]], [1, 1, None])
+        self.assertEqual(ends[4]["blocks"], len(qr_mod._block_edges(1000)) - 1)
+        self.assertGreater(ends[4]["blocks"], 1)
         self.assertEqual([(e["m"], e["n"]) for e in begins][:2], [(64, 4), (6, 4)])
         self.assertEqual(tree["sync:qr.breakdown_check"], "linalg.qr")
         if self.comm.size > 1:
@@ -869,13 +878,19 @@ class TestDeviceScopes(TestCase):
             self.assertIn(scope, text)
 
     def test_cholesky_qr2_and_blocked_scopes(self):
-        import importlib
-
         qr = importlib.import_module("heat_tpu.core.linalg.qr")
         text = self._lowered(qr._cholesky_qr2, jax.numpy.ones((64, 4)))
         self.assertIn("module @jit_ht_cholesky_qr2", text)
         for stage in ("gram1", "chol1", "apply1", "gram2", "chol2", "apply2"):
             self.assertIn(f"ht.qr.{stage}/", text)
+        # with several blocks a stage, every block sits under its stage's
+        # scope and no other ht.qr scope appears
+        text = self._lowered(qr._cholesky_qr2, jax.numpy.ones((2048, 1000), jax.numpy.float32))
+        stages = {f"ht.qr.{s}{i}" for s in ("gram", "chol", "apply") for i in (1, 2)}
+        self.assertEqual(set(re.findall(r"ht\.qr\.\w+", text)), stages)
+        self.assertGreater(len(qr._block_edges(1000)), 2)
+        for stage in ("gram1", "apply1", "gram2", "apply2"):
+            self.assertIn(f'/ht.qr.{stage}/dot_general"', text)
         text = self._lowered(qr._blocked_qr, jax.numpy.ones((6, 4)))
         self.assertIn("module @jit_ht_blocked_qr", text)
         self.assertIn("ht.qr.panel/", text)
