@@ -12,6 +12,16 @@ schedule ourselves wins (SURVEY.md §7, design stance #6):
 * :mod:`~heat_tpu.ops.attention` — flash attention (blockwise online
   softmax); no reference counterpart (Heat has no attention at all,
   SURVEY.md §5) but required for long-context sequence parallelism.
+* :mod:`~heat_tpu.ops.decode_attention` — a few query rows against a long
+  key/value cache: the read a decode step of a long generation spends its
+  time in (Pallas on a TPU), and the position-masked blockwise attention
+  that prefill chunks and rolling windows go through.
+* :mod:`~heat_tpu.ops.selective_scan` — Mamba's selective state-space scan,
+  chunked over a sequence and as one step.
+
+The last two are imported from their modules (``from heat_tpu.ops.decode_attention
+import decode_attention``): a function re-exported here under its module's
+name would hide the module.
 """
 
 from .halo import halo_exchange, map_with_halos
