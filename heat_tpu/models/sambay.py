@@ -42,7 +42,8 @@ import numpy as np
 
 from ..core import telemetry, types
 from ..core.dndarray import DNDarray
-from ..ops.decode_attention import decode_attention, masked_attention
+from ..ops._pallas_common import mode as pallas_mode
+from ..ops.decode_attention import decode_attention, keys_fetched, masked_attention
 from ..ops.selective_scan import selective_scan, selective_step
 
 __all__ = ["SambaY", "SambaYConfig", "DecodeSession", "sambay_layer_types"]
@@ -57,11 +58,15 @@ PREFILL_CHUNK = 512
 ATTN_BLOCK = 2048
 SCAN_CHUNK = 16
 
-# decode_steps and prefill_tokens count what sessions did; cache_bytes is what
-# the newest session allocated, by kind
+# decode_steps and prefill_tokens count what sessions did; cache_keys_visible
+# and cache_keys_fetched are the key slots of the shared cache that the decode
+# steps' reads could see and the slots the decode kernel's rule fetches for
+# them (their ratio is the fetch share; both stand still where the kernel does
+# not run and the jax.numpy fallback reads the cache); cache_bytes is what the
+# newest session allocated, by kind
 _LM = telemetry.register_group(
     "lm",
-    {"decode_steps": 0, "prefill_tokens": 0,
+    {"decode_steps": 0, "prefill_tokens": 0, "cache_keys_visible": 0, "cache_keys_fetched": 0,
      "cache_bytes": {"shared": 0, "window": 0, "state": 0}},
 )
 
@@ -637,8 +642,16 @@ class DecodeSession:
         if self.position + steps > self.capacity:
             raise ValueError(f"{self.position} + {steps} positions pass the session's {self.capacity}")
         cfg = self.cfg
-        with telemetry.span("lm.decode", batch=self.batch, context=self.position, steps=steps,
-                            readers=cfg.n_shared_readers, token_bytes=cfg.cache_token_bytes):
+        notes = dict(batch=self.batch, context=self.position, steps=steps,
+                     readers=cfg.n_shared_readers, token_bytes=cfg.cache_token_bytes)
+        visible = fetched = 0
+        if pallas_mode() != "off":  # the rule is the kernel's: the fallback feeds neither counter
+            reads = self.batch * cfg.n_shared_readers  # of the shared cache, a step
+            lengths = range(self.position + 1, self.position + steps + 1)
+            visible = reads * sum(lengths)
+            fetched = notes["fetched"] = reads * sum(
+                keys_fetched(n, self.capacity, ATTN_BLOCK) for n in lengths)
+        with telemetry.span("lm.decode", **notes):
             self._shared, self._state, self._token, chosen, logits = _decode(
                 cfg, self.model.params, self._shared, self._state, self._token,
                 np.int32(self.position), steps=steps, block=ATTN_BLOCK)
@@ -646,6 +659,8 @@ class DecodeSession:
                 self.tokens = np.asarray(chosen)
         self.position += steps
         _LM["decode_steps"] += steps
+        _LM["cache_keys_visible"] += visible
+        _LM["cache_keys_fetched"] += fetched
         return self._wrap(chosen), self._wrap(logits)
 
     def save(self) -> Snapshot:

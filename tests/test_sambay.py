@@ -346,23 +346,119 @@ def test_config_from_published_dict():
     assert cfg.head_dim == 8 and cfg.kv_groups == 2 and cfg.n_self == 6
 
 
-@pytest.mark.parametrize("kv_len", [1, 255, 256, 257, 700, 1024])
-def test_decode_kernel_equals_plain_attention(kv_len):
+# block 256 (a piece is 32 keys), capacity 1,024: under one block (the pipeline's
+# block, masked), on and around a bfloat16 tile edge, a block edge (no tail),
+# just past a block edge (the piece), a piece edge, past a piece (the pipeline
+# again), and the whole capacity
+KV_LENS = [1, 15, 16, 17, 255, 256, 257, 272, 287, 288, 289, 300, 511, 512, 513, 544, 545, 700,
+           1023, 1024]
+
+
+def kernel_operands(kv_len):
     keys = jax.random.split(jax.random.key(kv_len), 3)
     shape = (2, 3, 1024, 128)
     q = jax.random.normal(keys[0], (2, 3, 4, 128), jnp.float32).astype(jnp.bfloat16)
     k = jax.random.normal(keys[1], shape, jnp.float32).astype(jnp.bfloat16)
     v = jax.random.normal(keys[2], shape, jnp.float32).astype(jnp.bfloat16)
-    got = da._decode_pallas(q, k, v, jnp.int32(kv_len), scale=0.125, block=256, interpret=True)
-    want = da.masked_attention(q, k, v, jnp.full((4,), kv_len - 1), jnp.arange(1024),
+    return q, k, v
+
+
+def copied(kv_len, block=256):
+    """Whether the kernel brings the last block of ``kv_len`` keys as a piece."""
+    return bool(da._tail_rule(*divmod(kv_len, block), da._piece(block))[1])
+
+
+def fallback_attention(q, k, v, kv_len):
+    """The plain ``jax.numpy`` path, which shares no line with the kernel: every
+    block of 256 keys a sum of its own, softmax weights rounded to bfloat16
+    before the value product as in the kernel."""
+    return da.masked_attention(q, k, v, jnp.full((4,), kv_len - 1), jnp.arange(1024),
                                scale=0.125, kv_len=jnp.int32(kv_len), block=256)
-    # both round the softmax weights to bfloat16 before the value product
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=2e-5)
+
+
+def fallback_tol(kv_len):
+    """How far the kernel may lie from :func:`fallback_attention` (readings on
+    these operands, interpreter on the CPU).  The fallback without its newest
+    key lies 1.6e-2 (1,023 keys) to 3.7 (one key) from itself, and 3.4e-2 at
+    least at the lengths with a copied piece: every limit here sees one lost key.
+
+    * Nothing copied: the parent's sum in the parent's order, and the parent's
+      limit 2e-5 (readings 0 to 7.1e-6).
+    * 545: 1.15e-4, one weight of 0.03 that rounds to the other bfloat16
+      neighbour (XLA's batched product and the kernel's product of one stream
+      sum in another order, a float32 ulp apart in a score).
+    * A piece copied: the piece is folded in one sum with the block before it,
+      so the weights of both round from another running maximum than in the
+      fallback: 6.6e-7 to 9.3e-4."""
+    if copied(kv_len):
+        return 2e-3
+    return 2e-4 if kv_len == 545 else 2e-5
+
+
+@pytest.mark.parametrize("kv_len", KV_LENS)
+def test_decode_kernel_equals_plain_attention(kv_len):
+    q, k, v = kernel_operands(kv_len)
+    got = da._decode_pallas(q, k, v, jnp.int32(kv_len), scale=0.125, block=256, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(fallback_attention(q, k, v, kv_len)),
+                               rtol=0, atol=fallback_tol(kv_len))
     exact = jax.nn.softmax(
         jnp.where(jnp.arange(1024) < kv_len,
                   jnp.einsum("bgmd,bgtd->bgmt", q.astype(jnp.float32), k.astype(jnp.float32)) * 0.125,
                   -jnp.inf), axis=-1) @ v.astype(jnp.float32)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(exact), rtol=0, atol=2e-2)
+    # the weights' rounding to bfloat16: 2.3e-3 at 15 keys, 1.1e-3 and less from 255 on
+    np.testing.assert_allclose(np.asarray(got), np.asarray(exact), rtol=0, atol=5e-3)
+
+
+@pytest.mark.parametrize("kv_len", KV_LENS)
+def test_decode_kernel_weighs_every_visible_key_once(kv_len):
+    """Operands whose softmax weights do not round: each query row reads one
+    coordinate of the keys, which holds 0, -1 or -2, and the scale is ln 2, so
+    every weight is 1, 1/2 or 1/4 whatever maximum it is taken from, exactly a
+    bfloat16.  What is left between the kernel and the float32 softmax is the
+    order of float32 sums (readings 7.7e-9 to 7.6e-8); one key of 1,024 left out or
+    counted twice moves a value by 5.4e-3 and more."""
+    _, k, v = kernel_operands(kv_len)
+    powers = jax.random.randint(jax.random.key(kv_len + 5000), (2, 3, 1024, 4), 0, 3)
+    k = k.at[..., :4].set(-powers.astype(jnp.bfloat16))
+    q = jnp.broadcast_to(jnp.eye(4, 128, dtype=jnp.bfloat16), (2, 3, 4, 128))
+    unseen = jnp.arange(1024)[:, None] >= da.keys_fetched(kv_len, 1024, 256)
+    got = da._decode_pallas(q, jnp.where(unseen, jnp.nan, k), jnp.where(unseen, jnp.nan, v),
+                            jnp.int32(kv_len), scale=math.log(2), block=256, interpret=True)
+    weights = jnp.where(jnp.arange(1024) < kv_len, 0.5 ** jnp.swapaxes(powers, 2, 3), 0.0)
+    exact = (weights / weights.sum(-1, keepdims=True)) @ v.astype(jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(exact), rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("kv_len", KV_LENS)
+def test_decode_kernel_never_touches_unseen_slots(kv_len):
+    """Keys and values are NaN in every slot past those that ``keys_fetched``
+    says the kernel fetches: a kernel that computed on one of them gives NaN
+    (a masked weight of 0 times NaN is NaN), so the rule holds the kernel.
+    Just past a block edge that is all but a piece of the last block."""
+    q, k, v = kernel_operands(kv_len)
+    unseen = jnp.arange(1024)[:, None] >= da.keys_fetched(kv_len, 1024, 256)
+    got = da._decode_pallas(q, jnp.where(unseen, jnp.nan, k), jnp.where(unseen, jnp.nan, v),
+                            jnp.int32(kv_len), scale=0.125, block=256, interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(fallback_attention(q, k, v, kv_len)),
+                               rtol=0, atol=fallback_tol(kv_len))
+
+
+@pytest.mark.parametrize("kv_len,slots,block,fetched", [
+    # the benchmark's cell: 16 whole blocks and one piece of 256 keys, of 34,816 slots
+    (32769, 34816, 2048, 32768 + 256), (32776, 34816, 2048, 32768 + 256),
+    (32768 + 256, 34816, 2048, 32768 + 256),
+    # more than a piece of the last block visible: the block, through the pipeline
+    (32768 + 257, 34816, 2048, 34816), (34815, 34816, 2048, 34816),
+    # multiples of the block: what is visible, no piece
+    (32768, 34816, 2048, 32768), (2048, 34816, 2048, 2048), (34816, 34816, 2048, 34816),
+    # under one block: the pipeline's first block, as there is none to hide a copy behind
+    (1, 34816, 2048, 2048), (2047, 34816, 2048, 2048),
+    # a capacity of one short block (a toy session), and blocks under a tile
+    (5, 64, 2048, 64), (64, 64, 2048, 64), (11, 32, 8, 16), (3, 8, 8, 8),
+])
+def test_keys_fetched_rule(kv_len, slots, block, fetched):
+    assert da.keys_fetched(kv_len, slots, block) == fetched
 
 
 def test_masked_attention_reads_a_ring_in_any_order():
@@ -413,7 +509,20 @@ def test_precision_separates(tiles):
     assert program < 4e-2 < reference_in_fp8
 
 
-def test_spans_counters_and_one_sync_a_decode(model, tiles):
+@pytest.fixture
+def kernel_runs(monkeypatch):
+    """The session's programs read the shared cache through the Pallas kernel
+    (the interpreter here), as on a TPU; the tests' default is the fallback."""
+    def retrace():
+        sambay._decode.clear_cache()
+        sambay._prefill_finish.clear_cache()
+    monkeypatch.setenv("HEAT_TPU_PALLAS", "interpret")
+    retrace()
+    yield
+    retrace()
+
+
+def test_spans_counters_and_one_sync_a_decode(model, tiles, kernel_runs):
     tiles(prefill_chunk=8, attn_block=8)
     session = model.session(2, 32)
     before = telemetry.snapshot()
@@ -435,11 +544,36 @@ def test_spans_counters_and_one_sync_a_decode(model, tiles):
     # cache (the full layer and the cross layer of the toy stack) and a token in it
     assert ends["lm.decode"]["steps"] == 2 and ends["lm.decode"]["readers"] == 2
     assert ends["lm.decode"]["token_bytes"] == 2 * 4 * 8 * 4
+    # keys the decode kernel's rule fetches for 2 sessions x 2 readers at lengths
+    # 12 and 13 of 32 slots in blocks of 8: one whole block and one piece of 8
+    assert ends["lm.decode"]["fetched"] == 4 * (16 + 16) and ends["lm.decode"]["context"] == 11
+    visible = 4 * (12 + 13 + 14) + 4 * (12 + 13)
+    assert after["lm"]["cache_keys_visible"] - before["lm"]["cache_keys_visible"] == visible
+    assert after["lm"]["cache_keys_fetched"] - before["lm"]["cache_keys_fetched"] == 4 * 16 * 5
     assert after["lm"]["decode_steps"] - before["lm"]["decode_steps"] == 5
     assert after["lm"]["prefill_tokens"] - before["lm"]["prefill_tokens"] == 22
     assert after["sync"]["count"] - before["sync"]["count"] == 2
     assert after["sync"]["by_site"]["lm.tokens"] - before["sync"]["by_site"].get("lm.tokens", 0) == 2
     assert session.tokens.shape == (2, 2) and session.position == 13
+
+
+def test_fetch_counters_stand_still_under_the_fallback(model, tiles):
+    """``cache_keys_fetched`` states the kernel's rule, so where the kernel does
+    not run (the tests' default: ``masked_attention`` reads the cache) neither it
+    nor ``cache_keys_visible`` moves, and the span carries no ``fetched``."""
+    tiles(prefill_chunk=8, attn_block=8)
+    session = model.session(2, 32)
+    session.prefill(ht.array(prompts(model.cfg, 2, 11)))
+    before = telemetry.snapshot()["lm"]
+    with telemetry.telemetry_level("events"):
+        telemetry.clear_events()
+        session.decode(2)
+        (span,) = [e for e in telemetry.events("span_begin") if e["name"] == "lm.decode"]
+    after = telemetry.snapshot()["lm"]
+    assert "fetched" not in span and span["context"] == 11
+    assert after["decode_steps"] - before["decode_steps"] == 2
+    assert after["cache_keys_visible"] == before["cache_keys_visible"]
+    assert after["cache_keys_fetched"] == before["cache_keys_fetched"]
 
 
 def test_session_refuses_what_it_cannot_hold(model):
