@@ -12,6 +12,7 @@ import numpy as np
 
 import heat_tpu as ht
 from heat_tpu.core import autotune
+from heat_tpu.ops._pallas_common import KERNEL_ARMS
 from heat_tpu.utils.monitor import record
 
 import config
@@ -22,7 +23,7 @@ def _kernel_arm_note():
     resolved winner of a kernel-arm entry, or the honest decline."""
     rows = [
         r for r in autotune.report()["rows"]
-        if tuple(r.get("arms", ())) == autotune.KERNEL_ARMS
+        if tuple(r.get("arms", ())) == KERNEL_ARMS
     ]
     if not rows:
         return (

@@ -30,7 +30,7 @@ def _quant_arm_note():
     resolved winner of a ("bf16","int8") entry, or the honest decline."""
     rows = [
         r for r in autotune.report()["rows"]
-        if tuple(r.get("arms", ())) == autotune.QUANT_ARMS
+        if tuple(r.get("arms", ())) == quantize.QUANT_ARMS
     ]
     if not rows:
         return (

@@ -21,6 +21,7 @@ import scipy.sparse
 
 import heat_tpu as ht
 from heat_tpu.core import autotune, memtrack, telemetry
+from heat_tpu.sparse.matmul import SPMV_ARMS
 from heat_tpu.utils.monitor import record
 
 import config
@@ -32,7 +33,7 @@ def _spmv_arm_note():
     default when tuning never saw the site."""
     rows = [
         r for r in autotune.report()["rows"]
-        if set(r.get("arms", ())) == set(autotune.SPMV_ARMS)
+        if set(r.get("arms", ())) == set(SPMV_ARMS)
     ]
     if not rows:
         return (

@@ -22,6 +22,7 @@ import numpy as np
 
 import heat_tpu as ht
 from heat_tpu.core import autotune, memtrack, telemetry
+from heat_tpu.core.stream import STREAM_ARMS
 from heat_tpu.utils.monitor import record
 
 import config
@@ -33,7 +34,7 @@ def _stream_arm_note():
     default when tuning never resolved the site."""
     rows = [
         r for r in autotune.report()["rows"]
-        if set(r.get("arms", ())) == set(autotune.STREAM_ARMS)
+        if set(r.get("arms", ())) == set(STREAM_ARMS)
     ]
     if not rows:
         return (

@@ -71,7 +71,7 @@ def _tuned_arm_note(run):
             run()
         rows = [
             r for r in autotune.report()["rows"]
-            if tuple(r.get("arms", ())) == autotune.WIRE_ARMS
+            if tuple(r.get("arms", ())) == wire.WIRE_ARMS
         ]
         winners = [r["winner"] or "exploring" for r in rows]
         arm = winners[0] if winners else "wire_f32"

@@ -222,7 +222,7 @@ def tuned_kernel_rows():
     """Tuning-table entries of the classic-vs-kernel sites."""
     return [
         r for r in autotune.report()["rows"]
-        if tuple(r.get("arms", ())) == autotune.KERNEL_ARMS
+        if tuple(r.get("arms", ())) == _pallas_common.KERNEL_ARMS
     ]
 
 

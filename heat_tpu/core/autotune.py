@@ -7,18 +7,20 @@ roofline placement, real HBM watermarks); every performance decision
 still read a static env-var knob.  This module spends those
 measurements at the three engine sites:
 
-1. **Explore/exploit matmul dispatch.**  Per (program fingerprint,
-   device kind), the first K calls (``HEAT_TPU_AUTOTUNE_EXPLORE``,
-   default 3 per arm) run BOTH the ring and the GSPMD path under timed
+1. **Explore/exploit dispatch between lowerings of one operation.**
+   Per (program fingerprint, device kind), the first K calls
+   (:func:`explore_k`, 3 per arm) run EVERY arm under timed
    measurement; the winner by steady-state ``min_s`` sticks in a
-   per-process tuning table.  The static byte threshold
-   (``HEAT_TPU_MATMUL_RING_MIN_BYTES``) is demoted to a *prior*: it
-   still decides unexplored lazy chains and breaks ties, but a measured
-   winner overrides it.  Safety margin: a sticky winner whose sampled
-   wall clock degrades >2x vs its recorded best is sent back to
-   explore.  Exploration happens at the eager engine entry
-   (``overlap.matmul_raw``); the lazy chain path only *consumes*
-   winners — it never runs both arms inside a fused program.
+   per-process tuning table.  A site's static verdict is demoted to a
+   *prior*: it still decides sites that cannot explore (lazy chains)
+   and breaks ties, but a measured winner overrides it.  Safety
+   margin: a sticky winner whose sampled wall clock degrades >2x vs
+   its recorded best is sent back to explore.  The protocol is written
+   once, here: :func:`run` (decide, explore or run the winner under the
+   degradation watch) and :func:`explore` (one measured round, the
+   reference arm's result returned).  A family of arms is declared by
+   the module that dispatches it, which hands its arm names and a
+   :func:`key` to these functions; this module names no client.
 
 2. **HBM-seeded budgets up front.**  ``memtrack.suggest_budget()`` (the
    one formula behind transport's informed OOM retry) now also seeds
@@ -44,92 +46,43 @@ fusion compile-cache key via ``fusion.register_cache_salt`` so tuned
 flips build distinct entries without an import cycle.
 """
 
+import functools
 import json
 import os
 import time
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, Mapping, NamedTuple, Optional, Tuple
 
 from . import memtrack, telemetry
 from .envparse import env_int  # the strict env-int twin of env_bytes (lint HT001)
 from .version import __version__
 
 __all__ = [
-    "ARMS",
     "CACHE_VERSION",
     "Decision",
-    "KERNEL_ARMS",
     "decide",
     "device_kind",
     "enabled",
     "env_bytes",
     "env_int",
+    "explore",
     "explore_k",
-    "kernel_key",
+    "key",
     "load",
-    "matmul_key",
     "merge",
     "note_budget_seed",
     "note_prior",
     "observe",
-    "QUANT_ARMS",
-    "quant_key",
     "report",
     "reset",
+    "run",
     "salt",
     "save",
     "set_enabled",
-    "SPMV_ARMS",
-    "spmv_key",
     "stats",
-    "STREAM_ARMS",
-    "stream_key",
     "table",
-    "WIRE_ARMS",
     "winner",
-    "wire_key",
 ]
 
-ARMS = ("ring", "gspmd")
-# round 15: Pallas kernels join the explore set as per-site arm pairs —
-# "classic" is whatever the site dispatched before this round (ROADMAP
-# item 2 predicted exactly this extension)
-KERNEL_ARMS = ("classic", "kernel")
-# round 16: quantized inference epilogues (core/quantize.py) — "bf16" is
-# the dequantize-then-dispatch reference (bitwise the unquantized flow
-# over the same dequantized values), "int8" keeps the low-precision
-# buffer through the GEMM with the per-channel scale folded into the
-# ring epilogue.  The reference arm name stays "bf16" for fp8 entries
-# too: the arm names the REFERENCE precision class, not the storage.
-QUANT_ARMS = ("bf16", "int8")
-# round 17: quantized collectives (core/wire.py) — the WIRE format of the
-# data-movement engines.  "wire_f32" is the reference arm (today's
-# full-precision collective, byte-for-byte); "wire_int8"/"wire_fp8" ship
-# absmax-scaled low-precision tiles over the all_to_all/ppermute and
-# dequantize on landing.  Distinct from QUANT_ARMS: those pick what the
-# GEMM *computes on*, these pick what the COLLECTIVE *ships* — a site can
-# hold both kinds of entries at once.
-WIRE_ARMS = ("wire_f32", "wire_int8", "wire_fp8")
-# round 19: the sparse compute tier (sparse/matmul.py) — "dense" is the
-# todense() matmul (the authoritative reference; explore always returns
-# its result so numerics never depend on tuning state), "gather" the
-# jitted segment-sum CSR matvec (dense wins near-full matrices, gather
-# the sparse ones).
-SPMV_ARMS = ("dense", "gather")
-# round 22: the out-of-core streaming engine (core/stream.py) — the arms
-# are SLAB SIZES, not lowerings: "slab_full" is the budget-derived
-# maximum slab (budget//2 rows, two slabs live under double buffering),
-# "slab_half"/"slab_quarter" trade residency for pipeline granularity
-# (smaller slabs hide host reads better when the device step is short).
-# Every arm computes the identical result — explore runs the chosen arm
-# and observes its pass wall, so the tuner converges on whichever slab
-# maximizes prefetch overlap for this (source geometry, device kind).
-STREAM_ARMS = ("slab_full", "slab_half", "slab_quarter")
-# every arm name any entry may carry; load() refuses winners outside it
-# so a corrupt cache cannot inject an undispatched arm
-_KNOWN_ARMS = (
-    frozenset(ARMS) | frozenset(KERNEL_ARMS) | frozenset(QUANT_ARMS)
-    | frozenset(WIRE_ARMS) | frozenset(SPMV_ARMS) | frozenset(STREAM_ARMS)
-)
 CACHE_VERSION = 1
 
 # samples kept per arm (min_s over a bounded window; enough for the
@@ -170,20 +123,8 @@ def env_bytes(name: str, default: int, env: Optional[dict] = None) -> int:
 
 def explore_k() -> int:
     """Explore budget: measured samples per arm before a winner is
-    declared (``HEAT_TPU_AUTOTUNE_EXPLORE``, default 3)."""
-    raw = os.environ.get("HEAT_TPU_AUTOTUNE_EXPLORE", "").strip()
-    if not raw:
-        return 3
-    try:
-        k = int(raw)
-        if k <= 0:
-            raise ValueError
-    except ValueError:
-        raise ValueError(
-            "HEAT_TPU_AUTOTUNE_EXPLORE must be a positive integer, "
-            f"got {raw!r}"
-        ) from None
-    return k
+    declared."""
+    return 3
 
 
 # ------------------------------------------------------------------ enabling
@@ -279,7 +220,7 @@ def salt() -> tuple:
     return ("autotune", enabled(), _GENERATION[0])
 
 
-def _entry(key: Tuple[str, str], desc: str = "", arms: Tuple[str, ...] = ARMS) -> dict:
+def _entry(key: Tuple[str, str], desc: str, arms: Tuple[str, ...]) -> dict:
     e = _TABLE.get(key)
     if e is None:
         e = _TABLE[key] = {
@@ -344,71 +285,12 @@ def device_kind() -> str:
     return _DEVICE_KIND[0]
 
 
-def matmul_key(
-    case: str, out_split, m: int, k: int, n: int, size: int, comp: str,
-) -> Tuple[str, str]:
-    """Tuning-table key for one sharded GEMM geometry.  Deliberately
-    excludes epilogue steps: the ring-vs-GSPMD verdict is a function of
-    shape/sharding/dtype/mesh, and sharing the entry across epilogues is
-    what lets an eager explore warm the lazy chain's consult."""
-    fp = telemetry.fingerprint(
-        ("matmul", case, out_split, m, k, n, size, comp)
-    )
-    return fp, device_kind()
-
-
-def kernel_key(site: str, *geometry) -> Tuple[str, str]:
-    """Tuning-table key for one Pallas-kernel dispatch site
-    (``qr_panel`` / ``lasso_sweep``) at one
-    geometry.  The entry's arms are :data:`KERNEL_ARMS` — "classic" (the
-    pre-round-15 lowering) vs "kernel" (the Pallas arm); both are
-    measured by the same explore/exploit machinery as ring-vs-GSPMD."""
-    fp = telemetry.fingerprint(("kernel", site) + tuple(geometry))
-    return fp, device_kind()
-
-
-def quant_key(site: str, *geometry) -> Tuple[str, str]:
-    """Tuning-table key for one quantized-weight dispatch site
-    (``linear`` / ``moe_ffn`` — core/quantize.py) at one geometry.  The
-    entry's arms are :data:`QUANT_ARMS`: "bf16" (dequantize the weight,
-    then the ordinary tuned matmul — the reference arm explore returns)
-    vs "int8" (the low-precision buffer rides the GEMM, per-channel
-    scales fold into the ring epilogue as runtime extras)."""
-    fp = telemetry.fingerprint(("quant", site) + tuple(geometry))
-    return fp, device_kind()
-
-
-def spmv_key(site: str, *geometry) -> Tuple[str, str]:
-    """Tuning-table key for one sparse-matmul dispatch site
-    (``spmv_csr`` — sparse/matmul.py) at one sparsity geometry
-    (shape, rhs columns, nnz bucket, slab capacity, dtype, mesh size).
-    The entry's arms are :data:`SPMV_ARMS`: "dense" (todense() + the
-    ordinary matmul — the reference arm explore returns) and "gather"
-    (jitted segment-sum CSR matvec)."""
-    fp = telemetry.fingerprint(("spmv", site) + tuple(geometry))
-    return fp, device_kind()
-
-
-def wire_key(site: str, *geometry) -> Tuple[str, str]:
-    """Tuning-table key for one quantized-collective dispatch site
-    (``resplit`` / ``rechunk`` / ``ring_ag`` / ``ring_col`` / ``cdist``
-    — see core/wire.py) at one geometry.  The entry's arms are
-    :data:`WIRE_ARMS`: "wire_f32" (the full-precision collective explore
-    returns bitwise) vs "wire_int8"/"wire_fp8" (absmax-scaled tiles on
-    the wire, f32 scales beside them, dequantized on landing)."""
-    fp = telemetry.fingerprint(("wire", site) + tuple(geometry))
-    return fp, device_kind()
-
-
-def stream_key(site: str, *geometry) -> Tuple[str, str]:
-    """Tuning-table key for one out-of-core streaming pass
-    (``kmeans_fit`` / ``gnb_fit`` / ``knn_predict`` — core/stream.py) at
-    one source geometry (total rows, features, dtype, mesh size, budget
-    bucket).  The entry's arms are :data:`STREAM_ARMS`: fractions of the
-    budget-derived maximum slab.  All arms are numerically identical —
-    the tuner is picking the slab size that best hides host I/O behind
-    device compute, so each pass runs ONE arm and observes its wall."""
-    fp = telemetry.fingerprint(("stream", site) + tuple(geometry))
+def key(family: str, site: str, *geometry) -> Tuple[str, str]:
+    """Tuning-table key for one dispatch site of one arm family at one
+    geometry.  The family's owner declares its arm names and hands them
+    to :func:`decide` / :func:`run`; the family string keeps two owners'
+    sites apart where site and geometry coincide."""
+    fp = telemetry.fingerprint((family, site) + tuple(geometry))
     return fp, device_kind()
 
 
@@ -416,10 +298,10 @@ def stream_key(site: str, *geometry) -> Tuple[str, str]:
 
 
 class Decision(NamedTuple):
-    arm: str          # "ring" | "gspmd" — what to run (explore: run both,
-    #                   return this arm's result)
+    arm: str          # what to run (explore: run every arm, return the
+    #                   reference arm's result)
     source: str       # "explored" | "cached" | "prior"
-    explore: bool     # run BOTH arms under measurement this call
+    explore: bool     # run EVERY arm under measurement this call
     key: Tuple[str, str]
 
 
@@ -428,14 +310,27 @@ def decide(
     key: Tuple[str, str],
     prior_arm: str,
     desc: str = "",
-    arms: Tuple[str, ...] = ARMS,
+    *,
+    arms: Tuple[str, ...],
 ) -> Decision:
-    """One dispatch consult at the eager engine entry.  While either arm
+    """One dispatch consult at the eager engine entry.  While any arm
     has fewer than :func:`explore_k` samples the call explores (runs
-    both arms); a resolved entry serves its winner; the caller's static
-    threshold verdict rides along as the prior.  ``arms`` names the
-    entry's arm set on first touch (:data:`ARMS` for ring-vs-GSPMD,
-    :data:`KERNEL_ARMS` for the Pallas kernel sites)."""
+    every arm); a resolved entry serves its winner; the caller's static
+    verdict rides along as the prior.  ``arms`` is the arm set the site
+    dispatches.  A stored entry that names any other set came from
+    outside the program (a cache file written for another site or
+    build): it is dropped here, before it can be served, with a
+    ``fallback`` event, and the site explores afresh."""
+    e = _TABLE.get(key)
+    if e is not None and e["arms"].keys() != set(arms):
+        _STATS["fallbacks"] += 1
+        telemetry.record_event(
+            "fallback", site="autotune.decide", fingerprint=key[0],
+            device_kind=key[1],
+            error=f"entry arms {sorted(e['arms'])}, site dispatches {sorted(arms)}",
+        )
+        del _TABLE[key]
+        _GENERATION[0] += 1
     e = _entry(key, desc, arms)
     if e["winner"] is not None:
         _STATS["decisions"] += 1
@@ -471,15 +366,15 @@ def note_prior(key: Tuple[str, str], arm: str, site: str = "chain") -> None:
 
 def observe(key: Tuple[str, str], arm: str, dur_s: float) -> None:
     """Fold one measured wall clock into ``key``'s arm.  Resolves the
-    winner once both arms carry :func:`explore_k` samples (argmin over
+    winner once every arm carries :func:`explore_k` samples (argmin over
     per-arm ``min_s`` — min, not mean: the steady state, compile and
     cache-warm outliers washed out).  On a resolved entry this is the
     degradation watch: ``_DEGRADE_STRIKES`` consecutive samples slower
     than ``_DEGRADE_FACTOR``× the recorded best send it back to
     explore."""
     e = _TABLE.get(key)
-    if e is None:
-        e = _entry(key)
+    if e is None:  # never decided (or reset since): no arm set to fill
+        return
     if e["winner"] is not None:
         if arm != e["winner"] or not e["best_s"]:
             return
@@ -535,6 +430,73 @@ def timed(fn: Callable, *args) -> Tuple[Any, float]:
     return out, time.perf_counter() - t0
 
 
+def explore(
+    decision: Decision,
+    arms: Mapping[str, Callable[[], Any]],
+    *,
+    site: str,
+    forfeit: Tuple[str, ...] = (),
+    programs: Optional[Mapping[str, Optional[str]]] = None,
+) -> Any:
+    """One explore round: run every arm of ``arms`` (arm name → thunk,
+    the first being the reference) under :func:`timed`, fold each wall
+    clock into ``decision.key`` and return the REFERENCE arm's output, so
+    numerics never depend on tuning state.  An arm named in ``forfeit``
+    that raises loses by an infinite time (which keeps the explore phase
+    bounded); any other arm's exception propagates, and then nothing is
+    observed.  ``programs`` maps an arm to the cost-ledger fingerprint its
+    wall clock is also recorded under."""
+    programs = programs or {}
+    times: Dict[str, float] = {}
+    with telemetry.span("autotune.explore", site=site):
+        ref, *others = arms
+        out, times[ref] = timed(arms[ref])
+        for arm in others:
+            try:
+                times[arm] = timed(arms[arm])[1]
+            except Exception:
+                if arm not in forfeit:
+                    raise
+                times[arm] = float("inf")
+    for arm, dur_s in times.items():
+        observe(decision.key, arm, dur_s)
+        telemetry.record_timing(programs.get(arm), dur_s)  # no program: no-op
+    return out
+
+
+def run(
+    key: Tuple[str, str],
+    arms: Mapping[str, Callable[[], Any]],
+    *,
+    prior: str,
+    desc: str,
+    site: str,
+    cost: Optional[Mapping[str, dict]] = None,
+    forfeit: Tuple[str, ...] = (),
+) -> Any:
+    """THE explore/exploit dispatch between lowerings of one operation:
+    :func:`decide`, then :func:`explore` while the entry is unresolved,
+    the winner alone afterwards.  ``cost`` gives an arm its cost-ledger
+    program: ``sig`` (the tuple its fingerprint is taken of) beside what
+    ``telemetry.ensure_program`` takes.  Such an arm's explore times land
+    in the ledger, and as the winner it runs under telemetry's sampled
+    fence with :func:`observe` watching it for degradation."""
+    programs = {}
+    for arm, model in (cost or {}).items():
+        model = dict(model)
+        programs[arm] = telemetry.fingerprint(model.pop("sig"))
+        telemetry.ensure_program(programs[arm], **model)
+    d = decide(key, prior, desc=desc, arms=tuple(arms))
+    if d.explore:
+        return explore(d, arms, site=site, forfeit=forfeit, programs=programs)
+    if d.arm in programs:
+        return telemetry.timed_call(
+            programs[d.arm], arms[d.arm],
+            observer=functools.partial(observe, key, d.arm),
+        )
+    return arms[d.arm]()
+
+
 # ------------------------------------------------------------- HBM seeding
 
 
@@ -577,6 +539,16 @@ def save(path) -> int:
             "desc": e["desc"],
             "arms": {a: [_finite(t) for t in d] for a, d in e["arms"].items()},
         })
+    path = _write_doc(path, entries)
+    _STATS["saves"] += 1
+    telemetry.record_event(
+        "autotune_cache", action="save", path=path, entries=len(entries),
+    )
+    return len(entries)
+
+
+def _write_doc(path, entries) -> str:
+    """One cache document, written atomically (tmp + ``os.replace``)."""
     doc = {
         "version": CACHE_VERSION,
         "library": __version__,
@@ -587,11 +559,7 @@ def save(path) -> int:
     with open(tmp, "w") as f:
         json.dump(doc, f, indent=1, sort_keys=True)
     os.replace(tmp, path)
-    _STATS["saves"] += 1
-    telemetry.record_event(
-        "autotune_cache", action="save", path=path, entries=len(entries),
-    )
-    return len(entries)
+    return path
 
 
 def _finite(t):
@@ -617,16 +585,13 @@ def _parse_cache_doc(doc):
     parsed = []
     for ent in entries:
         w = ent.get("winner")
-        if w is not None and w not in _KNOWN_ARMS:
-            raise ValueError(f"unknown arm {w!r}")
-        # the entry's own arm set round-trips (ring/gspmd AND
-        # classic/kernel entries share one cache file); arm names
-        # outside the registry poison the whole file — a winner
-        # this build cannot dispatch must not warm-start anything
-        arm_names = tuple(ent.get("arms", {})) or ARMS
-        for a in arm_names:
-            if a not in _KNOWN_ARMS:
-                raise ValueError(f"unknown arm {a!r}")
+        # the entry's own arm set round-trips (every family shares one
+        # cache file).  What this module can know alone is checked here;
+        # that the names are the ones the site dispatches is checked by
+        # decide(), where the site says what they are
+        arm_names = tuple(ent.get("arms", {}))
+        if not arm_names:
+            raise ValueError("entry names no arms")
         if w is not None and w not in arm_names:
             raise ValueError(f"winner {w!r} outside entry arms")
         parsed.append((
@@ -640,6 +605,18 @@ def _parse_cache_doc(doc):
     return parsed
 
 
+def _read_doc(path: str, site: str):
+    """Read and parse one cache file; a file :func:`load` refuses is
+    ignored whole, with a recorded ``fallback`` event, and gives ``None``."""
+    try:
+        with open(path) as f:
+            return _parse_cache_doc(json.load(f))
+    except Exception as exc:
+        _STATS["fallbacks"] += 1
+        telemetry.record_event("fallback", site=site, path=path, error=str(exc))
+        return None
+
+
 def load(path) -> int:
     """Restore a saved tuning table.  A corrupt, stale-version, or
     different-library file is IGNORED with a recorded ``fallback`` event
@@ -647,18 +624,11 @@ def load(path) -> int:
     another device kind load fine — they simply never match a key here.
     Returns the number of entries restored (0 on fallback)."""
     path = os.fspath(path)
-    try:
-        with open(path) as f:
-            doc = json.load(f)
-        parsed = _parse_cache_doc(doc)
-    except Exception as exc:
-        _STATS["fallbacks"] += 1
-        telemetry.record_event(
-            "fallback", site="autotune.load", path=path, error=str(exc),
-        )
+    parsed = _read_doc(path, "autotune.load")
+    if parsed is None:
         return 0
     for key, w, best, desc, arms in parsed:
-        e = _entry(key, desc)
+        e = _entry(key, desc, tuple(arms))
         e["winner"] = w
         e["best_s"] = float(best) if best is not None else None
         e["arms"] = arms
@@ -701,16 +671,8 @@ def merge(paths, out) -> str:
     chosen: Dict[tuple, dict] = {}
     sources = 0
     for path in paths:
-        path = os.fspath(path)
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-            parsed = _parse_cache_doc(doc)
-        except Exception as exc:
-            _STATS["fallbacks"] += 1
-            telemetry.record_event(
-                "fallback", site="autotune.merge", path=path, error=str(exc),
-            )
+        parsed = _read_doc(os.fspath(path), "autotune.merge")
+        if parsed is None:
             continue
         sources += 1
         for key, w, best, desc, arms in parsed:
@@ -726,18 +688,9 @@ def merge(paths, out) -> str:
             old = chosen.get(mkey)
             if old is None or _merge_prefers(entry, old):
                 chosen[mkey] = entry
-    doc = {
-        "version": CACHE_VERSION,
-        "library": __version__,
-        "entries": sorted(
-            chosen.values(), key=lambda e: (e["fingerprint"], e["device_kind"])
-        ),
-    }
-    out = os.fspath(out)
-    tmp = f"{out}.tmp.{os.getpid()}"
-    with open(tmp, "w") as f:
-        json.dump(doc, f, indent=1, sort_keys=True)
-    os.replace(tmp, out)
+    out = _write_doc(out, sorted(
+        chosen.values(), key=lambda e: (e["fingerprint"], e["device_kind"])
+    ))
     telemetry.record_event(
         "autotune_cache", action="merge", path=out,
         entries=len(chosen), sources=sources,

@@ -61,7 +61,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from . import envparse, guard, memtrack, telemetry, types
+from . import guard, memtrack, telemetry, types
 from ..analysis import program_audit, sanitize
 from .dndarray import DNDarray, _physical_dim
 from .guard import NonFiniteError
@@ -606,7 +606,7 @@ class _Entry:
 
 
 _CACHE: "OrderedDict[tuple, _Entry]" = OrderedDict()
-_CACHE_MAX = envparse.env_int("HEAT_TPU_FUSE_CACHE_SIZE", 4096)
+_CACHE_MAX = 4096  # live executables; LRU drops beyond it
 # All counters live in ONE telemetry group; the registry owns the reset
 # contract (a counter added to the defaults below resets/exports/snapshots
 # with no second bookkeeping site).  Notable members:
@@ -644,7 +644,7 @@ _ROOTS_PER_PROGRAM = _STATS["roots_per_program"]
 def cache_stats() -> dict:
     """Counters for the executable cache: ``hits``/``misses`` (lookups),
     ``size`` (live entries), ``evictions`` (LRU drops past
-    ``HEAT_TPU_FUSE_CACHE_SIZE``), ``fallbacks`` (total degraded-to-eager
+    ``_CACHE_MAX``), ``fallbacks`` (total degraded-to-eager
     events) with a per-reason breakdown under ``fallback_reasons``
     (``unfusable`` / ``compile_error`` / ``exec_error`` /
     ``guard_replay``).  A serving steady state shows misses flat and hits

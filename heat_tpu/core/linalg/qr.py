@@ -447,40 +447,22 @@ def _qr(a: DNDarray, calc_q: bool, check: str, precision: str, sp) -> QR:
             return _blocked_qr(arr, mixed=mx, calc_q=calc_q, kernel=km)
 
         # round 15: the fused syrk+chol+trsm panel kernel as a measured
-        # autotune arm — explore times BOTH lowerings (and returns the
-        # classic result so numerics never depend on tuning state), then
-        # the per-geometry winner sticks with a degradation watch
+        # autotune arm beside the classic lowering (the reference arm)
         kmode = qr_panel.panel_mode(m, n, arr.dtype, mx, a.split, nshards)
         if kmode != "off" and autotune.enabled():
             dt = str(arr.dtype)
-            fp_k = telemetry.fingerprint(
-                ("qr_panel_fused", m, n, dt, calc_q)
+            q, r = autotune.run(
+                autotune.key("kernel", "qr_panel", m, n, dt, calc_q, nshards),
+                {"classic": fact, "kernel": functools.partial(fact, kmode)},
+                prior="classic", desc=f"qr {m}x{n} {dt}", site="qr_panel",
+                cost={"kernel": dict(
+                    sig=("qr_panel_fused", m, n, dt, calc_q),
+                    kind="kernel_qr_panel", ops=1,
+                    flops=4.0 * m * n * n,
+                    hbm_bytes=3.0 * m * n * arr.dtype.itemsize,
+                    mesh={"devices": nshards}, dtype=dt,
+                )},
             )
-            telemetry.ensure_program(
-                fp_k, kind="kernel_qr_panel", ops=1,
-                flops=4.0 * m * n * n,
-                hbm_bytes=3.0 * m * n * arr.dtype.itemsize,
-                mesh={"devices": nshards}, dtype=dt,
-            )
-            key = autotune.kernel_key("qr_panel", m, n, dt, calc_q, nshards)
-            d = autotune.decide(
-                key, "classic", desc=f"qr {m}x{n} {dt}",
-                arms=autotune.KERNEL_ARMS,
-            )
-            if d.explore:
-                with telemetry.span("autotune.explore", site="qr_panel"):
-                    (q, r), t_c = autotune.timed(fact)
-                    _, t_k = autotune.timed(fact, kmode)
-                autotune.observe(key, "classic", t_c)
-                autotune.observe(key, "kernel", t_k)
-                telemetry.record_timing(fp_k, t_k)
-            elif d.arm == "kernel":
-                q, r = telemetry.timed_call(
-                    fp_k, fact, kmode,
-                    observer=functools.partial(autotune.observe, key, "kernel"),
-                )
-            else:
-                q, r = fact()
         else:
             q, r = fact()
         # "eager": one deliberate host sync per factorization call: the

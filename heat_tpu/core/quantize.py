@@ -19,8 +19,7 @@ accumulates half-precision inputs in f32.  This module supplies that step:
 * :func:`matmul_quantized` / :func:`linear`: the quantized GEMM behind
   ``nn.functional.linear`` and ``linalg.basics.matmul``.  Dispatch rides
   the tuning plane as a ``("bf16", "int8")`` arm pair per (site,
-  geometry, device kind) — ``core/autotune.py``'s :data:`~heat_tpu.core
-  .autotune.QUANT_ARMS`:
+  geometry, device kind) — :data:`QUANT_ARMS`:
 
   - **bf16** — dequantize, then the ordinary (itself ring-vs-GSPMD
     tuned) matmul.  This is the *reference* arm: explore calls return
@@ -61,6 +60,7 @@ from .dndarray import DNDarray, _ensure_split
 from ..analysis import sanitize
 
 __all__ = [
+    "QUANT_ARMS",
     "QuantizedDNDarray",
     "QuantizedTensor",
     "dequantize_tensor",
@@ -79,6 +79,14 @@ __all__ = [
 # aliases keep this module's surface stable.
 _QMAX = _wire.QMAX
 _qdtype = _wire.qdtype
+
+# round 16: quantized inference epilogues — "bf16" is the
+# dequantize-then-dispatch reference (bitwise the unquantized flow over
+# the same dequantized values), "int8" keeps the low-precision buffer
+# through the GEMM with the per-channel scale folded into the ring
+# epilogue.  The reference arm name stays "bf16" for fp8 entries too:
+# the arm names the REFERENCE precision class, not the storage.
+QUANT_ARMS = ("bf16", "int8")
 
 
 _STATS = telemetry.register_group(
@@ -389,29 +397,24 @@ def tuned_arm(
     falls back to bf16 — quantization must never turn a working call
     into an error."""
     if arm is not None:
-        if arm not in autotune.QUANT_ARMS:
-            raise ValueError(f"arm must be one of {autotune.QUANT_ARMS}")
+        if arm not in QUANT_ARMS:
+            raise ValueError(f"arm must be one of {QUANT_ARMS}")
         _STATS["by_arm"][arm] += 1
         return int8_fn() if arm == "int8" else bf16_fn()
     if not autotune.enabled():
         _STATS["declines"] += 1
         _STATS["by_arm"]["bf16"] += 1
         return bf16_fn()
-    key = autotune.quant_key(site, *geometry)
+    key = autotune.key("quant", site, *geometry)
     decision = autotune.decide(
-        key, "bf16", desc=desc or f"{site} {geometry}",
-        arms=autotune.QUANT_ARMS,
+        key, "bf16", desc=desc or f"{site} {geometry}", arms=QUANT_ARMS,
     )
     if decision.explore:
-        with telemetry.span("autotune.explore", site="quantize"):
-            out, bf16_s = autotune.timed(bf16_fn)
-            try:
-                _, int8_s = autotune.timed(int8_fn)
-            except Exception:
-                # an arm that cannot run loses by forfeit (bounded explore)
-                int8_s = float("inf")
-        autotune.observe(key, "bf16", bf16_s)
-        autotune.observe(key, "int8", int8_s)
+        # an int8 arm that cannot run loses by forfeit (bounded explore)
+        out = autotune.explore(
+            decision, {"bf16": bf16_fn, "int8": int8_fn},
+            site="quantize", forfeit=("int8",),
+        )
         _STATS["by_arm"]["bf16"] += 1
         return out
     if decision.arm == "int8":

@@ -24,7 +24,7 @@ prefetch depth from the budget resolution chain: explicit argument >
 buffering (computing, prefetched, and the consumer's just-released loop
 reference), so a slab is at most ``budget // 3`` bytes; the slab-size
 *fraction* is an
-autotune arm (:data:`autotune.STREAM_ARMS`) per (source-geometry
+autotune arm (:data:`STREAM_ARMS`) per (source-geometry
 fingerprint, device kind) — the tuner, not a constant, picks the slab
 that maximizes overlap, and every arm is numerically identical so tuning
 state can never change results.
@@ -68,6 +68,7 @@ from ..parallel.mesh import sanitize_comm
 __all__ = [
     "ChunkSource",
     "DEFAULT_BUDGET",
+    "STREAM_ARMS",
     "Slab",
     "StreamPass",
     "StreamPlan",
@@ -314,6 +315,14 @@ class StreamPlan(NamedTuple):
     key: Optional[Tuple[str, str]]  # tuning-table key (None: tuner off)
 
 
+# round 22: the arms are SLAB SIZES, not lowerings: "slab_full" is the
+# budget-derived maximum slab (budget//2 rows, two slabs live under double
+# buffering), "slab_half"/"slab_quarter" trade residency for pipeline
+# granularity (smaller slabs hide host reads better when the device step
+# is short).  Every arm computes the identical result — each pass runs
+# ONE arm and observes its wall, so the tuner converges on whichever slab
+# maximizes prefetch overlap for this (source geometry, device kind).
+STREAM_ARMS = ("slab_full", "slab_half", "slab_quarter")
 _ARM_DIV = {"slab_full": 1, "slab_half": 2, "slab_quarter": 4}
 
 
@@ -328,9 +337,9 @@ def _pick_arm(key: Tuple[str, str]) -> str:
     e = autotune.table().get(key)
     counts = {
         a: len(e["arms"].get(a, [])) if e else 0
-        for a in autotune.STREAM_ARMS
+        for a in STREAM_ARMS
     }
-    return min(autotune.STREAM_ARMS, key=lambda a: counts[a])
+    return min(STREAM_ARMS, key=lambda a: counts[a])
 
 
 def plan_pass(
@@ -365,13 +374,13 @@ def plan_pass(
         # geometry: rows bucket coarse (streaming length doesn't change
         # the right slab), features/dtype/mesh exact, budget bucketed to
         # a power of two so headroom jitter can't fragment the table
-        key = autotune.stream_key(
-            site, rows.bit_length(), shape[1:], str(src.np_dtype),
+        key = autotune.key(
+            "stream", site, rows.bit_length(), shape[1:], str(src.np_dtype),
             n_dev, int(b).bit_length(),
         )
         d = autotune.decide(
             key, _pick_arm(key), desc=f"stream {site} {shape}",
-            arms=autotune.STREAM_ARMS,
+            arms=STREAM_ARMS,
         )
         arm = d.arm
     slab_rows = max(n_dev, _round_down(max_rows // _ARM_DIV[arm], n_dev))

@@ -106,7 +106,6 @@ from collections import OrderedDict, deque
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional
 
-from . import envparse
 
 __all__ = [
     "annotate_program",
@@ -478,11 +477,7 @@ def export_prometheus() -> str:
 
 # ----------------------------------------------------------- flight recorder
 
-def _env_capacity() -> int:
-    return envparse.env_int("HEAT_TPU_TELEMETRY_CAPACITY", 2048)
-
-
-_RING: "deque[dict]" = deque(maxlen=_env_capacity())
+_RING: "deque[dict]" = deque(maxlen=2048)
 _SEQ = itertools.count()
 _DROPPED = [0]  # events evicted by the ring bound (list: mutable module slot)
 
@@ -946,17 +941,13 @@ _TIMING_SAMPLES = 64  # per-program reservoir backing the p50 estimate
 _TICK = itertools.count()
 
 
-def _env_sample_every() -> int:
-    return envparse.env_int("HEAT_TPU_TELEMETRY_SAMPLE", 16)
-
-
-_SAMPLE_EVERY = _env_sample_every()
+_SAMPLE_EVERY = 16
 
 
 def set_sample_every(n: int) -> int:
     """Set the ``counters``-level sampling period (every Nth executable
-    call is wall-clocked; ``HEAT_TPU_TELEMETRY_SAMPLE``, default 16).
-    Returns the previous period."""
+    call is wall-clocked; 16 unless set here).  Returns the previous
+    period."""
     global _SAMPLE_EVERY
     prev = _SAMPLE_EVERY
     _SAMPLE_EVERY = max(int(n), 1)

@@ -19,7 +19,7 @@ pad lanes stay exact zeros on the far side.
 
 Dispatch rides the tuning plane as a ``("wire_f32", "wire_int8",
 "wire_fp8")`` arm tuple per (site, geometry, device kind) —
-``core/autotune.py``'s :data:`~heat_tpu.core.autotune.WIRE_ARMS`:
+:data:`WIRE_ARMS`:
 
 - **wire_f32** — today's full-precision collective, byte-for-byte.  This
   is the *reference* arm: explore calls return its result bitwise, and
@@ -51,6 +51,7 @@ work.
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Optional, Tuple
 
@@ -60,6 +61,7 @@ from . import autotune, telemetry
 
 __all__ = [
     "QMAX",
+    "WIRE_ARMS",
     "absmax_decode",
     "absmax_encode",
     "account",
@@ -79,6 +81,15 @@ __all__ = [
 
 # absmax maps onto the quantized grid's largest representable magnitude
 QMAX = {"int8": 127.0, "fp8": 448.0}
+
+# round 17: the WIRE format of the data-movement engines.  "wire_f32" is
+# the reference arm (today's full-precision collective, byte-for-byte);
+# "wire_int8"/"wire_fp8" ship absmax-scaled low-precision tiles over the
+# all_to_all/ppermute and dequantize on landing.  Distinct from
+# quantize.QUANT_ARMS: those pick what the GEMM *computes on*, these pick
+# what the COLLECTIVE *ships* — a site can hold both kinds of entries at
+# once.
+WIRE_ARMS = ("wire_f32", "wire_int8", "wire_fp8")
 
 _VALID_MODES = ("on", "off", "int8", "fp8")
 _MODE_OVERRIDE: "list[Optional[str]]" = [None]
@@ -252,10 +263,10 @@ def choose(site: str, geometry: tuple, desc: str = ""):
         return "wire_" + m, None
     if not autotune.enabled():
         return "wire_f32", None
-    key = autotune.wire_key(site, *geometry)
+    key = autotune.key("wire", site, *geometry)
     d = autotune.decide(
         key, "wire_f32", desc=desc or f"wire {site} {geometry}",
-        arms=autotune.WIRE_ARMS,
+        arms=WIRE_ARMS,
     )
     return d.arm, d
 
@@ -275,7 +286,7 @@ def consume(site: str, geometry: tuple) -> str:
         return m
     if not autotune.enabled():
         return ""
-    key = autotune.wire_key(site, *geometry)
+    key = autotune.key("wire", site, *geometry)
     w = autotune.winner(key)
     if w in ("wire_int8", "wire_fp8"):
         return w[len("wire_"):]
@@ -284,24 +295,23 @@ def consume(site: str, geometry: tuple) -> str:
     return ""
 
 
-@telemetry.span("autotune.explore", site="wire")
 def explore(decision, run_for) -> object:
     """One explore round at a wire site: run every arm under measurement
     — ``run_for(wire_mode)`` with ``""`` (f32), ``"int8"``, ``"fp8"`` —
     and return the f32 result, so numerics never depend on tuning state.
-    An arm that cannot run (no fp8 dtype, a backend refusing the wire
-    format) loses by forfeit — inf keeps the explore phase bounded."""
-    out, f32_s = autotune.timed(run_for, "")
-    autotune.observe(decision.key, "wire_f32", f32_s)
-    for arm, wm in (("wire_int8", "int8"), ("wire_fp8", "fp8")):
-        if wm == "fp8" and not fp8_available():
-            dur = float("inf")
-        else:
-            try:
-                _, dur = autotune.timed(run_for, wm)
-            except Exception:
-                dur = float("inf")
-        autotune.observe(decision.key, arm, dur)
+    An arm that cannot run (no fp8 dtype: :func:`qdtype` raises; a
+    backend refusing the wire format) loses by forfeit."""
+    out = autotune.explore(
+        decision,
+        {
+            "wire_f32": functools.partial(run_for, ""),
+            "wire_int8": functools.partial(run_for, "int8"),
+            "wire_fp8": functools.partial(
+                run_for if fp8_available() else qdtype, "fp8"
+            ),
+        },
+        site="wire", forfeit=WIRE_ARMS[1:],
+    )
     _STATS["explores"] += 1
     _STATS["by_arm"]["wire_f32"] += 1
     return out

@@ -80,9 +80,6 @@ def _load() -> Optional[ctypes.CDLL]:
             return _lib
         if _build_failed:
             return None
-        if os.environ.get("HEAT_TPU_NO_NATIVE"):
-            _build_failed = True
-            return None
         if _needs_build() and not _build():
             _build_failed = True
             return None

@@ -12,11 +12,11 @@ Mode contract (unchanged from PR 4): ``HEAT_TPU_PALLAS`` forces
 and ``off`` elsewhere (tests run the kernels on CPU through the Pallas
 interpreter by exporting ``HEAT_TPU_PALLAS=interpret``).
 
-Per-kernel kill switches: the round-15 kernels are *autotune dispatch
-arms*, so each also honors its own env knob
-(``HEAT_TPU_KERNEL_QR`` / ``_LASSO`` = ``off``) via
-:func:`kernel_enabled` — an operator can disable one kernel family
-without touching the others or the Pallas tier as a whole.
+How a kernel enters the library: one that replaces a lowering on every
+input it accepts is chosen by :func:`mode` alone (cdist, attention,
+decode attention); one that wins on some geometries only is an arm
+(:data:`KERNEL_ARMS`) handed to ``autotune.run``, which measures both
+(qr_panel, lasso_sweep); no kernel gets an environment switch of its own.
 """
 
 from __future__ import annotations
@@ -27,9 +27,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
+    "KERNEL_ARMS",
     "LANE",
-    "kernel_enabled",
-    "kernel_mode",
     "mode",
     "pad_to",
     "sublane",
@@ -39,6 +38,12 @@ __all__ = [
 # generation this library targets (pallas_guide: min tile (8,128) f32).
 LANE = 128
 
+# round 15: Pallas kernels join the explore set as per-site arm pairs —
+# "classic" is whatever the site dispatched before this round (ROADMAP
+# item 2 predicted exactly this extension); both are measured by the same
+# explore/exploit machinery as ring-vs-GSPMD
+KERNEL_ARMS = ("classic", "kernel")
+
 
 def mode() -> str:
     """Pallas execution mode: ``tpu`` | ``interpret`` | ``off``."""
@@ -46,22 +51,6 @@ def mode() -> str:
     if forced in ("interpret", "tpu", "off"):
         return forced
     return "tpu" if jax.default_backend() == "tpu" else "off"
-
-
-def kernel_enabled(name: str) -> bool:
-    """Per-kernel kill switch: ``HEAT_TPU_KERNEL_<NAME>`` in
-    ``off/0/false/no`` disables that kernel family (it stops registering
-    as an autotune arm; dispatch is restored bit-for-bit)."""
-    raw = os.environ.get(f"HEAT_TPU_KERNEL_{name.upper()}", "").strip().lower()
-    return raw not in ("off", "0", "false", "no")
-
-
-def kernel_mode(name: str) -> str:
-    """Mode for one gated kernel family: :func:`mode` unless the
-    family's kill switch turned it ``off``."""
-    if not kernel_enabled(name):
-        return "off"
-    return mode()
 
 
 def sublane(dtype) -> int:

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_common import LANE, kernel_mode, pad_to
+from ._pallas_common import LANE, mode, pad_to
 
 __all__ = ["prepare", "sweep", "sweep_mode", "sweep_prepared"]
 
@@ -67,7 +67,7 @@ def sweep_mode(m: int, n: int, dtype, split, nshards: int) -> str:
     forced = os.environ.get("HEAT_TPU_PALLAS", "") in ("interpret", "tpu")
     if not forced and m * n < 1 << 16:
         return "off"
-    return kernel_mode("lasso")
+    return mode()
 
 
 def _sweep_kernel(m_true, n_true, lam_ref, x_ref, th_ref, r0_ref, o_ref, r_ref):

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_common import LANE, kernel_mode, pad_to
+from ._pallas_common import LANE, mode, pad_to
 
 __all__ = ["fused_gram_chol", "panel_mode"]
 
@@ -76,15 +76,15 @@ def panel_mode(m: int, n: int, dtype, mixed: bool, split, nshards: int) -> str:
         return "off"
     if n < 2 or m < n:
         return "off"
-    mode = kernel_mode("qr")
-    if mode == "off":
+    km = mode()
+    if km == "off":
         return "off"
     leaf = _leaf_panel_n(m, n)
     leaf_pad = -(-leaf // LANE) * LANE
-    limit = _MAX_N_PAD_INTERPRET if mode == "interpret" else _MAX_N_PAD_TPU
+    limit = _MAX_N_PAD_INTERPRET if km == "interpret" else _MAX_N_PAD_TPU
     if leaf_pad > limit or leaf < 2:
         return "off"
-    return mode
+    return km
 
 
 def _panel_kernel(n_true, a_ref, r_ref, rinv_ref, g_ref):

@@ -75,6 +75,7 @@ from .collectives import (
 )
 
 __all__ = [
+    "ARMS",
     "Epilogue",
     "matmul",
     "matmul_raw",
@@ -100,6 +101,10 @@ _MODE_OVERRIDE: Optional[str] = None
 # this layout/mesh (vs merely dispreferred) — the tuning plane never
 # second-guesses these
 _RING_IMPOSSIBLE = ("layout", "mesh1", "out-split")
+
+# the two lowerings of one sharded GEMM that the tuning plane measures
+# against each other ("ring" is the arm an explore returns)
+ARMS = ("ring", "gspmd")
 
 
 def set_mode(mode: Optional[str]) -> Optional[str]:
@@ -672,7 +677,7 @@ def matmul_raw(comm, a, b, lshape_a, lshape_b, a_split, b_split,
 
     Wire plane (round 17): the ``ag``/``col`` rings may ship their moving
     block absmax-quantized (int8/fp8 grid + f32 scales per contraction
-    slice) — a second tuning axis over :data:`autotune.WIRE_ARMS`,
+    slice) — a second tuning axis over :data:`wire.WIRE_ARMS`,
     consulted only once the ring-vs-GSPMD entry has stopped exploring.
     ``exact=True`` pins the f32 wire (linalg callers whose residuals are
     measured in ulps); the ``rs`` case always declines (the traveling
@@ -705,8 +710,12 @@ def matmul_raw(comm, a, b, lshape_a, lshape_b, a_split, b_split,
         and _mode() == "auto"
         and autotune.enabled()
     ):
-        tune_key = autotune.matmul_key(
-            case, out_split, m, k, n, comm.size, str(comp)
+        # the key deliberately excludes epilogue steps: the ring-vs-GSPMD
+        # verdict is a function of shape/sharding/dtype/mesh, and sharing
+        # the entry across epilogues is what lets an eager explore warm
+        # the lazy chain's consult
+        tune_key = autotune.key(
+            "matmul", case, out_split, m, k, n, comm.size, str(comp)
         )
         # plan-time staging admission from measured free HBM — refuse the
         # ring BEFORE it can RESOURCE_EXHAUST (statsless backends: None,
@@ -724,7 +733,7 @@ def matmul_raw(comm, a, b, lshape_a, lshape_b, a_split, b_split,
             return None
         tune = autotune.decide(
             tune_key, "ring" if use else "gspmd",
-            desc=f"{case} {m}x{k}x{n} {comp} S={comm.size}",
+            desc=f"{case} {m}x{k}x{n} {comp} S={comm.size}", arms=ARMS,
         )
         if tune.explore:
             use, reason = True, "autotune:explore"
@@ -797,16 +806,19 @@ def matmul_raw(comm, a, b, lshape_a, lshape_b, a_split, b_split,
             # call, K calls per geometry, then the winner runs alone.
             if hit:
                 telemetry.program_hit(ring_fp)
-            with telemetry.span("autotune.explore", site="ring_" + case):
-                out, ring_s = autotune.timed(fn, a, b, *extras)
-                gfn = _gspmd_reference(comm.mesh, spec)
-                _, gspmd_s = autotune.timed(gfn, a, b, *extras)
-            if hit:
-                # keep the roofline ledger's convention: the build call's
-                # wall (trace+compile) stays out of min/p50
-                telemetry.record_timing(ring_fp, ring_s)
-            autotune.observe(tune.key, "ring", ring_s)
-            autotune.observe(tune.key, "gspmd", gspmd_s)
+            # keep the roofline ledger's convention: the build call's
+            # wall (trace+compile) stays out of min/p50
+            out = autotune.explore(
+                tune,
+                {
+                    "ring": functools.partial(fn, a, b, *extras),
+                    "gspmd": functools.partial(
+                        _gspmd_reference(comm.mesh, spec), a, b, *extras
+                    ),
+                },
+                site="ring_" + case,
+                programs={"ring": ring_fp} if hit else None,
+            )
         elif wire_d is not None and wire_d.explore:
             # wire explore round: the f32 ring (this `fn` — wm is "")
             # and both quantized rings run under measurement; the f32
@@ -1077,7 +1089,7 @@ def _lower_chain(instrs, leaves, out_slot, lshapes, gshape, split, comm,
         and _mode() == "auto"
         and autotune.enabled()
     ):
-        key = autotune.matmul_key(case, split, m, k, n, S, str(comp))
+        key = autotune.key("matmul", case, split, m, k, n, S, str(comp))
         w = autotune.winner(key)
         if w is not None:
             use, reason = w == "ring", "autotune:cached"

@@ -590,7 +590,7 @@ def tiled_resplit(
 
     Wire plane (round 17): large float payloads may ship absmax-quantized
     int8/fp8 tiles instead of full-width words — the per-link format is
-    an autotune arm over ``autotune.WIRE_ARMS``, forced by
+    an autotune arm over ``wire.WIRE_ARMS``, forced by
     ``HEAT_TPU_WIRE``, and statically declined for integer/bool dtypes,
     sub-threshold payloads, and ``exact=True`` callers (who need the
     f32-wire bit pattern, e.g. comparison fixtures)."""

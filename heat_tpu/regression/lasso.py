@@ -169,39 +169,23 @@ class Lasso(RegressionMixin, BaseEstimator):
             return fn(Xa, yv, theta0, self.__lam, self.max_iter, self.tol)
 
         # round 15: the fused VMEM-resident sweep as a measured autotune
-        # arm — explore times BOTH lowerings (returning the classic
-        # result so coefficients never depend on tuning state), then the
-        # per-geometry winner sticks with a degradation watch
+        # arm beside the classic lowering (the reference arm)
         kmode = lasso_sweep.sweep_mode(ma, na, Xa.dtype, x.split, x.comm.size)
         if kmode != "off" and autotune.enabled():
             dt = str(Xa.dtype)
-            fp_k = telemetry.fingerprint(("lasso_sweep_fused", ma, na, dt))
-            telemetry.ensure_program(
-                fp_k, kind="kernel_lasso_sweep", ops=1,
-                flops=4.0 * ma * na,
-                hbm_bytes=float(ma * na * Xa.dtype.itemsize),
-                mesh={"devices": x.comm.size}, dtype=dt,
+            theta, _, n_iter = autotune.run(
+                autotune.key("kernel", "lasso_sweep", ma, na, dt, x.comm.size),
+                {"classic": fit_fn, "kernel": partial(fit_fn, kmode)},
+                prior="classic", desc=f"lasso {ma}x{na} {dt}",
+                site="lasso_sweep",
+                cost={"kernel": dict(
+                    sig=("lasso_sweep_fused", ma, na, dt),
+                    kind="kernel_lasso_sweep", ops=1,
+                    flops=4.0 * ma * na,
+                    hbm_bytes=float(ma * na * Xa.dtype.itemsize),
+                    mesh={"devices": x.comm.size}, dtype=dt,
+                )},
             )
-            key = autotune.kernel_key("lasso_sweep", ma, na, dt, x.comm.size)
-            d = autotune.decide(
-                key, "classic", desc=f"lasso {ma}x{na} {dt}",
-                arms=autotune.KERNEL_ARMS,
-            )
-            if d.explore:
-                with telemetry.span("autotune.explore", site="lasso_sweep"):
-                    out_c, t_c = autotune.timed(fit_fn)
-                    _, t_k = autotune.timed(fit_fn, kmode)
-                autotune.observe(key, "classic", t_c)
-                autotune.observe(key, "kernel", t_k)
-                telemetry.record_timing(fp_k, t_k)
-                theta, _, n_iter = out_c
-            elif d.arm == "kernel":
-                theta, _, n_iter = telemetry.timed_call(
-                    fp_k, fit_fn, kmode,
-                    observer=partial(autotune.observe, key, "kernel"),
-                )
-            else:
-                theta, _, n_iter = fit_fn()
         else:
             theta, _, n_iter = fit_fn()
         with telemetry.sync("lasso.n_iter"):  # one scalar per fit

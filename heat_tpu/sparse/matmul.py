@@ -36,13 +36,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..core import autotune, memtrack, telemetry, types
+from ..core import autotune, memtrack, types
 from ..core.dndarray import DNDarray, _ensure_split
 from ..parallel.collectives import shard_map_unchecked
 from ._operations import _expand_rows
 from .dcsr_matrix import DCSR_matrix
 
-__all__ = ["matmul", "matvec_program"]
+__all__ = ["SPMV_ARMS", "matmul", "matvec_program"]
 
 
 # ------------------------------------------------------------- gather arm
@@ -103,6 +103,11 @@ def _run_dense(A: DCSR_matrix, x2: jax.Array) -> jax.Array:
     return jnp.matmul(dense.larray.astype(x2.dtype), x2)
 
 
+# round 19: the sparse compute tier — "dense" is the todense() matmul (the
+# authoritative reference; explore always returns its result so numerics
+# never depend on tuning state), "gather" the jitted segment-sum CSR
+# matvec (dense wins near-full matrices, gather the sparse ones).
+SPMV_ARMS = ("dense", "gather")
 _ARM_RUNNERS = {"dense": _run_dense, "gather": _run_gather}
 
 
@@ -132,35 +137,35 @@ def _nnz_bucket(nnz: int) -> int:
 
 
 def _tuning_key(A: DCSR_matrix, k: int, dt: str):
+    """One sparsity geometry: shape, rhs columns, nnz bucket, slab
+    capacity, dtype, mesh size."""
     n, ncols = A.shape
-    return autotune.spmv_key(
-        "spmv_csr", n, ncols, k, _nnz_bucket(A.nnz), A._data.shape[1],
-        dt, A.comm.size,
+    return autotune.key(
+        "spmv", "spmv_csr", n, ncols, k, _nnz_bucket(A.nnz),
+        A._data.shape[1], dt, A.comm.size,
     )
 
 
-def _site_programs(A: DCSR_matrix, k: int, dt: str) -> dict:
-    """Ensure one cost-ledger program row per arm (``kind="spmv_*"``,
-    nnz-based FLOP/HBM models) and return their fingerprints."""
+def _site_cost(A: DCSR_matrix, k: int, dt: str) -> dict:
+    """One cost-ledger program per arm (``kind="spmv_*"``, nnz-based
+    FLOP/HBM models)."""
     n, ncols = A.shape
     nnz = A.nnz
     mesh = {"devices": A.comm.size}
-    fps = {}
-    fps["dense"] = telemetry.fingerprint(("spmv_dense", n, ncols, k, dt))
-    telemetry.ensure_program(
-        fps["dense"], kind="spmv_dense", ops=2,
-        flops=2.0 * n * ncols * k,
-        hbm_bytes=float((n * ncols + ncols * k + n * k) * 4),
-        mesh=mesh, dtype=dt,
-    )
-    fps["gather"] = telemetry.fingerprint(("spmv_gather", n, ncols, k, nnz, dt))
-    telemetry.ensure_program(
-        fps["gather"], kind="spmv_gather", ops=1,
-        flops=2.0 * nnz * k,
-        hbm_bytes=float(nnz * 8 + ncols * k * 4 + n * k * 4),
-        mesh=mesh, dtype=dt,
-    )
-    return fps
+    return {
+        "dense": dict(
+            sig=("spmv_dense", n, ncols, k, dt), kind="spmv_dense", ops=2,
+            flops=2.0 * n * ncols * k,
+            hbm_bytes=float((n * ncols + ncols * k + n * k) * 4),
+            mesh=mesh, dtype=dt,
+        ),
+        "gather": dict(
+            sig=("spmv_gather", n, ncols, k, nnz, dt), kind="spmv_gather",
+            ops=1, flops=2.0 * nnz * k,
+            hbm_bytes=float(nnz * 8 + ncols * k * 4 + n * k * 4),
+            mesh=mesh, dtype=dt,
+        ),
+    }
 
 
 def _dispatch(A: DCSR_matrix, x2: jax.Array) -> jax.Array:
@@ -178,25 +183,11 @@ def _dispatch(A: DCSR_matrix, x2: jax.Array) -> jax.Array:
         return _run_gather(A, x2)
 
     dt = str(x2.dtype)
-    fps = _site_programs(A, k, dt)
-    key = _tuning_key(A, k, dt)
-    d = autotune.decide(
-        key, "gather",
-        desc=f"spmv {n}x{ncols} nnz={A.nnz} k={k} {dt}",
-        arms=autotune.SPMV_ARMS,
-    )
-    if d.explore:
-        with telemetry.span("autotune.explore", site="spmv"):
-            out_d, t_d = autotune.timed(_run_dense, A, x2)
-            _, t_g = autotune.timed(_run_gather, A, x2)
-        autotune.observe(key, "dense", t_d)
-        autotune.observe(key, "gather", t_g)
-        telemetry.record_timing(fps["dense"], t_d)
-        telemetry.record_timing(fps["gather"], t_g)
-        return out_d  # the reference arm's result, always
-    return telemetry.timed_call(
-        fps[d.arm], _ARM_RUNNERS[d.arm], A, x2,
-        observer=partial(autotune.observe, key, d.arm),
+    return autotune.run(
+        _tuning_key(A, k, dt),
+        {arm: partial(_ARM_RUNNERS[arm], A, x2) for arm in SPMV_ARMS},
+        prior="gather", desc=f"spmv {n}x{ncols} nnz={A.nnz} k={k} {dt}",
+        site="spmv", cost=_site_cost(A, k, dt),
     )
 
 

@@ -7,11 +7,33 @@ slice computed by ``comm.chunk`` — so sharding layout bugs cannot hide behind
 a correct gather.
 """
 
+import contextlib
+import math
 import unittest
+from unittest import mock
 
 import numpy as np
 
 import heat_tpu as ht
+from heat_tpu.core import autotune
+
+
+@contextlib.contextmanager
+def scripted_clock(**arm_seconds):
+    """Inside, every wall clock the tuning plane folds into its table for
+    the named arms reads as scripted.  Explore times and the sampled
+    degradation watch both enter through ``autotune.observe``, so under
+    this clock a winner, and whether it is sent back to explore, never
+    depend on how loaded the machine is.  A forfeit (``inf``) stays one."""
+    real = autotune.observe
+
+    def observe(key, arm, dur_s):
+        if math.isfinite(dur_s):
+            dur_s = arm_seconds.get(arm, dur_s)
+        real(key, arm, dur_s)
+
+    with mock.patch.object(autotune, "observe", observe):
+        yield
 
 
 class TestCase(unittest.TestCase):

@@ -16,6 +16,7 @@ import tempfile
 import unittest
 
 import numpy as np
+import pytest
 
 import jax
 
@@ -24,7 +25,7 @@ from heat_tpu.core import autotune, fusion, memtrack, telemetry
 from heat_tpu.parallel import overlap, transport
 from heat_tpu.utils import fault
 
-from .base import TestCase
+from .base import TestCase, scripted_clock
 
 _MULTI = len(jax.local_devices()) > 1
 
@@ -203,7 +204,7 @@ class TestExploreExploit(TestCase):
 
     @unittest.skipUnless(_MULTI, "needs a multi-device mesh")
     def test_explore_then_sticky(self):
-        with _Tuned():
+        with _Tuned(), scripted_clock(ring=0.001, gspmd=0.002):
             a, b = _mm_pair()
             k = autotune.explore_k()
             with fusion.fuse(False):
@@ -220,7 +221,7 @@ class TestExploreExploit(TestCase):
             (key, entry), = autotune.table().items()
             self.assertGreaterEqual(len(entry["arms"]["ring"]), k)
             self.assertGreaterEqual(len(entry["arms"]["gspmd"]), k)
-            self.assertIn(entry["winner"], autotune.ARMS)
+            self.assertEqual(entry["winner"], "ring")
             self.assertEqual(entry["best_s"], min(entry["arms"][entry["winner"]]))
             # the flight recorder saw the explores and the sticky phase
             sources = [e["source"] for e in _decision_events()]
@@ -300,7 +301,7 @@ class TestExploreExploit(TestCase):
         with _Tuned():
             key = ("fp_degrade", "test:kind")
             for _ in range(autotune.explore_k()):
-                d = autotune.decide(key, "ring")
+                d = autotune.decide(key, "ring", arms=overlap.ARMS)
                 self.assertTrue(d.explore)
                 autotune.observe(key, "ring", 0.001)
                 autotune.observe(key, "gspmd", 0.002)
@@ -329,8 +330,8 @@ class TestPersistence(TestCase):
         slow = {"ring": 0.002, "gspmd": 0.001}
         slow[winner] = 0.0005
         for _ in range(autotune.explore_k()):
-            autotune.decide(key, "ring")
-            for arm in autotune.ARMS:
+            autotune.decide(key, "ring", arms=overlap.ARMS)
+            for arm in overlap.ARMS:
                 autotune.observe(key, arm, slow[arm])
 
     def test_save_load_roundtrip(self):
@@ -354,7 +355,7 @@ class TestPersistence(TestCase):
             self.assertEqual(autotune.winner(k1), "ring")
             self.assertEqual(autotune.winner(k2), "gspmd")
             # loaded entries serve decisions without exploring
-            d = autotune.decide(k1, "gspmd")
+            d = autotune.decide(k1, "gspmd", arms=overlap.ARMS)
             self.assertEqual((d.arm, d.source, d.explore), ("ring", "cached", False))
             row = [r for r in autotune.report()["rows"] if r["fingerprint"] == "fp_one"][0]
             self.assertEqual(row["source"], "cached")
@@ -405,13 +406,13 @@ class TestPersistence(TestCase):
         with tempfile.TemporaryDirectory() as td:
             path = os.path.join(td, "tune.json")
             a, b = _mm_pair()
-            with _Tuned():
+            with _Tuned(), scripted_clock(ring=0.001, gspmd=0.002):
                 with fusion.fuse(False):
                     for _ in range(autotune.explore_k() + 1):
                         _ = ht.matmul(a, b).larray
                 self.assertGreater(autotune.stats()["explores"], 0)
                 autotune.save(path)
-            with _Tuned():
+            with _Tuned(), scripted_clock(ring=0.001, gspmd=0.002):
                 autotune.load(path)
                 with fusion.fuse(False):
                     for _ in range(3):
@@ -502,7 +503,7 @@ class TestOpsSurface(TestCase):
     def _seed_one(self):
         key = ("fp_prom", "test:kind")
         for _ in range(autotune.explore_k()):
-            autotune.decide(key, "ring")
+            autotune.decide(key, "ring", arms=overlap.ARMS)
             autotune.observe(key, "ring", 0.001)
             autotune.observe(key, "gspmd", 0.002)
 
@@ -518,18 +519,6 @@ class TestOpsSurface(TestCase):
             self.assertEqual(row["ring_min_s"], 0.001)
             self.assertEqual(row["gspmd_min_s"], 0.002)
             self.assertEqual(rep["stats"]["resolved"], 1)
-
-    def test_explore_k_env(self):
-        self.assertEqual(autotune.explore_k(), 3)
-        os.environ["HEAT_TPU_AUTOTUNE_EXPLORE"] = "5"
-        try:
-            self.assertEqual(autotune.explore_k(), 5)
-            os.environ["HEAT_TPU_AUTOTUNE_EXPLORE"] = "zero"
-            with self.assertRaises(ValueError):
-                autotune.explore_k()
-        finally:
-            del os.environ["HEAT_TPU_AUTOTUNE_EXPLORE"]
-
 
 class TestMerge(TestCase):
     """`autotune.merge` (ISSUE 14 satellite): fleet caches fold into one
@@ -660,6 +649,179 @@ class TestMerge(TestCase):
             self.assertEqual(
                 autotune.winner(("fp_w", "cpu")), "wire_int8"
             )
+
+
+# ------------------------------------------------------------------ the seam
+# autotune.key / autotune.run / autotune.explore: the one place the
+# explore/exploit protocol is written.  Plain pytest functions, so the
+# cases are parametrised and each counts.
+
+
+@pytest.mark.parametrize(
+    "family, site, geometry, want",
+    [
+        # fingerprints printed by the parent commit's kernel_key / quant_key
+        # / spmv_key / wire_key / stream_key / matmul_key for these arguments:
+        # a HEAT_TPU_AUTOTUNE_CACHE file written before the seam stays valid
+        ("kernel", "qr_panel", (4096, 256, "float32", True, 1), "bece932f73e2"),
+        ("quant", "linear", (64, 128, 256, "int8", 8), "29091f48d567"),
+        ("spmv", "spmv_csr", (1000, 1000, 4, 13, 128, "float32", 8), "0ca49b9d7329"),
+        ("wire", "resplit", ((512, 64), 0, 1, "float32", 8), "eca15d039228"),
+        ("stream", "kmeans_fit", (100000, 64, "float32", 8, 27), "595c6a08d65b"),
+        # the parent's matmul_key(case, out_split, m, k, n, size, comp)
+        ("matmul", "ag", (0, 512, 256, 128, 8, "float32"), "cbcb26037aa9"),
+    ],
+)
+def test_key_is_the_parents_fingerprint(family, site, geometry, want):
+    assert autotune.key(family, site, *geometry) == (want, autotune.device_kind())
+
+
+class _Arm:
+    """One lowering under a scripted clock: counts its runs, returns its
+    own array, may raise."""
+
+    def __init__(self, value, raises=None):
+        self.out = jax.numpy.full(4, value)
+        self.raises = raises
+        self.runs = 0
+
+    def __call__(self):
+        self.runs += 1
+        if self.raises is not None:
+            raise self.raises
+        return self.out
+
+
+_BOOM = RuntimeError("arm cannot run")
+
+# name: (arm -> (scripted seconds, exception or None), forfeit, cost arm,
+#        expected winner or the exception that must propagate)
+_SEAM_CASES = {
+    "reference_wins": ({"ref": (1.0, None), "alt": (2.0, None)}, (), None, "ref"),
+    "other_arm_wins": ({"ref": (2.0, None), "alt": (1.0, None)}, (), None, "alt"),
+    "three_arms_last_wins": (
+        {"ref": (3.0, None), "alt": (2.0, None), "alt2": (1.0, None)},
+        (), None, "alt2",
+    ),
+    "forfeiting_arm_gets_inf_and_loses": (
+        {"ref": (2.0, None), "alt": (1.0, _BOOM)}, ("alt",), None, "ref",
+    ),
+    "forfeit_named_arm_that_runs_can_win": (
+        {"ref": (2.0, None), "alt": (1.0, None)}, ("alt",), None, "alt",
+    ),
+    "other_exception_propagates": (
+        {"ref": (2.0, None), "alt": (1.0, _BOOM)}, (), None, _BOOM,
+    ),
+    "winner_with_a_cost_is_ledgered_and_watched": (
+        {"ref": (2.0, None), "alt": (1.0, None)}, (), "alt", "alt",
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SEAM_CASES))
+def test_seam_protocol(case, monkeypatch):
+    script, forfeit, cost_arm, want = _SEAM_CASES[case]
+    arms = {a: _Arm(i, exc) for i, (a, (_, exc)) in enumerate(script.items())}
+    by_thunk = {id(fn): a for a, fn in arms.items()}
+    timed_calls = []
+
+    def timed(fn, *args):  # the scripted clock: the one explore-phase timer
+        timed_calls.append(by_thunk[id(fn)])
+        return fn(*args), script[by_thunk[id(fn)]][0]
+
+    monkeypatch.setattr(autotune, "timed", timed)
+    cost = None
+    if cost_arm:
+        cost = {cost_arm: dict(sig=("seam_probe", case), kind="seam_probe",
+                               ops=1, flops=8.0, hbm_bytes=16.0)}
+    key = autotune.key("probe", case, 4)
+    k = autotune.explore_k()
+
+    def call():
+        return autotune.run(
+            key, arms, prior="ref", desc=case, site="probe",
+            cost=cost, forfeit=forfeit,
+        )
+
+    with _Tuned():
+        if isinstance(want, Exception):
+            with pytest.raises(RuntimeError, match="arm cannot run"):
+                call()
+            # a poisoned round is no measurement: nothing was observed
+            assert autotune.table()[key]["arms"] == {"ref": [], "alt": []}
+            assert autotune.stats()["explores"] == 1
+            return
+        for i in range(1, k + 1):
+            out = call()
+            # explore: every arm timed once, the REFERENCE result returned
+            assert out is arms["ref"].out
+            assert timed_calls == list(arms) * i
+            assert {a: len(d) for a, d in autotune.table()[key]["arms"].items()} \
+                == dict.fromkeys(arms, i)
+        entry = autotune.table()[key]
+        assert entry["winner"] == want
+        for a, (secs, exc) in script.items():
+            assert entry["arms"][a] == [float("inf") if exc else secs] * k
+        spans = [e for e in telemetry.events("span_begin")
+                 if e["name"] == "autotune.explore"]
+        assert [e["site"] for e in spans] == ["probe"] * k
+        # exploit: the winner alone, no timer of the explore phase
+        runs = {a: fn.runs for a, fn in arms.items()}
+        for _ in range(2):
+            assert call() is arms[want].out
+        assert timed_calls == list(arms) * k
+        runs[want] += 2
+        assert {a: fn.runs for a, fn in arms.items()} == runs
+        st = autotune.stats()
+        assert (st["explores"], st["cache_hits"], st["decisions"]) == (k, 2, k + 2)
+        if cost_arm:
+            (prog,) = [p for p in telemetry.programs() if p["kind"] == "seam_probe"]
+            # k explore times and two watched runs (events level: all sampled)
+            assert prog["calls"] == k + 2
+            # the watch is alive: two slow samples send the winner back
+            with scripted_clock(**{want: 10.0}):
+                call(), call()
+            assert autotune.table()[key]["winner"] is None
+            assert autotune.stats()["re_explores"] == 1
+
+
+@pytest.mark.parametrize(
+    "alien_arms, alien_winner",
+    [
+        ({"dense": [0.001] * 3, "gather": [0.002] * 3}, "dense"),
+        ({"classic": [0.002] * 3, "kernel": [0.001] * 3, "turbo": [1e-9] * 3}, "turbo"),
+    ],
+    ids=["another_familys_arms", "a_superset_with_an_unknown_winner"],
+)
+def test_planted_entry_with_other_arms_is_never_served(alien_arms, alien_winner, tmp_path):
+    """A cache file is input from outside the program.  load() cannot
+    know which arms a site dispatches; the site can: an entry under its
+    key that names any other arm set is dropped at the consult, explored
+    afresh, and leaves a ``fallback`` event."""
+    key = autotune.key("kernel", "probe_site", 64, 8)
+    path = tmp_path / "planted.json"
+    path.write_text(json.dumps({
+        "version": autotune.CACHE_VERSION,
+        "library": ht.__version__,
+        "entries": [{"fingerprint": key[0], "device_kind": key[1],
+                     "winner": alien_winner, "best_s": 1e-9, "desc": "planted",
+                     "arms": alien_arms}],
+    }))
+    arms = {"classic": _Arm(0), "kernel": _Arm(1)}
+    with _Tuned():
+        assert autotune.load(path) == 1
+        assert autotune.winner(key) == alien_winner
+        out = autotune.run(key, arms, prior="classic", desc="probe", site="probe")
+        assert out is arms["classic"].out
+        assert (arms["classic"].runs, arms["kernel"].runs) == (1, 1)
+        entry = autotune.table()[key]
+        assert set(entry["arms"]) == {"classic", "kernel"}
+        assert entry["winner"] is None and not entry["loaded"]
+        st = autotune.stats()
+        assert (st["fallbacks"], st["explores"], st["cache_hits"]) == (1, 1, 1)
+        (ev,) = [e for e in telemetry.events("fallback")
+                 if e.get("site") == "autotune.decide"]
+        assert ev["fingerprint"] == key[0] and alien_winner in ev["error"]
 
 
 if __name__ == "__main__":

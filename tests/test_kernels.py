@@ -6,15 +6,18 @@ Everything runs on the CPU mesh through Pallas interpret mode
 exercised with no TPU: value equality against the classic lowerings,
 the autotune arm-registration laws (explore-then-sticky, safe decline
 on unsupported layouts, ``HEAT_TPU_AUTOTUNE=off`` restoring today's
-dispatch bit-for-bit), and the per-kernel kill switches.  The suite
+dispatch bit-for-bit), and ``HEAT_TPU_PALLAS=off`` restoring the classic
+lowering with no kernel arm registered.  The suite
 default keeps autotune off (conftest); kernel-arm tests opt back in
 via the API, mirroring tests/test_autotune.py."""
 
+import json
 import os
 import tempfile
 import unittest
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +29,7 @@ from heat_tpu.ops import _pallas_common, lasso_sweep, qr_panel
 from heat_tpu.regression import lasso as lasso_mod
 from heat_tpu.regression.lasso import Lasso, _cd_sweep
 
-from .base import TestCase
+from .base import TestCase, scripted_clock
 
 _MULTI = len(jax.local_devices()) > 1
 
@@ -83,8 +86,8 @@ def _table_rows():
 
 
 class TestPallasCommon(TestCase):
-    """Satellite: the shared kernel plumbing all six kernels route
-    through (mode selection, kill switches, tile geometry helpers)."""
+    """Satellite: the shared kernel plumbing all the kernels route
+    through (mode selection, tile geometry helpers)."""
 
     def test_mode_forced_by_env(self):
         with _Interpret("interpret"):
@@ -96,20 +99,6 @@ class TestPallasCommon(TestCase):
         with _Interpret(None):
             # CPU backend, nothing forced: Pallas tier is off
             self.assertEqual(_pallas_common.mode(), "off")
-
-    def test_kernel_kill_switches(self):
-        for name in ("qr", "lasso"):
-            knob = f"HEAT_TPU_KERNEL_{name.upper()}"
-            self.assertTrue(_pallas_common.kernel_enabled(name))
-            os.environ[knob] = "off"
-            try:
-                self.assertFalse(_pallas_common.kernel_enabled(name))
-                with _Interpret("interpret"):
-                    self.assertEqual(_pallas_common.kernel_mode(name), "off")
-            finally:
-                del os.environ[knob]
-        with _Interpret("interpret"):
-            self.assertEqual(_pallas_common.kernel_mode("qr"), "interpret")
 
     def test_sublane_and_pad(self):
         self.assertEqual(_pallas_common.sublane(jnp.dtype(jnp.float32)), 8)
@@ -208,15 +197,15 @@ class TestQRPanelKernel(TestCase):
         rng = np.random.default_rng(14)
         for shape in [(512, 64), (256, 256)]:  # CholeskyQR2 and blocked BCGS2
             a_np = rng.standard_normal(shape).astype(np.float32)
-            with _Interpret(), _Tuned():
+            with _Interpret(), _Tuned(), scripted_clock(classic=0.002, kernel=0.001):
                 a = ht.array(a_np)
                 for _ in range(7):
                     q, r = ht.linalg.qr(a)
                 rows = [r_ for r_ in _table_rows() if r_[2] == ("classic", "kernel")]
                 self.assertTrue(rows, _table_rows())
                 self.assertEqual(rows[0][3], {"classic": 3, "kernel": 3})
-                self.assertIn(rows[0][1], ("classic", "kernel"))
-                # value quality regardless of winning arm
+                self.assertEqual(rows[0][1], "kernel")
+                # value quality of the winning (kernel) arm
                 self.assertLess(float(orthogonality_defect(q).larray), 3e-4)
                 recon = np.asarray(q.larray) @ np.asarray(r.larray)
                 np.testing.assert_allclose(recon, a_np, rtol=1e-3, atol=1e-3)
@@ -251,20 +240,6 @@ class TestQRPanelKernel(TestCase):
         np.testing.assert_allclose(
             np.asarray(r_k), np.asarray(r_c), rtol=1e-4, atol=1e-4
         )
-
-    def test_kill_switch(self):
-        rng = np.random.default_rng(17)
-        a = ht.array(rng.standard_normal((512, 64)).astype(np.float32))
-        os.environ["HEAT_TPU_KERNEL_QR"] = "off"
-        try:
-            with _Interpret(), _Tuned():
-                ht.linalg.qr(a)
-                self.assertEqual(
-                    [r for r in _table_rows() if r[2] == ("classic", "kernel")],
-                    [],
-                )
-        finally:
-            del os.environ["HEAT_TPU_KERNEL_QR"]
 
 
 class TestLassoSweepKernel(TestCase):
@@ -310,7 +285,7 @@ class TestLassoSweepKernel(TestCase):
 
     def test_fit_kernel_arm_explore_then_sticky(self):
         xa, ya = self._problem()
-        with _Interpret(), _Tuned():
+        with _Interpret(), _Tuned(), scripted_clock(classic=0.002, kernel=0.001):
             thetas = []
             for _ in range(7):
                 est = Lasso(lam=0.05, max_iter=100, tol=1e-6)
@@ -350,19 +325,6 @@ class TestLassoSweepKernel(TestCase):
             np.asarray(th_k), np.asarray(th_c), rtol=1e-4, atol=1e-5
         )
 
-    def test_kill_switch(self):
-        xa, ya = self._problem(seed=22)
-        os.environ["HEAT_TPU_KERNEL_LASSO"] = "off"
-        try:
-            with _Interpret(), _Tuned():
-                Lasso(lam=0.05).fit(xa, ya)
-                self.assertEqual(
-                    [r for r in _table_rows() if r[2] == ("classic", "kernel")],
-                    [],
-                )
-        finally:
-            del os.environ["HEAT_TPU_KERNEL_LASSO"]
-
 
 class TestKernelArmPersistence(TestCase):
     """Kernel arms ride the same versioned warm-start cache as
@@ -370,11 +332,11 @@ class TestKernelArmPersistence(TestCase):
 
     def test_save_load_roundtrip_kernel_arms(self):
         with _Tuned():
-            key = autotune.kernel_key("qr_panel", 512, 64, "float32", True, 1)
+            key = autotune.key("kernel", "qr_panel", 512, 64, "float32", True, 1)
             # decide seeds the entry with the kernel arm set; observes
             # then fill both arms to resolution
             autotune.decide(
-                key, "classic", desc="qr", arms=autotune.KERNEL_ARMS
+                key, "classic", desc="qr", arms=_pallas_common.KERNEL_ARMS
             )
             for i in range(3):
                 autotune.observe(key, "classic", 0.01 + i * 1e-4)
@@ -388,23 +350,101 @@ class TestKernelArmPersistence(TestCase):
                 self.assertGreaterEqual(autotune.load(path), 1)
                 self.assertEqual(autotune.winner(key), "kernel")
                 ent = autotune._TABLE[key]
-                self.assertEqual(tuple(ent["arms"]), autotune.KERNEL_ARMS)
+                self.assertEqual(tuple(ent["arms"]), _pallas_common.KERNEL_ARMS)
 
     def test_report_carries_kernel_rows(self):
         with _Tuned():
-            key = autotune.kernel_key("lasso_sweep", 200, 31, "float32", 1)
-            autotune.decide(key, "classic", desc="lasso", arms=autotune.KERNEL_ARMS)
+            key = autotune.key("kernel", "lasso_sweep", 200, 31, "float32", 1)
+            autotune.decide(key, "classic", desc="lasso", arms=_pallas_common.KERNEL_ARMS)
             for i in range(3):
                 autotune.observe(key, "classic", 0.01)
                 autotune.observe(key, "kernel", 0.002)
             rows = [
                 r for r in autotune.report()["rows"]
-                if tuple(r.get("arms", ())) == autotune.KERNEL_ARMS
+                if tuple(r.get("arms", ())) == _pallas_common.KERNEL_ARMS
             ]
             self.assertTrue(rows)
             self.assertEqual(rows[0]["winner"], "kernel")
             self.assertIn("classic_min_s", rows[0])
             self.assertIn("kernel_min_s", rows[0])
+
+
+def _qr_factors(shape, seed):
+    a = ht.array(
+        np.random.default_rng(seed).standard_normal(shape).astype(np.float32)
+    )
+    q, r = ht.linalg.qr(a)
+    return np.asarray(q.larray), np.asarray(r.larray)
+
+
+def _lasso_theta(seed):
+    rng = np.random.default_rng(seed)
+    X = rng.standard_normal((200, 30)).astype(np.float32)
+    y = (X[:, 0] - X[:, 1]).astype(np.float32).reshape(-1, 1)
+    est = Lasso(lam=0.05, max_iter=100, tol=1e-6)
+    est.fit(ht.array(X), ht.array(y))
+    return (np.asarray(est.theta.larray),)
+
+
+@pytest.mark.parametrize(
+    "site",
+    [
+        lambda: _qr_factors((512, 64), 17),    # CholeskyQR2
+        lambda: _qr_factors((256, 256), 23),   # blocked BCGS2
+        lambda: _lasso_theta(22),
+    ],
+    ids=["qr_tall", "qr_blocked", "lasso"],
+)
+def test_pallas_off_restores_classic_and_registers_no_arm(site):
+    """``HEAT_TPU_PALLAS=off`` is the one switch in front of the kernel
+    tier: with the tuning plane live it leaves the classic lowering bit
+    for bit, and no classic/kernel entry, decision or explore."""
+    with _Interpret("off"):
+        want = site()  # autotune off (suite default): pure classic
+        with _Tuned():
+            got = site()
+            assert [r for r in _table_rows() if r[2] == ("classic", "kernel")] == []
+            assert autotune.stats()["explores"] == 0
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    # the same call with the tier on does register the arm
+    with _Interpret(), _Tuned():
+        site()
+        assert [r[2] for r in _table_rows()] == [("classic", "kernel")]
+
+
+# written by the parent commit's `autotune.save` after four
+# `ht.linalg.qr` of a (512, 64) float32 under HEAT_TPU_PALLAS=interpret
+_PARENT_TABLE = {
+    "version": 1,
+    "library": "0.1.0-dev",
+    "entries": [{
+        "fingerprint": "e640005b82ef",
+        "device_kind": "cpu:cpu",
+        "desc": "qr 512x64 float32",
+        "winner": "classic",
+        "best_s": 0.013684708013897762,
+        "arms": {
+            "classic": [2.1315906069939956, 0.027628385985735804,
+                        0.013684708013897762],
+            "kernel": [0.6019594539829995, 0.0172544400265906,
+                       0.015469934995053336],
+        },
+    }],
+}
+
+
+def test_table_saved_by_the_parent_serves_without_an_explore(tmp_path):
+    """The five families' keys are the parent's, so a warm-start file
+    from before the seam still warms: loaded, then served at the site."""
+    doc = dict(_PARENT_TABLE, library=ht.__version__)
+    path = tmp_path / "tune.json"
+    path.write_text(json.dumps(doc))
+    with _Interpret(), _Tuned():
+        assert autotune.load(path) == 1
+        _qr_factors((512, 64), 0)
+        st = autotune.stats()
+        assert (st["explores"], st["cache_hits"], st["fallbacks"]) == (0, 1, 0)
 
 
 class TestMosaicLowering(TestCase):

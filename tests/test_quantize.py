@@ -252,7 +252,7 @@ class TestArmDispatch(TestCase):
             out = quantize.matmul_quantized(x, qw.T)  # first call: explore
             rows = [
                 r for r in autotune.report()["rows"]
-                if tuple(r.get("arms", ())) == autotune.QUANT_ARMS
+                if tuple(r.get("arms", ())) == quantize.QUANT_ARMS
             ]
             self.assertTrue(rows, autotune.report()["rows"])
         with _Tuned():  # fresh table: the same inner-dispatch route
@@ -272,8 +272,8 @@ class TestArmDispatch(TestCase):
 
     def test_resolved_winner_runs_alone(self):
         with _Tuned():
-            key = autotune.quant_key("law2", 7)
-            autotune.decide(key, "bf16", desc="law2", arms=autotune.QUANT_ARMS)
+            key = autotune.key("quant", "law2", 7)
+            autotune.decide(key, "bf16", desc="law2", arms=quantize.QUANT_ARMS)
             for i in range(autotune.explore_k()):
                 autotune.observe(key, "bf16", 0.010 + i * 1e-4)
                 autotune.observe(key, "int8", 0.001 + i * 1e-4)
@@ -294,8 +294,8 @@ class TestArmDispatch(TestCase):
 
     def test_int8_arm_error_falls_back_to_bf16(self):
         with _Tuned():
-            key = autotune.quant_key("law3", 7)
-            autotune.decide(key, "bf16", desc="law3", arms=autotune.QUANT_ARMS)
+            key = autotune.key("quant", "law3", 7)
+            autotune.decide(key, "bf16", desc="law3", arms=quantize.QUANT_ARMS)
             for i in range(autotune.explore_k()):
                 autotune.observe(key, "bf16", 0.010)
                 autotune.observe(key, "int8", 0.001)
@@ -324,7 +324,7 @@ class TestArmDispatch(TestCase):
             jax.block_until_ready(y)  # ht: HT002 ok — test fence
             quant_rows = [
                 r for r in autotune.report()["rows"]
-                if tuple(r.get("arms", ())) == autotune.QUANT_ARMS
+                if tuple(r.get("arms", ())) == quantize.QUANT_ARMS
             ]
             self.assertEqual(quant_rows, [])
 
@@ -334,8 +334,8 @@ class TestPersistence(TestCase):
 
     def test_save_load_roundtrip_quant_arms(self):
         with _Tuned():
-            key = autotune.quant_key("linear", 64, 128, 256, 8, "float32")
-            autotune.decide(key, "bf16", desc="q", arms=autotune.QUANT_ARMS)
+            key = autotune.key("quant", "linear", 64, 128, 256, 8, "float32")
+            autotune.decide(key, "bf16", desc="q", arms=quantize.QUANT_ARMS)
             for i in range(autotune.explore_k()):
                 autotune.observe(key, "bf16", 0.01 + i * 1e-4)
                 autotune.observe(key, "int8", 0.002 + i * 1e-4)
@@ -348,7 +348,7 @@ class TestPersistence(TestCase):
                 self.assertGreaterEqual(autotune.load(path), 1)
                 self.assertEqual(autotune.winner(key), "int8")
                 self.assertEqual(
-                    tuple(autotune._TABLE[key]["arms"]), autotune.QUANT_ARMS
+                    tuple(autotune._TABLE[key]["arms"]), quantize.QUANT_ARMS
                 )
 
 

@@ -411,15 +411,20 @@ class TestStallShedding(TestCase):
         det.start()
         stalled = threading.Event()
         det.subscribe(lambda kind, info: stalled.set() if kind == "stall" else None)
+        inj = _HeldStall("fusion.exec")
+        # quiet by right: the warm-up compile and the hand-over to the
+        # worker (a stall fired there would leave `stalled` set before
+        # the mesh is wedged, or shed the request that is to wedge it)
+        det.pause()
         try:
             eng.register(
                 "exp", predict=lambda x: ht.exp(x), feature_dim=8,
                 min_bucket=8, max_batch=8, warm=True,
             )
-            det.beat()
-            inj = fault.FaultInjector().stall_in("fusion.exec", 0.8, times=1)
             with fault.injected(inj):
                 wedged = eng.submit("exp", np.ones((2, 8), dtype=np.float32))
+                self.assertTrue(inj.entered.wait(30.0), "stall never injected")
+                det.resume()  # the clock starts with the mesh already wedged
                 self.assertTrue(stalled.wait(5.0), "stall never detected")
                 with self.assertRaisesRegex(
                     RequestRejected, r"serving request rejected \(stalled\)"
@@ -427,6 +432,7 @@ class TestStallShedding(TestCase):
                     eng.submit("exp", np.ones((1, 8), dtype=np.float32))
                 self.assertEqual(ctx.exception.reason, "stalled")
                 self.assertIsNotNone(ctx.exception.retry_after_s)
+                inj.release.set()
                 # the wedged request itself completes — shed, not lost
                 out = wedged.result(30)
                 self.assertEqual(np.asarray(out).shape[0], 2)
@@ -440,8 +446,27 @@ class TestStallShedding(TestCase):
             out = eng.predict("exp", np.ones((1, 8), dtype=np.float32), timeout=30)
             self.assertEqual(np.asarray(out).shape[0], 1)
         finally:
+            inj.release.set()
             det.stop()
             eng.close()
+
+
+class _HeldStall(fault.FaultInjector):
+    """One stall at ``site`` that lasts until the test releases it (a
+    wedged collective does not end on a timer either), so it outlasts
+    its own detection by construction, however loaded the machine is."""
+
+    def __init__(self, site):
+        super().__init__()
+        self.site = site
+        self.entered = threading.Event()
+        self.release = threading.Event()
+
+    def fire_site(self, site):
+        if site == self.site and not self.entered.is_set():
+            self.fired.append(("stall", site))
+            self.entered.set()
+            self.release.wait(60.0)
 
 
 class TestSLOAndDeadlines(TestCase):

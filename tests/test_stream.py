@@ -332,7 +332,7 @@ class TestAutotunedSlabArm(TestCase):
             data = np.zeros((256, 8), np.float32)
             src = stream.open_source(data)
             arms = []
-            for _ in range(len(autotune.STREAM_ARMS)):
+            for _ in range(len(stream.STREAM_ARMS)):
                 sp = stream.StreamPass(src, site="arm_test",
                                        budget=16 << 10)
                 for slab in sp:
@@ -340,10 +340,10 @@ class TestAutotunedSlabArm(TestCase):
                 stream.finish_pass(sp)
                 arms.append(sp.plan.arm)
             self.assertEqual(sorted(arms),
-                             sorted(autotune.STREAM_ARMS))
+                             sorted(stream.STREAM_ARMS))
             key = sp.plan.key
             entry = autotune.table()[key]
-            for arm in autotune.STREAM_ARMS:
+            for arm in stream.STREAM_ARMS:
                 self.assertEqual(len(entry["arms"][arm]), 1)
         finally:
             autotune.set_enabled(prev)

@@ -829,12 +829,14 @@ class TestLayerSpans(TestCase):
         prev = autotune.set_enabled(True)
         try:
             with _EventsLevel():
-                key = autotune.kernel_key("probe_site", 8, 8, "float32")
+                key = autotune.key("kernel", "probe_site", 8, 8, "float32")
+                ones = lambda: jax.numpy.ones(4)  # noqa: E731
                 with telemetry.span("probe.caller"):
-                    d = autotune.decide(key, "classic", arms=autotune.KERNEL_ARMS)
+                    d = autotune.decide(key, "classic", arms=("classic", "kernel"))
                     self.assertTrue(d.explore)
-                    with telemetry.span("autotune.explore", site="probe_site"):
-                        autotune.timed(lambda: jax.numpy.ones(4))
+                    autotune.explore(
+                        d, {"classic": ones, "kernel": ones}, site="probe_site"
+                    )
                 tree = self._tree()
         finally:
             autotune.set_enabled(prev)

@@ -500,7 +500,7 @@ class TestTunedWire(TestCase):
     def _wire_rows(self):
         return [
             r for r in autotune.report()["rows"]
-            if set(r["arms"]) == set(autotune.WIRE_ARMS)
+            if set(r["arms"]) == set(wire.WIRE_ARMS)
         ]
 
     def test_explore_returns_f32_then_resolves(self):
@@ -516,8 +516,8 @@ class TestTunedWire(TestCase):
                 self.assertTrue(np.array_equal(out, ref))
             self.assertEqual(wire.stats()["explores"], k)
             (row,) = self._wire_rows()
-            self.assertIn(row["winner"], autotune.WIRE_ARMS)
-            for arm in autotune.WIRE_ARMS:
+            self.assertIn(row["winner"], wire.WIRE_ARMS)
+            for arm in wire.WIRE_ARMS:
                 if arm == "wire_fp8" and not wire.fp8_available():
                     continue
                 self.assertGreaterEqual(row[arm + "_samples"], k)
