@@ -88,7 +88,7 @@ import numpy as np  # noqa: E402
 from heat_tpu import native, serving  # noqa: E402
 from heat_tpu.core import autotune, fusion, telemetry, wire  # noqa: E402
 from heat_tpu.core.dndarray import DNDarray  # noqa: E402
-from heat_tpu.ops import _pallas_common, attention, lasso_sweep, qr_panel  # noqa: E402
+from heat_tpu.ops import _pallas_common, attention, lasso_sweep, lloyd_pass, qr_panel  # noqa: E402
 from heat_tpu.ops import cdist as cdist_kernel  # noqa: E402
 from heat_tpu.parallel import overlap, transport  # noqa: E402
 from heat_tpu.utils import compile_cache  # noqa: E402
@@ -346,6 +346,8 @@ def st_kmeans():
         return est.cluster_centers_
 
     centers, first, steady = first_and_steady(fit)
+    bodies = {e.get("lloyd") for e in telemetry.events("span_end") if e["name"] == "kmeans.fit"}
+    check(bodies == {"fused"}, f"Lloyd bodies that ran: {bodies}, wanted the fused pass alone")
     check(centers.shape == (k, f), f"centers shape {centers.shape}")
     check(bool(np.isfinite(centers.numpy()).all()), "non-finite centers")
     del data
@@ -602,6 +604,20 @@ def st_kernels():
         - 2.0 * jnp.matmul(x, y.T, precision=HI), 0.0))
     info["cdist"] = [round(first, 3), round(steady, 4),
                      close(got, want, 2e-3, "cdist kernel vs expansion")]
+
+    # the fused Lloyd pass over rows that end inside a tile
+    n, f, k = SZ["kmeans"]
+    n = min(n, 41_037)  # two and a half tiles of 16,384 rows at f = 64
+    rows = jnp.asarray(rng.standard_normal((n, f)), jnp.float32)
+    cen = rows[:k] + 0.5
+    fused = jax.jit(lambda v, c: lloyd_pass._pass_pallas(v.T, c, n, interpret=interp))
+    got, first, steady = first_and_steady(lambda: fused(rows, cen))
+    want = lloyd_pass._pass_jnp(rows.T, cen, n)
+    # a row or two may sit on a tie that the two products' rounding breaks
+    check(float(jnp.abs(got[1] - want[1]).max()) <= 2, f"lloyd_pass counts {got[1]} vs {want[1]}")
+    info["lloyd_pass"] = [round(first, 3), round(steady, 4), max(
+        close(got[0] / n, want[0] / n, 1e-4, "lloyd_pass sums vs jax.numpy"),
+        close(got[2] / n, want[2] / n, 1e-4, "lloyd_pass inertia vs jax.numpy"))]
 
     m, n = SZ["qr_panel"]
     mode = qr_panel.panel_mode(m, n, jnp.float32, False, None, 1)

@@ -5,6 +5,17 @@ the masked sums (kmeans.py:73-100).  Here the whole iteration — distance
 matrix (quadratic expansion on the MXU), argmin, one-hot count/sum matmuls —
 is a single jitted XLA program with one fused cross-device reduction
 (SURVEY.md §3.4), the benchmark north-star workload.
+
+On unpacked rows an iteration reads the data **once**: where the Pallas tier
+is on (``ops/_pallas_common.mode()``) and the input is one the kernel takes
+(:func:`_fused_rows`: float32 or bfloat16, at most 128 clusters, a width of
+8 to 512 that is no multiple of 128, rows on one device or ``split=0`` over
+the mesh), :func:`_lloyd_step` is one pass of ``ops.lloyd_pass`` over the
+rows where they lie — distances, argmin, one-hot sums, counts and inertia per
+tile in VMEM, nothing of size ``n`` written — and the centre update.  Every
+other input, and every backend without the tier, runs the classic body: the
+``(n, k)`` distances, then the one-hot GEMM, two passes over a bfloat16 copy
+XLA keeps of float32 rows.  ``ht:kmeans.fit`` notes which ran (``lloyd``).
 """
 
 from __future__ import annotations
@@ -15,10 +26,13 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 from jax.experimental.layout import Format, Layout
+from jax.sharding import PartitionSpec
 
 from ..core.dndarray import DNDarray
 from ..core import memtrack, telemetry, types
+from ..ops import lloyd_pass
 from ..ops.cdist import cdist as ops_cdist
+from ..parallel.collectives import shard_map_unchecked
 from ..spatial import distance
 from ._kcluster import _KCluster
 
@@ -59,23 +73,77 @@ def _lloyd_while(step, centers, max_iter, tol):
 # traces relies on carry a module name of their own: telemetry.module_name
 
 
-@partial(jax.jit, static_argnames=("k",))
+@partial(jax.jit, static_argnames=("k", "fused"))
 @telemetry.module_name("ht_lloyd_loop")
-def _lloyd_loop(x, centers, k: int, max_iter, tol):
-    """Lloyd iterations over unpacked data (see :func:`_lloyd_while`)."""
-    return _lloyd_while(
-        lambda c: _lloyd_step(x, c, k), centers, max_iter, tol
-    )
+def _lloyd_loop(x, centers, k: int, max_iter, tol, fused=None):
+    """Lloyd iterations over unpacked data (see :func:`_lloyd_while`);
+    ``fused`` is :func:`_lloyd_step`'s."""
+    # the classic call stays positional: perf/tests plant their faults by
+    # replacing _lloyd_step with a function of (x, centers, k)
+    step = _lloyd_step if fused is None else partial(_lloyd_step, fused=fused)
+    return _lloyd_while(lambda c: step(x, c, k), centers, max_iter, tol)
 
 
-@partial(jax.jit, static_argnames=("k",))
+def _fused_rows(x: DNDarray, k: int):
+    """Where the rows of ``x`` lie, if :func:`_lloyd_step` can take them in
+    one pass an iteration, else None: ``(n, mesh, axis)``, the rows on one
+    device (``mesh`` None) or split over ``axis`` of ``mesh``.  Decided by
+    ``mode()`` and by what the input shows (shape, dtype, layout, split), as
+    ``ops/_pallas_common.py`` has it for a kernel that replaces a lowering
+    on every input it accepts."""
+    if not lloyd_pass.accepts(x.parray, k):
+        return None
+    if x.comm.n_devices == 1:
+        return x.shape[0], None, None
+    if x.split == 0:
+        return x.shape[0], x.comm.mesh, x.comm.split_axis
+    return None
+
+
+def _pass_over_rows(x, centers, fused):
+    """``(sums, counts, inertia)`` of one fused pass over all rows: the
+    kernel on one device, or on each shard of the physical (evenly padded)
+    array under ``shard_map`` with one ``psum`` of the three."""
+    n, mesh, axis = fused
+    if mesh is None:
+        return lloyd_pass.lloyd_pass(x.T, centers, n)
+
+    def shard(xs, c):
+        first = jax.lax.axis_index(axis) * xs.shape[0]
+        got = lloyd_pass.lloyd_pass(xs.T, c, jnp.clip(n - first, 0, xs.shape[0]))
+        return jax.lax.psum(got, axis)
+
+    return shard_map_unchecked(
+        shard, mesh, in_specs=(PartitionSpec(axis, None), PartitionSpec()),
+        out_specs=PartitionSpec())(x, centers)
+
+
+def _moved_centers(sums, counts, centers):
+    """The centre update of a Lloyd step and the squared shift it makes:
+    the mean of each cluster's rows; an empty cluster keeps its centre."""
+    new_centers = jnp.where(
+        counts[:, None] > 0, sums / jnp.maximum(counts, 1)[:, None], centers.astype(jnp.float32)
+    ).astype(centers.dtype)
+    return new_centers, jnp.sum((new_centers - centers).astype(jnp.float32) ** 2)
+
+
+@partial(jax.jit, static_argnames=("k", "fused"))
 @telemetry.module_name("ht_lloyd_step")
-def _lloyd_step(x, centers, k: int):
+def _lloyd_step(x, centers, k: int, fused=None):
     """One fused Lloyd iteration: returns (new_centers, shift², inertia).
 
     With ``x`` row-sharded and ``centers`` replicated, XLA compiles this to
     local MXU matmuls plus a single psum of the (k, f) sums and (k,) counts.
+
+    ``fused`` (:func:`_fused_rows`) says that ``x`` is the physical array
+    and its rows are read once, by ``ops.lloyd_pass``: no distances, labels
+    or copy of the rows are kept; the update below is the same.
     """
+    if fused is not None:
+        sums, counts, inertia = _pass_over_rows(x, centers, fused)
+        with jax.named_scope("ht.kmeans.update"):
+            new_centers, shift = _moved_centers(sums, counts, centers)
+        return new_centers, shift, inertia
     with jax.named_scope("ht.kmeans.assign"):
         d2 = ops_cdist(x, centers, sqrt=False)
         labels = jnp.argmin(d2, axis=1)
@@ -88,10 +156,7 @@ def _lloyd_step(x, centers, k: int):
         sums = jax.lax.dot_general(
             onehot, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        new_centers = jnp.where(
-            counts[:, None] > 0, sums / jnp.maximum(counts, 1)[:, None], centers.astype(jnp.float32)
-        ).astype(centers.dtype)
-        shift = jnp.sum((new_centers - centers).astype(jnp.float32) ** 2)
+        new_centers, shift = _moved_centers(sums, counts, centers)
     with jax.named_scope("ht.kmeans.assign"):
         # distance to the assigned (= nearest) centroid is the row minimum; a
         # take_along_axis gather here costs ~20x the rest of the step on TPU
@@ -471,11 +536,16 @@ class KMeans(_KCluster):
             None, x.device, x.comm,
         )
 
-    @telemetry.span("kmeans.fit")
     def fit(self, x) -> "KMeans":
         """Lloyd iterations until centroid shift < tol (reference:
         kmeans.py:102-139).  Also accepts :class:`packing.PackedSamples`
         (lane-packed ingest — the 1e8x64 bf16 north-star path)."""
+        with telemetry.span("kmeans.fit") as sp:
+            return self._fit(x, sp)
+
+    def _fit(self, x, sp) -> "KMeans":
+        """:meth:`fit` inside its span ``sp``, which notes the Lloyd body
+        that ran on unpacked rows (``lloyd`` = ``fused`` | ``classic``)."""
         from ..core import sanitation
         from .packing import PackedSamples
 
@@ -500,8 +570,11 @@ class KMeans(_KCluster):
                 self.max_iter, self.tol,
             )
         else:
+            fused = _fused_rows(x, self.n_clusters)
+            sp.note(lloyd="classic" if fused is None else "fused")
+            rows, how = (arr, {}) if fused is None else (x.parray, {"fused": fused})
             centers, _, inertia, n_iter = _lloyd_loop(
-                arr, centers, self.n_clusters, self.max_iter, self.tol
+                rows, centers, self.n_clusters, self.max_iter, self.tol, **how
             )
         with telemetry.sync("kmeans.n_iter"):  # one scalar per fit
             self._n_iter = int(n_iter)

@@ -14,8 +14,8 @@ interpreter by exporting ``HEAT_TPU_PALLAS=interpret``).
 
 How a kernel enters the library: one that replaces a lowering on every
 input it accepts is chosen by :func:`mode` alone (cdist, attention,
-decode attention); one that wins on some geometries only is an arm
-(:data:`KERNEL_ARMS`) handed to ``autotune.run``, which measures both
+decode attention, the Lloyd pass); one that wins on some geometries only
+is an arm (:data:`KERNEL_ARMS`) handed to ``autotune.run``, which measures both
 (qr_panel, lasso_sweep); no kernel gets an environment switch of its own.
 """
 

@@ -9,10 +9,12 @@ exactly as they ran.
 """
 
 import importlib
+import os
 import re
 import threading
 import unittest
 import warnings
+from unittest import mock
 
 import numpy as np
 
@@ -796,6 +798,19 @@ class TestLayerSpans(TestCase):
         self.assertEqual(sites["kmeans.n_iter"], 1)
         self.assertEqual(sites["kmeans.inertia"], 1)
 
+    def test_kmeans_fit_notes_the_lloyd_body(self):
+        """``lloyd`` on the span's end event says which body ran: the
+        classic one where the Pallas tier is off, the fused pass where it
+        is on and the rule takes the shape."""
+        rng = np.random.default_rng(5)
+        x = ht.array(rng.normal(size=(64, 8)).astype(np.float32), split=0)
+        with _EventsLevel():
+            for how in ("off", "interpret"):
+                with mock.patch.dict(os.environ, {"HEAT_TPU_PALLAS": how}):
+                    ht.cluster.KMeans(n_clusters=3, max_iter=2, random_state=0).fit(x)
+            ends = [e for e in telemetry.events("span_end") if e["name"] == "kmeans.fit"]
+        self.assertEqual([e["lloyd"] for e in ends], ["classic", "fused"])
+
     def test_linalg_qr_span_names_the_path(self):
         rng = np.random.default_rng(4)
         tall = ht.array(rng.normal(size=(64, 4)).astype(np.float32), split=None)
@@ -864,6 +879,26 @@ class TestDeviceScopes(TestCase):
                       "ht.kmeans.update/dot_general"):
             self.assertIn(scope, text)
         self.assertEqual(kmeans._lloyd_loop.__name__, "ht_lloyd_loop")
+
+    def test_fused_lloyd_loop_scopes(self):
+        """The kernel's operations sit under ``ht.kmeans.lloyd`` (what
+        ``lloyd_ms_per_call`` reads) in a scope of their own, in the module
+        the classic loop has."""
+        from heat_tpu.cluster import kmeans
+
+        x, c = jax.numpy.ones((64, 8)), jax.numpy.ones((3, 8))
+        with mock.patch.dict(os.environ, {"HEAT_TPU_PALLAS": "interpret"}):
+            lowered = kmeans._lloyd_loop.lower(x, c, 3, 5, 0.0, fused=(64, None, None))
+        self.assertIn("module @jit_ht_lloyd_loop", lowered.as_text())
+        # the compiled module carries whole paths (the call of the step is
+        # inlined there): the ``op_name`` a device trace's events are given
+        names = set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
+        step = "jit(ht_lloyd_loop)/ht.kmeans.lloyd/while/body/jit(ht_lloyd_step)/"
+        kernel = {n for n in names if "ht_lloyd_pass" in n}  # the kernel's own name
+        self.assertTrue(kernel and all(
+            n.startswith(step + "ht.kmeans.pass/ht_lloyd_pass") for n in kernel))
+        self.assertTrue(any(n.startswith(step + "ht.kmeans.update/") for n in names))
+        self.assertFalse(any("ht.kmeans.assign" in n for n in names))
 
     def test_packed_lloyd_loop_scopes(self):
         from heat_tpu.cluster import kmeans
