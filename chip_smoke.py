@@ -645,6 +645,55 @@ def st_kernels():
     return {"kernel": "[first_s, steady_s, max_err]", **info}
 
 
+def st_brumby():
+    """Brumby-14B-Base five layers deep at the published widths (the
+    benchmark's configuration: 6.4 GB of seeded weights), on one device:
+    prefill of 2 x 1,024 tokens in chunks, a save, four greedy steps through
+    the state kernel, a rewind and the same four steps again, against the
+    plain reference's full forward pass (attention form, float32)."""
+    from jax.sharding import Mesh
+
+    from heat_tpu.models import brumby
+    from heat_tpu.parallel.mesh import MeshComm
+    from perf.reference import brumby as reference
+
+    if REHEARSAL:
+        cfg = brumby.BrumbyConfig(vocab_size=96, hidden_size=64, intermediate_size=128,
+                                  num_attention_heads=6, num_key_value_heads=2, head_dim=16,
+                                  num_hidden_layers=3, dtype="float32")
+        length, tol = 40, 1e-4
+    else:
+        cfg = brumby.BrumbyConfig(num_hidden_layers=5)
+        length, tol = 1024, 2e-2
+        check(_pallas_common.mode() == KERNEL_MODE, f"pallas mode {_pallas_common.mode()!r}")
+    # a Mosaic kernel is a one-device program (the served models are not
+    # sharded: PERF.md section 7)
+    one = MeshComm(Mesh(np.array(jax.devices()[:1]), ("x",)), "x")
+    model = brumby.Brumby(cfg, seed=7, comm=one)
+    tokens = np.random.default_rng(7).integers(0, cfg.vocab_size, (2, length)).astype(np.int32)
+    session = model.session(2, length + 8)
+    (first, saved), prefill_s = clocked(
+        lambda: (session.prefill(tokens), session.save()))
+    first_token = np.asarray(jnp.argmax(first.larray, -1))
+    (chosen, logits), decode_s = clocked(lambda: session.decode(4))
+    session.rewind(saved)
+    again, again_logits = session.decode(4)
+    check(np.array_equal(np.asarray(again.larray), np.asarray(chosen.larray))
+          and np.array_equal(np.asarray(again_logits.larray), np.asarray(logits.larray)),
+          "decode after a rewind does not repeat itself")
+    rcfg = {k: getattr(cfg, k) for k in reference.SIZES}
+    chosen = np.asarray(chosen.larray)
+    worst = 0.0
+    for b in range(2):
+        seq = jnp.asarray(np.concatenate([tokens[b], [first_token[b]], chosen[b, :-1]]))
+        want = reference.logits_at_end(rcfg, model.params, seq, 4)
+        worst = max(worst, close(logits.larray[b], want, tol, "brumby logits vs reference"))
+    held = session.cache_bytes()["state"]
+    model.params = None
+    return {"layers": cfg.num_hidden_layers, "prefill_s": round(prefill_s, 3),
+            "decode4_first_s": round(decode_s, 3), "logits_err": worst, "state_bytes": held}
+
+
 def st_fence():
     """ROADMAP S1: one chain of matmuls timed by block_until_ready and by a
     scalar readback.  If the two agree and both dwarf the enqueue-only
@@ -851,6 +900,7 @@ def main():
     stage("flash_attention", st_flash_attention)
     stage("moe_ffn", st_moe)
     stage("pallas_kernels", st_kernels)
+    stage("brumby_serve", st_brumby)
     stage("fence_timing", st_fence)
     if NDEV > 1:
         stage("multichip_schedules", st_multichip)

@@ -3,11 +3,13 @@
 from .mlp import MLP
 from .resnet import ResNet, ResNet18, ResNet34, ResNet50, ResNet101, ResNet152
 from .transformer import TransformerLM, TransformerBlock, MoEMlp
-from .sambay import SambaY, SambaYConfig, DecodeSession
+from .session import DecodeSession
+from .sambay import SambaY, SambaYConfig
+from .brumby import Brumby, BrumbyConfig
 
 __all__ = [
     "MLP",
     "ResNet", "ResNet18", "ResNet34", "ResNet50", "ResNet101", "ResNet152",
     "TransformerLM", "TransformerBlock", "MoEMlp",
-    "SambaY", "SambaYConfig", "DecodeSession",
+    "SambaY", "SambaYConfig", "Brumby", "BrumbyConfig", "DecodeSession",
 ]

@@ -18,8 +18,10 @@ key/value heads form pairs whose two value heads are read as one of twice the
 width, and a pair's output is ``softmax(q1 k1) V - lambda softmax(q2 k2) V``,
 RMS-normalised and scaled by ``1 - lambda_init``.
 
-:class:`DecodeSession` holds the hybrid cache of a batch of sequences that
-advance in lockstep: ``prefill(tokens)`` walks a prompt through the
+:class:`~heat_tpu.models.session.DecodeSession` (one class for every served
+model; this module supplies the cache and the programs) holds the hybrid cache
+of a batch of sequences that advance in lockstep: ``prefill(tokens)`` walks a
+prompt through the
 self-decoder in chunks (the cross-decoder runs for the last position only: no
 later position reads it), ``decode(steps)`` generates greedily on the device
 and reads the chosen tokens back once, and ``save()`` / ``rewind(snapshot)``
@@ -34,19 +36,21 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import NamedTuple, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import telemetry, types
-from ..core.dndarray import DNDarray
+from ..core import telemetry
 from ..ops._pallas_common import mode as pallas_mode
 from ..ops.decode_attention import decode_attention, keys_fetched, masked_attention
 from ..ops.selective_scan import selective_scan, selective_step
+from ._lm import (dot as _dot, embed as _embed, gated_mlp as _gated_mlp, greedy as _greedy,
+                  init_tree as _init_tree, spec_size as _spec_size)
+from .session import DecodeSession, Snapshot, tree_bytes
 
-__all__ = ["SambaY", "SambaYConfig", "DecodeSession", "sambay_layer_types"]
+__all__ = ["SambaY", "SambaYConfig", "DecodeSession", "Snapshot", "sambay_layer_types"]
 
 KINDS = ("mamba", "window", "full", "gmu", "cross")
 _F32 = jnp.float32
@@ -57,18 +61,6 @@ _F32 = jnp.float32
 PREFILL_CHUNK = 512
 ATTN_BLOCK = 2048
 SCAN_CHUNK = 16
-
-# decode_steps and prefill_tokens count what sessions did; cache_keys_visible
-# and cache_keys_fetched are the key slots of the shared cache that the decode
-# steps' reads could see and the slots the decode kernel's rule fetches for
-# them (their ratio is the fetch share; both stand still where the kernel does
-# not run and the jax.numpy fallback reads the cache); cache_bytes is what the
-# newest session allocated, by kind
-_LM = telemetry.register_group(
-    "lm",
-    {"decode_steps": 0, "prefill_tokens": 0, "cache_keys_visible": 0, "cache_keys_fetched": 0,
-     "cache_bytes": {"shared": 0, "window": 0, "state": 0}},
-)
 
 
 def sambay_layer_types(num_layers: int, mb_per_layer: int = 2) -> Tuple[str, ...]:
@@ -206,45 +198,17 @@ def param_spec(cfg: SambaYConfig) -> dict:
     return {"embed": ((cfg.vocab_size, d), d ** -0.5), "layers": layers, "final_norm": norm()}
 
 
-def _is_leaf(node) -> bool:
-    return isinstance(node, tuple) and len(node) == 2 and isinstance(node[0], tuple)
-
-
 def param_count(cfg: SambaYConfig) -> dict:
     """Parameters per layer kind (mixer, norms and MLP of one layer), of the
     embedding (tied: counted once) and of the whole model; shapes only."""
     spec = param_spec(cfg)
-
-    def count(tree):
-        return sum(math.prod(leaf[0]) for leaf in jax.tree.leaves(tree, is_leaf=_is_leaf))
-
+    count = _spec_size
     out = {"embed": count(spec["embed"]), "final_norm": count(spec["final_norm"])}
     for kind, layer in zip(cfg.layer_types, spec["layers"]):
         out[kind] = count(layer)
         out.setdefault("mlp", count(layer["mlp"]))
     out["total"] = count(spec)
     return out
-
-
-@functools.partial(jax.jit, static_argnames=("shape", "init", "dtype", "blocks"))
-def _make_leaf(key, shape, init, dtype, blocks):
-    if init == "ones":
-        return jnp.ones(shape, dtype)
-    if init == "zeros":
-        return jnp.zeros(shape, dtype)
-    if init == "a_log":  # A = -(1 .. d_state) for every channel
-        return jnp.broadcast_to(
-            jnp.log(jnp.arange(1, shape[0] + 1, dtype=_F32))[:, None], shape).astype(dtype)
-    if init == "dt_bias":  # softplus^-1 of step sizes log-uniform in [1e-3, 1e-1]
-        dt = jnp.exp(jax.random.uniform(key, shape, _F32, math.log(1e-3), math.log(1e-1)))
-        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-    if blocks == 1:
-        return (jax.random.normal(key, shape, _F32) * init).astype(dtype)
-    part = (shape[0] // blocks,) + shape[1:]
-    made = jax.lax.map(
-        lambda k: (jax.random.normal(k, part, _F32) * init).astype(dtype),
-        jax.random.split(key, blocks))
-    return made.reshape(shape)
 
 
 def init_params(cfg: SambaYConfig, key, sharding=None) -> dict:
@@ -254,19 +218,7 @@ def init_params(cfg: SambaYConfig, key, sharding=None) -> dict:
     residual stream scaled by ``1/sqrt(2 L)`` besides; norms start at one and
     zero; ``A = -(1..d_state)``; the step-size bias gives steps in
     ``[1e-3, 1e-1]``; the lambda vectors are ``N(0, 0.01)``."""
-    dtype = jnp.dtype(cfg.dtype)
-    spec = param_spec(cfg)
-    leaves, tree = jax.tree.flatten(spec, is_leaf=_is_leaf)
-    made = []
-    for number, (shape, init) in enumerate(leaves):
-        blocks = 1
-        while math.prod(shape) // blocks > (1 << 26) and shape[0] % (2 * blocks) == 0:
-            blocks *= 2
-        # what the scan and the lambdas read in float32 is stored in float32
-        leaf_dtype = _F32 if init in ("a_log", "dt_bias") or len(shape) == 1 else dtype
-        leaf = _make_leaf(jax.random.fold_in(key, number), shape, init, leaf_dtype, blocks)
-        made.append(leaf if sharding is None else jax.device_put(leaf, sharding))
-    return jax.tree.unflatten(tree, made)
+    return _init_tree(param_spec(cfg), jnp.dtype(cfg.dtype), key, sharding)
 
 
 # ---------------------------------------------------------------------- layers
@@ -278,16 +230,9 @@ def _layer_norm(x, p, eps):
     return (x - mean) * jax.lax.rsqrt(var + eps) * p["w"].astype(_F32) + p["b"].astype(_F32)
 
 
-def _dot(x, w):
-    """``x @ w`` in the weights' type with float32 accumulation."""
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=_F32)
-
-
 def _mlp(cfg, p, x):
     with jax.named_scope("ht.lm.mlp"):
-        h = _layer_norm(x, p["norm2"], cfg.layer_norm_eps)
-        gate, up = _dot(h, p["mlp"]["w_gate"]), _dot(h, p["mlp"]["w_up"])
-        return x + _dot(jax.nn.silu(gate) * up, p["mlp"]["w_down"])
+        return x + _gated_mlp(p["mlp"], _layer_norm(x, p["norm2"], cfg.layer_norm_eps))
 
 
 def _mamba(cfg, p, h, conv_tail, ssm, scan_chunk):
@@ -462,16 +407,7 @@ def _run_layers(cfg, params, first, last, x, pos0, shared, state, memory, *, blo
 def _head(cfg, params, x):
     """Greedy token and float32 logits of ``x`` of ``(batch, d)``."""
     with jax.named_scope("ht.lm.head"):
-        h = _layer_norm(x, params["final_norm"], cfg.layer_norm_eps)
-        embed = params["embed"]
-        logits = jax.lax.dot_general(h.astype(embed.dtype), embed, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=_F32)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
-
-
-def _embed(params, tokens):
-    with jax.named_scope("ht.lm.embed"):
-        return jnp.take(params["embed"], tokens, axis=0).astype(_F32)
+        return _greedy(_layer_norm(x, params["final_norm"], cfg.layer_norm_eps), params["embed"])
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "block", "scan_chunk"), donate_argnums=(2, 3))
@@ -515,28 +451,16 @@ def _decode(cfg, params, shared, state, token, pos, *, steps, block):
     return shared, state, token, chosen.T, jnp.moveaxis(logits, 0, 1)
 
 
-@jax.jit
-def _copied(tree):
-    return jax.tree.map(jnp.copy, tree)
-
-
-# --------------------------------------------------------------------- session
-
-class Snapshot(NamedTuple):
-    """A saved position of a :class:`DecodeSession`: the position, the token
-    waiting to be fed there, and copies of the constant-size states."""
-
-    position: int
-    token: jax.Array
-    state: dict
-
+# ----------------------------------------------------------------------- model
 
 class SambaY:
     """The model: a configuration and its parameters.
 
     ``SambaY(cfg)`` draws seeded parameters (:func:`init_params`);
     ``SambaY(cfg, params)`` takes a tree of the same layout.  Serving goes
-    through :meth:`session`."""
+    through :meth:`session`, a :class:`~heat_tpu.models.session.DecodeSession`
+    over what the ``serve_*`` methods below supply: the hybrid cache, the
+    jitted programs, the decode span's notes."""
 
     def __init__(self, cfg: SambaYConfig, params: Optional[dict] = None, *, seed: int = 0,
                  comm=None):
@@ -549,131 +473,69 @@ class SambaY:
             params = init_params(cfg, jax.random.key(seed), self._placement)
         self.params = params
 
-    def session(self, batch: int, max_context: int) -> "DecodeSession":
+    def session(self, batch: int, max_context: int) -> DecodeSession:
         return DecodeSession(self, batch, max_context)
 
+    prefill_chunk = property(lambda self: PREFILL_CHUNK)
 
-class DecodeSession:
-    """The hybrid cache of ``batch`` sequences that advance in lockstep, and
-    the calls that move it: :meth:`prefill`, :meth:`decode`, :meth:`save`,
-    :meth:`rewind`.
-
-    The shared cache holds ``capacity`` positions: ``max_context`` rounded up
-    to whole blocks of ``ATTN_BLOCK`` keys, the unit the decode kernel
-    streams (a context shorter than a block: to a power of two, one block).
-    A prompt is walked in chunks of ``PREFILL_CHUNK`` positions."""
-
-    def __init__(self, model: SambaY, batch: int, max_context: int):
-        cfg = self.cfg = model.cfg
-        self.model = model
-        self.batch = int(batch)
-        block = min(ATTN_BLOCK, 1 << max(4, (int(max_context) - 1).bit_length()))
-        self.capacity = -(-int(max_context) // block) * block
-        self.position = 0
+    def serve_cache(self, batch: int, max_context: int):
+        """The shared cache holds ``capacity`` positions: ``max_context``
+        rounded up to whole blocks of ``ATTN_BLOCK`` keys, the unit the decode
+        kernel streams (a context shorter than a block: to a power of two, one
+        block).  The state a snapshot copies: the window rings, the Mamba
+        layers' convolution tails and scan states."""
+        cfg = self.cfg
+        block = min(ATTN_BLOCK, 1 << max(4, (max_context - 1).bit_length()))
+        capacity = -(-max_context // block) * block
         dtype = jnp.dtype(cfg.dtype)
         groups, lanes = cfg.kv_groups, 2 * cfg.head_dim
 
         def zeros(shape, dt):
-            return jnp.zeros(shape, dt, device=model._placement)
+            return jnp.zeros(shape, dt, device=self._placement)
 
-        self._shared = tuple(
-            zeros((self.batch, groups, self.capacity, lanes), dtype) for _ in range(2))
+        shared = tuple(zeros((batch, groups, capacity, lanes), dtype) for _ in range(2))
         n_window, n_mamba = cfg.layer_types.count("window"), cfg.layer_types.count("mamba")
-        ring = (self.batch, groups, cfg.sliding_window, lanes)
-        self._state = {
+        ring = (batch, groups, cfg.sliding_window, lanes)
+        state = {
             "ring": tuple((zeros(ring, dtype), zeros(ring, dtype)) for _ in range(n_window)),
-            "conv": tuple(zeros((self.batch, cfg.d_conv - 1, cfg.d_inner), dtype)
+            "conv": tuple(zeros((batch, cfg.d_conv - 1, cfg.d_inner), dtype)
                           for _ in range(n_mamba)),
-            "ssm": tuple(zeros((self.batch, cfg.d_state, cfg.d_inner), _F32)
+            "ssm": tuple(zeros((batch, cfg.d_state, cfg.d_inner), _F32)
                          for _ in range(n_mamba)),
         }
-        self._token = None  # the token waiting to be fed at `position`
-        self.tokens = None  # the newest decode call's tokens, on the host
-        _LM["cache_bytes"].update(self.cache_bytes())
+        return capacity, shared, state
 
-    def cache_bytes(self) -> dict:
-        """Bytes this session holds on the device, by kind of state."""
-        def nbytes(tree):
-            return sum(int(leaf.nbytes) for leaf in jax.tree.leaves(tree))
+    def serve_bytes(self, shared, state) -> dict:
+        return {"shared": tree_bytes(shared), "window": tree_bytes(state["ring"]),
+                "state": tree_bytes(state["conv"]) + tree_bytes(state["ssm"])}
 
-        return {"shared": nbytes(self._shared), "window": nbytes(self._state["ring"]),
-                "state": nbytes(self._state["conv"]) + nbytes(self._state["ssm"])}
-
-    def _wrap(self, array) -> DNDarray:
-        from ..core.devices import get_device
-
-        return DNDarray(array, tuple(array.shape), types.canonical_heat_type(array.dtype),
-                        None, get_device(), self.model.comm)
-
-    def prefill(self, tokens) -> DNDarray:
-        """Append a prompt of ``(batch, n)`` token ids at the current position.
-        Returns the logits of its last position, ``(batch, vocab)``; their
-        argmax is the token the next :meth:`decode` feeds first."""
-        ids = tokens.larray if isinstance(tokens, DNDarray) else jnp.asarray(tokens)
-        ids = ids.astype(jnp.int32)
-        if ids.ndim != 2 or ids.shape[0] != self.batch or ids.shape[1] < 1:
-            raise ValueError(f"prefill takes (batch={self.batch}, n >= 1) token ids, got {ids.shape}")
+    def serve_prefill(self, shared, state, ids, position: int):
+        """The prompt through the self-decoder in chunks of ``PREFILL_CHUNK``
+        positions, then the cross-decoder and the head for its last one."""
+        cfg, params = self.cfg, self.params
         n = int(ids.shape[1])
-        if self.position + n > self.capacity:
-            raise ValueError(f"{self.position} + {n} positions pass the session's {self.capacity}")
-        cfg, params = self.cfg, self.model.params
-        with telemetry.span("lm.prefill", tokens=self.batch * n, chunk=PREFILL_CHUNK):
-            for start in range(0, n, PREFILL_CHUNK):
-                chunk = ids[:, start:start + PREFILL_CHUNK]
-                self._shared, self._state, x, memory = _prefill_chunk(
-                    cfg, params, self._shared, self._state, chunk,
-                    np.int32(self.position + start), block=ATTN_BLOCK, scan_chunk=SCAN_CHUNK)
-            self.position += n
-            self._token, logits = _prefill_finish(
-                cfg, params, self._shared, x, memory, np.int32(self.position - 1),
-                block=ATTN_BLOCK)
-        _LM["prefill_tokens"] += self.batch * n
-        return self._wrap(logits)
+        for start in range(0, n, PREFILL_CHUNK):
+            chunk = ids[:, start:start + PREFILL_CHUNK]
+            shared, state, x, memory = _prefill_chunk(
+                cfg, params, shared, state, chunk, np.int32(position + start),
+                block=ATTN_BLOCK, scan_chunk=SCAN_CHUNK)
+        token, logits = _prefill_finish(
+            cfg, params, shared, x, memory, np.int32(position + n - 1), block=ATTN_BLOCK)
+        return shared, state, token, logits
 
-    def decode(self, steps: int):
-        """``steps`` greedy tokens for every sequence.  Returns ``(tokens,
-        logits)``: the tokens chosen, ``(batch, steps)`` int32, and the logits
-        they were chosen from, ``(batch, steps, vocab)`` float32; ``logits[:,
-        j]`` are those of position ``position + j``.  The tokens are read back
-        once (``session.tokens``: what a serving loop looks at)."""
-        steps = int(steps)
-        if self._token is None:
-            raise ValueError("decode needs a prompt: call prefill first")
-        if self.position + steps > self.capacity:
-            raise ValueError(f"{self.position} + {steps} positions pass the session's {self.capacity}")
+    def serve_notes(self, session: DecodeSession, steps: int):
         cfg = self.cfg
-        notes = dict(batch=self.batch, context=self.position, steps=steps,
+        notes = dict(batch=session.batch, context=session.position, steps=steps,
                      readers=cfg.n_shared_readers, token_bytes=cfg.cache_token_bytes)
-        visible = fetched = 0
+        counts = {}
         if pallas_mode() != "off":  # the rule is the kernel's: the fallback feeds neither counter
-            reads = self.batch * cfg.n_shared_readers  # of the shared cache, a step
-            lengths = range(self.position + 1, self.position + steps + 1)
-            visible = reads * sum(lengths)
-            fetched = notes["fetched"] = reads * sum(
-                keys_fetched(n, self.capacity, ATTN_BLOCK) for n in lengths)
-        with telemetry.span("lm.decode", **notes):
-            self._shared, self._state, self._token, chosen, logits = _decode(
-                cfg, self.model.params, self._shared, self._state, self._token,
-                np.int32(self.position), steps=steps, block=ATTN_BLOCK)
-            with telemetry.sync("lm.tokens"):
-                self.tokens = np.asarray(chosen)
-        self.position += steps
-        _LM["decode_steps"] += steps
-        _LM["cache_keys_visible"] += visible
-        _LM["cache_keys_fetched"] += fetched
-        return self._wrap(chosen), self._wrap(logits)
+            reads = session.batch * cfg.n_shared_readers  # of the shared cache, a step
+            lengths = range(session.position + 1, session.position + steps + 1)
+            counts["cache_keys_visible"] = reads * sum(lengths)
+            counts["cache_keys_fetched"] = notes["fetched"] = reads * sum(
+                keys_fetched(n, session.capacity, ATTN_BLOCK) for n in lengths)
+        return notes, counts
 
-    def save(self) -> Snapshot:
-        """The current position, to :meth:`rewind` to.  Copies the window
-        rings and the Mamba states; the shared cache is not copied, its
-        entries past a saved position are simply overwritten later."""
-        if self._token is None:
-            raise ValueError("nothing to save before the first prefill")
-        token, state = _copied((self._token, self._state))
-        return Snapshot(self.position, token, state)
-
-    def rewind(self, snapshot: Snapshot) -> None:
-        """Back to a saved position of this session."""
-        with telemetry.span("lm.rewind", position=snapshot.position):
-            self._token, self._state = _copied((snapshot.token, snapshot.state))
-            self.position = snapshot.position
+    def serve_decode(self, shared, state, token, position: int, steps: int):
+        return _decode(self.cfg, self.params, shared, state, token, np.int32(position),
+                       steps=steps, block=ATTN_BLOCK)

@@ -18,8 +18,12 @@ schedule ourselves wins (SURVEY.md §7, design stance #6):
   that prefill chunks and rolling windows go through.
 * :mod:`~heat_tpu.ops.selective_scan` — Mamba's selective state-space scan,
   chunked over a sequence and as one step.
+* :mod:`~heat_tpu.ops.power_retention` — power retention of degree 2 (linear
+  attention with weights ``(q . k)^2`` under a gate): one decode step as one
+  pass over the constant-size state in place (Pallas on a TPU), and the
+  chunked form that prefill walks.
 
-The last two are imported from their modules (``from heat_tpu.ops.decode_attention
+The last three are imported from their modules (``from heat_tpu.ops.decode_attention
 import decode_attention``): a function re-exported here under its module's
 name would hide the module.
 """

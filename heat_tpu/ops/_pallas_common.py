@@ -25,10 +25,13 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "KERNEL_ARMS",
     "LANE",
+    "device_copy",
     "mode",
     "pad_to",
     "sublane",
@@ -70,3 +73,37 @@ def pad_to(x: jax.Array, mults) -> jax.Array:
     if any(p[1] for p in pads):
         x = jnp.pad(x, pads)
     return x
+
+
+def _copy_kernel(src_ref, *refs):
+    dst_ref, sem = refs[-2:]  # with ``into`` its ref comes between: the output is its buffer
+    copy = pltpu.make_async_copy(src_ref, dst_ref, sem)
+    copy.start()
+    copy.wait()
+
+
+def device_copy(src: jax.Array, into: jax.Array | None = None) -> jax.Array:
+    """A copy of ``src`` made where it lies, by one DMA from HBM to HBM (the
+    kernel ``ht_device_copy``; ``jnp.copy`` where :func:`mode` is ``off``).
+    ``into``: an array of the same shape and type that the caller gives up;
+    the copy is written into its buffer (the kernel's output aliases it), so
+    that copying a large state back over itself never holds a third copy.
+    Unlike the copy XLA inserts for ``jnp.copy`` of a program's argument, the
+    kernel carries the ``jax.named_scope`` it was called under."""
+    how = mode()
+    if how == "off":
+        return jnp.copy(src)
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    operands = (src,) if into is None else (src, into)
+    return pl.pallas_call(
+        _copy_kernel,
+        in_specs=[anywhere] * len(operands),
+        out_specs=anywhere,
+        out_shape=jax.ShapeDtypeStruct(src.shape, src.dtype),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        input_output_aliases={} if into is None else {1: 0},
+        cost_estimate=pl.CostEstimate(flops=0, transcendentals=0,
+                                      bytes_accessed=2 * src.size * src.dtype.itemsize),
+        interpret=(how == "interpret"),
+        name="ht_device_copy",
+    )(*operands)

@@ -324,3 +324,31 @@ def test_the_compiled_loop_keeps_nothing_the_size_of_the_rows(dtype, v5e, pallas
     made = re.findall(r"= \w+\[([\d,]+)\]\S* (?!parameter|get-tuple-element|bitcast)[\w\-]+\(", text)
     sizes = [int(np.prod([int(s) for s in d.split(",") if s])) for d in made]
     assert sizes and max(sizes) < n
+
+
+def test_the_retention_state_is_stepped_and_restored_in_place(v5e, pallas):
+    """Here because one file, hence one process, may describe the chip (the
+    ``on-chip-measurement`` guide): the decode step of a power-retention layer
+    (``ops/power_retention.py``) and the copy that a session's ``rewind`` makes
+    (``models/session.py``), at the benchmark cell's shapes, compiled for the
+    chip: the state is updated where it lies and the saved state is copied
+    into the live state's own buffers, with no temporary the size of either."""
+    from heat_tpu.models import session
+    from heat_tpu.ops import power_retention as pr
+    pallas("tpu")
+    batch, heads, group, d = 16, 8, 5, 128
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=v5e)  # noqa: E731
+    S, z = f32(batch, heads, d, pr.state_rows(d)), f32(batch, heads, pr.feature_blocks(d), d)
+    held = 4 * batch * heads * (d + 1) * pr.state_rows(d)
+    with jax.enable_x64(False):
+        step = jax.jit(pr.retention_step, donate_argnums=(0, 1)).lower(
+            S, z, f32(batch, heads, group, d), f32(batch, heads, d), f32(batch, heads, d),
+            f32(batch, heads)).compile()
+        token = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=v5e)
+        tree = (token, {"S": (S, S), "z": (z, z)})
+        restore = session._restored.lower(tree, tree).compile()
+    for compiled, aliased, kernels in ((step, held, 1), (restore, 2 * 4 * S.size, 2)):
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= aliased
+        assert memory.temp_size_in_bytes < 1 << 20
+        assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == kernels
