@@ -348,6 +348,8 @@ def st_kmeans():
     centers, first, steady = first_and_steady(fit)
     bodies = {e.get("lloyd") for e in telemetry.events("span_end") if e["name"] == "kmeans.fit"}
     check(bodies == {"fused"}, f"Lloyd bodies that ran: {bodies}, wanted the fused pass alone")
+    assigned = {e.get("assign") for e in telemetry.events("span_end") if e["name"] == "kmeans.labels"}
+    check(assigned == {"fused"}, f"what made the labels: {assigned}, wanted the fused pass alone")
     check(centers.shape == (k, f), f"centers shape {centers.shape}")
     check(bool(np.isfinite(centers.numpy()).all()), "non-finite centers")
     del data
@@ -367,6 +369,9 @@ def st_kmeans():
     est.fit(ht.array(small, split=0))
     err = close(est.cluster_centers_.numpy(), _numpy_lloyd(small, init, 3), 2e-3,
                 "kmeans vs numpy Lloyd")
+    labels = est.labels_.numpy()
+    check(labels.shape == (2048, 1) and bool((labels.ravel() == blob).all()),
+          f"kmeans labels_: {int((labels.ravel() != blob).sum())} of 2048 rows off their blob")
     return {"first_s": round(first, 3), "steady_s": round(steady, 4),
             "bytes_in_use_rise": [None if b1 is None else b1 - b0
                                   for b0, b1 in zip(base, held)],
@@ -610,11 +615,12 @@ def st_kernels():
     n = min(n, 41_037)  # two and a half tiles of 16,384 rows at f = 64
     rows = jnp.asarray(rng.standard_normal((n, f)), jnp.float32)
     cen = rows[:k] + 0.5
-    fused = jax.jit(lambda v, c: lloyd_pass._pass_pallas(v.T, c, n, interpret=interp))
+    fused = jax.jit(lambda v, c: lloyd_pass._pass_pallas(v.T, c, n, interpret=interp, labels=True))
     got, first, steady = first_and_steady(lambda: fused(rows, cen))
-    want = lloyd_pass._pass_jnp(rows.T, cen, n)
+    want = lloyd_pass._pass_jnp(rows.T, cen, n, labels=True)
     # a row or two may sit on a tie that the two products' rounding breaks
     check(float(jnp.abs(got[1] - want[1]).max()) <= 2, f"lloyd_pass counts {got[1]} vs {want[1]}")
+    check(int((got[3] != want[3]).sum()) <= 2, "lloyd_pass labels vs jax.numpy")
     info["lloyd_pass"] = [round(first, 3), round(steady, 4), max(
         close(got[0] / n, want[0] / n, 1e-4, "lloyd_pass sums vs jax.numpy"),
         close(got[2] / n, want[2] / n, 1e-4, "lloyd_pass inertia vs jax.numpy"))]

@@ -215,32 +215,48 @@ class _KCluster(ClusteringMixin, BaseEstimator):
             types.canonical_heat_type(centroids.dtype), None, x.device, x.comm,
         )
 
+    def _labels_by_kernel(self, x: DNDarray) -> Optional[DNDarray]:
+        """A hook of :meth:`_assign_to_cluster`: the labels of ``x`` by a
+        kernel that computes this estimator's metric, as the concrete
+        ``(n, 1)`` array the lazy path would give, or None where there is no
+        such kernel or it declines ``x``."""
+        return None
+
     def _assign_to_cluster(self, x: DNDarray, return_inertia: bool = False):
         """Assign each sample to its closest centroid (reference:
         _kcluster.py:196).  With ``return_inertia`` the min-distance sum
         rides along as a second root of the SAME fused program — the
         cdist subtree is shared through the scheduler's CSE, so labels and
-        inertia cost one compile and one dispatch, not two cdists."""
+        inertia cost one compile and one dispatch, not two cdists.
+
+        The one entry for labels, ``fit``'s and ``predict``'s alike; its span
+        ``kmeans.labels`` notes what made them (``assign`` = ``fused``, the
+        estimator's kernel, | ``classic``, the lazy distances and argmin)."""
         from ..core import fusion, statistics
 
-        # the distance update rides the fusion engine: a GSPMD cdist defers a
-        # lazy DAG and this argmin extends it, so distances + labels lower as
-        # one cached executable per (shape, sharding) key
-        distances = self._metric(x, self._cluster_centers)
-        labels = statistics.argmin(distances, axis=1, keepdims=True)
-        if return_inertia:
-            inertia = statistics.min(distances, axis=1).sum()
-            fusion.materialize(labels, inertia)
-            with telemetry.sync("kcluster.inertia"):  # one scalar per fit
-                inertia_val = float(jnp.asarray(inertia.larray).reshape(()))
-        if labels.split != x.split:
-            out = DNDarray(
-                labels.larray, labels.gshape, labels.dtype, x.split, x.device, x.comm
-            )
-            labels = _ensure_split(out, x.split)
-        if return_inertia:
-            return labels, inertia_val
-        return labels
+        with telemetry.span("kmeans.labels") as sp:
+            labels = None if return_inertia else self._labels_by_kernel(x)
+            sp.note(assign="classic" if labels is None else "fused")
+            if labels is not None:
+                return labels
+            # the distance update rides the fusion engine: a GSPMD cdist defers a
+            # lazy DAG and this argmin extends it, so distances + labels lower as
+            # one cached executable per (shape, sharding) key
+            distances = self._metric(x, self._cluster_centers)
+            labels = statistics.argmin(distances, axis=1, keepdims=True)
+            if return_inertia:
+                inertia = statistics.min(distances, axis=1).sum()
+                fusion.materialize(labels, inertia)
+                with telemetry.sync("kcluster.inertia"):  # one scalar per fit
+                    inertia_val = float(jnp.asarray(inertia.larray).reshape(()))
+            if labels.split != x.split:
+                out = DNDarray(
+                    labels.larray, labels.gshape, labels.dtype, x.split, x.device, x.comm
+                )
+                labels = _ensure_split(out, x.split)
+            if return_inertia:
+                return labels, inertia_val
+            return labels
 
     def _update_centroids(self, x: DNDarray, matching_centroids: DNDarray):
         raise NotImplementedError()

@@ -16,6 +16,13 @@ tile in VMEM, nothing of size ``n`` written — and the centre update.  Every
 other input, and every backend without the tier, runs the classic body: the
 ``(n, k)`` distances, then the one-hot GEMM, two passes over a bfloat16 copy
 XLA keeps of float32 rows.  ``ht:kmeans.fit`` notes which ran (``lloyd``).
+
+The labels, ``fit``'s and ``predict``'s, are one more run of the same pass on
+the inputs it takes (:func:`_labels_of_rows`, reached through the hook
+``_labels_by_kernel`` of ``_KCluster._assign_to_cluster``): the kernel writes
+the cluster number it assigns each row, 4 bytes a row beside the one read of
+the rows, and neither distances nor norms exist.  Every other input takes
+the lazy distances and argmin; ``ht:kmeans.labels`` notes which (``assign``).
 """
 
 from __future__ import annotations
@@ -100,22 +107,37 @@ def _fused_rows(x: DNDarray, k: int):
     return None
 
 
-def _pass_over_rows(x, centers, fused):
+def _pass_over_rows(x, centers, fused, labels=False):
     """``(sums, counts, inertia)`` of one fused pass over all rows: the
     kernel on one device, or on each shard of the physical (evenly padded)
-    array under ``shard_map`` with one ``psum`` of the three."""
+    array under ``shard_map`` with one ``psum`` of the three.  With
+    ``labels`` the pass's fourth result alone, every physical row's cluster
+    number, split as the rows are and with no collective at all."""
     n, mesh, axis = fused
     if mesh is None:
-        return lloyd_pass.lloyd_pass(x.T, centers, n)
+        got = lloyd_pass.lloyd_pass(x.T, centers, n, labels)
+        return got[3] if labels else got
 
     def shard(xs, c):
         first = jax.lax.axis_index(axis) * xs.shape[0]
-        got = lloyd_pass.lloyd_pass(xs.T, c, jnp.clip(n - first, 0, xs.shape[0]))
-        return jax.lax.psum(got, axis)
+        got = lloyd_pass.lloyd_pass(xs.T, c, jnp.clip(n - first, 0, xs.shape[0]), labels)
+        return got[3] if labels else jax.lax.psum(got, axis)
 
     return shard_map_unchecked(
         shard, mesh, in_specs=(PartitionSpec(axis, None), PartitionSpec()),
-        out_specs=PartitionSpec())(x, centers)
+        out_specs=PartitionSpec(axis) if labels else PartitionSpec())(x, centers)
+
+
+@partial(jax.jit, static_argnames=("fused",))
+@telemetry.module_name("ht_kmeans_labels")
+def _labels_of_rows(x, centers, fused):
+    """The number of the nearest of ``centers`` for every row of the physical
+    array ``x``, ``(rows, 1)`` in the index type ``jnp.argmin`` gives: one
+    run of the fused pass (``fused`` is :func:`_fused_rows`'s) that also
+    writes what it assigns; no distances are kept."""
+    with jax.named_scope("ht.kmeans.labels"):
+        numbers = _pass_over_rows(x, centers, fused, labels=True)
+        return numbers.astype(jax.dtypes.canonicalize_dtype(jnp.int64)).reshape(-1, 1)
 
 
 def _moved_centers(sums, counts, centers):
@@ -576,18 +598,30 @@ class KMeans(_KCluster):
             centers, _, inertia, n_iter = _lloyd_loop(
                 rows, centers, self.n_clusters, self.max_iter, self.tol, **how
             )
-        with telemetry.sync("kmeans.n_iter"):  # one scalar per fit
-            self._n_iter = int(n_iter)
-
         self._cluster_centers = DNDarray(
             centers, tuple(centers.shape), types.canonical_heat_type(centers.dtype),
             None, x.device, x.comm,
         )
-        with telemetry.span("kmeans.labels"):
-            self._labels = self._assign_to_cluster(x)
+        # dispatched on the loop's centres before the host waits for the
+        # loop: the device goes from one program to the next, one wait for both
+        self._labels = self._assign_to_cluster(x)
+        with telemetry.sync("kmeans.n_iter"):  # one scalar per fit
+            self._n_iter = int(n_iter)
         with telemetry.sync("kmeans.inertia"):  # one scalar per fit
             self._inertia = float(inertia)
         return self
+
+    def _labels_by_kernel(self, x: DNDarray) -> Optional[DNDarray]:
+        """The squared Euclidean distance is the fused pass's metric: where
+        :func:`_fused_rows` accepts ``x`` the labels are one more run of it."""
+        fused = _fused_rows(x, self.n_clusters)
+        if fused is None:
+            return None
+        labels = _labels_of_rows(x.parray, self._cluster_centers.larray, fused)
+        return DNDarray(
+            labels, (x.shape[0], 1), types.canonical_heat_type(labels.dtype),
+            x.split, x.device, x.comm,
+        )
 
     # ------------------------------------------------------ packed-ingest path
     def _init_centers_packed(self, packed) -> jax.Array:

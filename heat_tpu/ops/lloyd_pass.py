@@ -3,7 +3,10 @@
 ``lloyd_pass(xt, centers, nvalid)`` streams the rows once and returns what
 the centre update needs and nothing of size ``n``: the per-cluster sums
 ``(k, f)``, the counts ``(k,)`` and the inertia, all float32, for the
-assignment of every valid row to its nearest centre.
+assignment of every valid row to its nearest centre.  On request
+(``labels=True``) the same pass also writes that assignment, an int32 a
+row, laid along the lanes as the rows are: a fit's labels cost one more read
+of the rows and no distances (``cluster/kmeans.py:_labels_of_rows``).
 
 The rows come **transposed**, ``xt`` of shape ``(f, n)``: a tall ``(n, f)``
 array whose width is no multiple of 128 lies rows-minor on the TPU (the
@@ -91,7 +94,8 @@ def _lane_sum(a, width):
 
 
 def _pass_kernel(nv_ref, x_ref, cb_ref, cn_ref, sums_ref, counts_ref, inertia_ref,
-                 acc_s, acc_c, acc_i, *, tile, chunk):
+                 *refs, tile, chunk):
+    *labels_ref, acc_s, acc_c, acc_i = refs  # with the request for labels their ref comes between
     i = pl.program_id(0)
     kp = cb_ref.shape[0]
 
@@ -121,6 +125,9 @@ def _pass_kernel(nv_ref, x_ref, cb_ref, cn_ref, sums_ref, counts_ref, inertia_re
         best = jnp.min(m2, axis=0, keepdims=True)
         # the lowest cluster number among the minima, as jnp.argmin
         number = jnp.min(jnp.where(m2 == best, cluster, np.int32(kp)), axis=0, keepdims=True)
+        if labels_ref:
+            # scores that are all NaN equal no minimum: 0, as jnp.argmin gives
+            labels_ref[0][:, pl.ds(at, chunk)] = jnp.where(number < np.int32(kp), number, np.int32(0))
         hit = cluster == number
         if masked:
             hit = hit & ok
@@ -161,7 +168,7 @@ def _pass_kernel(nv_ref, x_ref, cb_ref, cn_ref, sums_ref, counts_ref, inertia_re
         inertia_ref[...] = acc_i[...]
 
 
-def _pass_pallas(xt, centers, nvalid, *, interpret, chunk=None, chunks=_CHUNKS):
+def _pass_pallas(xt, centers, nvalid, *, interpret, labels=False, chunk=None, chunks=_CHUNKS):
     f, n = xt.shape
     k = centers.shape[0]
     kp = pl.cdiv(k, 16) * 16  # whole bfloat16 sublane tiles of clusters
@@ -175,7 +182,10 @@ def _pass_pallas(xt, centers, nvalid, *, interpret, chunk=None, chunks=_CHUNKS):
     cn = jnp.pad(jnp.sum(c32 * c32, axis=1, keepdims=True), ((0, kp - k), (0, 0)),
                  constant_values=_INF)
     whole = lambda shape: pl.BlockSpec(shape, lambda i, nv: (0, 0))
-    sums, counts, inertia = pl.pallas_call(
+    # the rows' numbers run along the lanes as the rows do: a tile a grid step
+    numbers_spec = [pl.BlockSpec((1, tile), lambda i, nv: (0, i))] if labels else []
+    numbers_shape = [jax.ShapeDtypeStruct((1, n), jnp.int32)] if labels else []
+    sums, counts, inertia, *numbers = pl.pallas_call(
         functools.partial(_pass_kernel, tile=tile, chunk=chunk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -185,7 +195,7 @@ def _pass_pallas(xt, centers, nvalid, *, interpret, chunk=None, chunks=_CHUNKS):
                 whole((kp, f)),
                 whole((kp, 1)),
             ],
-            out_specs=[whole((kp, f)), whole((kp, LANE)), whole((1, LANE))],
+            out_specs=[whole((kp, f)), whole((kp, LANE)), whole((1, LANE))] + numbers_spec,
             scratch_shapes=[
                 pltpu.VMEM((kp, f), jnp.float32),
                 pltpu.VMEM((kp, LANE), jnp.float32),
@@ -196,23 +206,24 @@ def _pass_pallas(xt, centers, nvalid, *, interpret, chunk=None, chunks=_CHUNKS):
             jax.ShapeDtypeStruct((kp, f), jnp.float32),
             jax.ShapeDtypeStruct((kp, LANE), jnp.float32),
             jax.ShapeDtypeStruct((1, LANE), jnp.float32),
-        ],
+        ] + numbers_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=32 * 1024 * 1024,
         ),
         cost_estimate=pl.CostEstimate(
             flops=4 * n * f * kp + 2 * n * f,
-            bytes_accessed=n * f * xt.dtype.itemsize,
+            bytes_accessed=n * f * xt.dtype.itemsize + (4 * n if labels else 0),
             transcendentals=0,
         ),
         interpret=interpret,
         name="ht_lloyd_pass",
     )(jnp.asarray(nvalid, jnp.int32).reshape(1), xt, cb, cn)
-    return sums[:k], jnp.sum(counts[:k], axis=1), jnp.sum(inertia)
+    three = sums[:k], jnp.sum(counts[:k], axis=1), jnp.sum(inertia)
+    return three + (numbers[0].reshape(n),) if labels else three
 
 
-def _pass_jnp(xt, centers, nvalid):
+def _pass_jnp(xt, centers, nvalid, labels=False):
     """The kernel's arithmetic in ``jax.numpy``, whole arrays at a time."""
     f, n = xt.shape
     k = centers.shape[0]
@@ -224,21 +235,27 @@ def _pass_jnp(xt, centers, nvalid):
         centers.astype(jnp.bfloat16), xb, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m2 = jnp.sum(c32 * c32, axis=1, keepdims=True) - _TWO * cross
-    hit = (jnp.argmin(m2, axis=0)[None, :] == jnp.arange(k)[:, None]) & ok[None, :]
+    number = jnp.argmin(m2, axis=0)
+    hit = (number[None, :] == jnp.arange(k)[:, None]) & ok[None, :]
     sums = jax.lax.dot_general(
         hit.astype(jnp.bfloat16), xb, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     xf = x.astype(jnp.float32)
     dist = jnp.maximum(jnp.sum(xf * xf, axis=0) + jnp.min(m2, axis=0), _ZERO)
-    return sums, jnp.sum(hit, axis=1, dtype=jnp.float32), jnp.sum(jnp.where(ok, dist, _ZERO))
+    three = sums, jnp.sum(hit, axis=1, dtype=jnp.float32), jnp.sum(jnp.where(ok, dist, _ZERO))
+    return three + (number.astype(jnp.int32),) if labels else three
 
 
 @jax.named_scope("ht.kmeans.pass")
-def lloyd_pass(xt: jax.Array, centers: jax.Array, nvalid):
+def lloyd_pass(xt: jax.Array, centers: jax.Array, nvalid, labels: bool = False):
     """Sums ``(k, f)``, counts ``(k,)`` and inertia, float32, of the rows
     ``xt[:, :nvalid]`` (``xt`` is ``(f, n)``, the rows transposed) assigned
-    to the nearest of ``centers`` ``(k, f)``."""
+    to the nearest of ``centers`` ``(k, f)``.  With ``labels`` (static) a
+    fourth result, the number of each row's nearest centre, int32 ``(n,)``:
+    ties go to the lowest number, a row whose scores are all NaN gets 0, and
+    what rows at or past ``nvalid`` get is not defined.  Without it the pass
+    writes nothing of size ``n``."""
     how = _mode()
     if how == "off":
-        return _pass_jnp(xt, centers, nvalid)
-    return _pass_pallas(xt, centers, nvalid, interpret=(how == "interpret"))
+        return _pass_jnp(xt, centers, nvalid, labels)
+    return _pass_pallas(xt, centers, nvalid, interpret=(how == "interpret"), labels=labels)

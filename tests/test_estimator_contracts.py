@@ -6,8 +6,10 @@ validation, fit-result invariances across splits and dtypes).
 """
 
 import numpy as np
+import pytest
 
 import heat_tpu as ht
+from heat_tpu.core import telemetry
 from .base import TestCase
 
 
@@ -209,3 +211,101 @@ class TestSpatialGraphContracts(TestCase):
         off = L - np.diag(np.diag(L))
         self.assertLessEqual(off.max(), 1e-6)
         self.assertGreaterEqual(np.diag(L).min(), -1e-6)
+
+
+# --------------------------------------------------- the seams labels pass through
+# ``perf/tests/test_faults.py`` plants its faults by replacing
+# ``_KCluster._assign_to_cluster``, ``kmeans._lloyd_loop`` and
+# ``kmeans._lloyd_step``; these hold the three seams where KMeans takes its
+# labels from the fused pass (ISSUE 33), with the kernel in the interpreter.
+
+def _far_blobs(n, f, k, seed=83):
+    """Row r in blob r % k, blobs far apart, values bfloat16 holds exactly."""
+    rng = np.random.default_rng(seed)
+    centres = np.round(8.0 * rng.normal(size=(k, f)))
+    return (centres[np.arange(n) % k] + np.round(4.0 * rng.normal(size=(n, f))) / 8.0).astype(np.float32)
+
+
+@pytest.fixture
+def label_spans(monkeypatch):
+    monkeypatch.setenv("HEAT_TPU_PALLAS", "interpret")
+    prev = telemetry.set_level("events")
+    telemetry.clear_events()
+    yield lambda: [e["assign"] for e in telemetry.events("span_end") if e["name"] == "kmeans.labels"]
+    telemetry.clear_events()
+    telemetry.set_level(prev)
+
+
+def test_replacing_assign_to_cluster_alters_what_a_fused_fit_stores(label_spans, monkeypatch):
+    from heat_tpu.cluster import _kcluster
+    from heat_tpu.core.dndarray import DNDarray
+
+    xn, k = _far_blobs(640, 20, 4), 4
+    x, init = ht.array(xn, split=0), ht.array(xn[:k], split=None)
+    fit = lambda: ht.cluster.KMeans(n_clusters=k, init=init, max_iter=4).fit(x)  # noqa: E731
+    clean = fit().labels_.numpy()
+    assign = _kcluster._KCluster._assign_to_cluster
+
+    def altered(self, x, *a, **kw):
+        lab = assign(self, x, *a, **kw)
+        arr = lab.larray.at[0, 0].set((lab.larray[0, 0] + 1) % self.n_clusters)
+        return DNDarray(arr, lab.gshape, lab.dtype, lab.split, lab.device, lab.comm)
+
+    monkeypatch.setattr(_kcluster._KCluster, "_assign_to_cluster", altered)
+    est = fit()
+    got = est.labels_.numpy()
+    assert label_spans() == ["fused", "fused"]
+    assert got[0, 0] == (clean[0, 0] + 1) % k and (got[1:] == clean[1:]).all()
+    assert est.predict(x).numpy()[0, 0] == got[0, 0]
+    assert "_assign_to_cluster" not in vars(ht.cluster.KMeans)  # one entry, the base class's
+
+
+def test_the_lloyd_loop_and_step_keep_the_shape_faults_are_planted_in(label_spans, monkeypatch):
+    import inspect
+
+    import jax.numpy as jnp
+    from heat_tpu.cluster import kmeans
+
+    loop = inspect.signature(kmeans._lloyd_loop.__wrapped__)
+    assert list(loop.parameters)[:5] == ["x", "centers", "k", "max_iter", "tol"]
+    assert all(p.default is not p.empty for p in list(loop.parameters.values())[5:])
+    step = inspect.signature(kmeans._lloyd_step.__wrapped__)
+    assert list(step.parameters)[:3] == ["x", "centers", "k"]
+    assert all(p.default is not p.empty for p in list(step.parameters.values())[3:])
+    xn, k = _far_blobs(640, 20, 4), 4
+    x, init = ht.array(xn, split=0), ht.array(xn[:k] + 0.5, split=None)
+    fused = kmeans._fused_rows(x, k)
+    assert fused is not None
+    assert len(kmeans._lloyd_loop(x.parray, init.larray, k, 2, 0.0, fused=fused)) == 4
+    # a loop that returns its centres unchanged: the labels are the
+    # assignment to THOSE centres, made after the loop and not by it
+    stuck = lambda x, centers, k, max_iter, tol, **how: (  # noqa: E731
+        centers, jnp.float32(0), jnp.float32(0), jnp.int32(max_iter))
+    monkeypatch.setattr(kmeans, "_lloyd_loop", stuck)
+    est = ht.cluster.KMeans(n_clusters=k, init=init, max_iter=3).fit(x)
+    np.testing.assert_array_equal(est.cluster_centers_.numpy(), xn[:k] + 0.5)
+    np.testing.assert_array_equal(est.labels_.numpy().ravel(), np.arange(640) % k)
+    assert est.n_iter_ == 3 and label_spans() == ["fused"]
+
+
+@pytest.mark.parametrize("which", ["kmedians", "kmedoids", "kmeans_width_128"])
+def test_what_the_kernel_does_not_take_is_assigned_lazily(which, label_spans):
+    """Another metric (no hook) or a width the rule declines: the lazy
+    distances and argmin, as before, though the Pallas tier is on."""
+    from heat_tpu.core.fusion import LazyDNDarray
+
+    f, k = (128, 4) if which == "kmeans_width_128" else (20, 4)
+    xn = _far_blobs(320, f, k)
+    x, init = ht.array(xn, split=0), ht.array(xn[:k], split=None)
+    make = {"kmedians": ht.cluster.KMedians, "kmedoids": ht.cluster.KMedoids,
+            "kmeans_width_128": ht.cluster.KMeans}[which]
+    est = make(n_clusters=k, init=init, max_iter=5).fit(x)
+    again = est.predict(x)
+    assert label_spans() == ["classic", "classic"]
+    assert isinstance(again, LazyDNDarray) and again.shape == (320, 1) and again.split == 0
+    centres = est.cluster_centers_.numpy().astype(np.float64)
+    apart = xn[:, None, :].astype(np.float64) - centres[None, :, :]
+    want = (np.abs(apart).sum(-1) if which != "kmeans_width_128" else (apart ** 2).sum(-1)).argmin(1)
+    np.testing.assert_array_equal(est.labels_.numpy().ravel(), want)
+    np.testing.assert_array_equal(again.numpy().ravel(), want)
+    np.testing.assert_array_equal(want, np.arange(320) % k)

@@ -811,6 +811,43 @@ class TestLayerSpans(TestCase):
             ends = [e for e in telemetry.events("span_end") if e["name"] == "kmeans.fit"]
         self.assertEqual([e["lloyd"] for e in ends], ["classic", "fused"])
 
+    def test_kmeans_labels_span_notes_what_assigned(self):
+        """``assign`` on ``kmeans.labels``' end event, ``fit``'s and
+        ``predict``'s: the lazy distances and argmin where the Pallas tier is
+        off, the fused pass's own labels where it is on."""
+        rng = np.random.default_rng(6)
+        x = ht.array(rng.normal(size=(64, 8)).astype(np.float32), split=0)
+        with _EventsLevel():
+            for how in ("off", "interpret"):
+                with mock.patch.dict(os.environ, {"HEAT_TPU_PALLAS": how}):
+                    est = ht.cluster.KMeans(n_clusters=3, max_iter=2, random_state=0).fit(x)
+                    est.predict(x)
+            ends = [e for e in telemetry.events("span_end") if e["name"] == "kmeans.labels"]
+            begins = {e["id"]: e for e in telemetry.events("span_begin")}
+        self.assertEqual([e["assign"] for e in ends], ["classic"] * 2 + ["fused"] * 2)
+        parents = [begins.get(e["parent"], {}).get("name") for e in ends]
+        self.assertEqual(parents, ["kmeans.fit", None] * 2)
+
+    def test_fused_kmeans_fit_waits_twice_and_never_for_the_guard(self):
+        """A fit whose labels the kernel writes closes two sync spans in
+        all, reading its labels included: no lazy program is left whose
+        flag the guard would read."""
+        rng = np.random.default_rng(7)
+        x = ht.array(rng.normal(size=(64, 8)).astype(np.float32), split=0)
+        init = ht.array(rng.normal(size=(3, 8)).astype(np.float32), split=None)
+        syncs = {}
+        with _EventsLevel():
+            for how in ("off", "interpret"):
+                telemetry.clear_events()
+                with mock.patch.dict(os.environ, {"HEAT_TPU_PALLAS": how}):
+                    est = ht.cluster.KMeans(n_clusters=3, init=init, max_iter=2).fit(x)
+                    _ = est.labels_.larray
+                syncs[how] = [e["name"] for e in telemetry.events("span_end")
+                              if e["name"].startswith(telemetry.SYNC_PREFIX)]
+        self.assertEqual(syncs["interpret"], ["sync:kmeans.n_iter", "sync:kmeans.inertia"])
+        # the lazy labels program's guard (``guard.flag`` on a TPU) is the third
+        self.assertEqual([n.split(".")[0] for n in syncs["off"][2:]], ["sync:guard"])
+
     def test_linalg_qr_span_names_the_path(self):
         rng = np.random.default_rng(4)
         tall = ht.array(rng.normal(size=(64, 4)).astype(np.float32), split=None)
