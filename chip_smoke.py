@@ -700,6 +700,81 @@ def st_brumby():
             "decode4_first_s": round(decode_s, 3), "logits_err": worst, "state_bytes": held}
 
 
+def st_deepseek():
+    """DeepSeek-V3.2 as the benchmark's configuration cuts it (one dense and
+    four expert layers at the published widths, experts 0-15 of 256 held, an
+    eighth of the vocabulary: 9.3 GB of seeded weights), on one device:
+    prefill of 2 x 4,096 tokens (so that the selection is a true choice: 4,096
+    > 2,048), a save, four greedy steps, a rewind and the same four steps
+    again, against the plain reference's full forward pass (per-head attention
+    with the selection as a mask, float32).  The median position is held to
+    the tolerance: a routing near-tie moves a single position by one expert's
+    whole output (PERF.md section 2)."""
+    from jax.sharding import Mesh
+
+    from heat_tpu.models import deepseek
+    from heat_tpu.parallel.mesh import MeshComm
+    from perf.reference import deepseek as reference
+
+    if REHEARSAL:
+        cfg = deepseek.DeepSeekConfig(
+            vocab_size=96, hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+            num_hidden_layers=3, first_k_dense_replace=1, num_attention_heads=4, q_lora_rank=48,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            index_n_heads=16, index_head_dim=16, index_topk=12, n_routed_experts=8,
+            num_experts_per_tok=2, n_group=4, topk_group=2, max_position_embeddings=512,
+            rope_original=16, experts_held=(2, 2), vocab_held=48, dtype="float32")
+        length, tol = 40, 1e-4
+    else:
+        cfg = deepseek.DeepSeekConfig(num_hidden_layers=5, first_k_dense_replace=1,
+                                      experts_held=(0, 16), vocab_held=16160)
+        length, tol = 4096, 3e-2
+    one = MeshComm(Mesh(np.array(jax.devices()[:1]), ("x",)), "x")
+    model = deepseek.DeepSeek(cfg, seed=11, comm=one)
+    tokens = np.random.default_rng(11).integers(0, cfg.vocab_held, (2, length)).astype(np.int32)
+    session = model.session(2, length + 8)
+    (first, saved), prefill_s = clocked(
+        lambda: (session.prefill(tokens), session.save()))
+    first_token = np.asarray(jnp.argmax(first.larray, -1))
+    (chosen, logits), decode_s = clocked(lambda: session.decode(4))
+    selection = np.asarray(model.last_selection)
+    session.rewind(saved)
+    again, again_logits = session.decode(4)
+    check(np.array_equal(np.asarray(again.larray), np.asarray(chosen.larray))
+          and np.array_equal(np.asarray(again_logits.larray), np.asarray(logits.larray)),
+          "decode after a rewind does not repeat itself")
+    check(selection.shape == (cfg.num_hidden_layers, 2, min(cfg.index_topk, session.capacity))
+          and (selection >= 0).all() and (selection < length + 4).all(),
+          "the last step's selection is not index_topk visible slots a layer and session")
+    rcfg = {k: getattr(cfg, k) for k in reference.SIZES if hasattr(cfg, k)}
+    rcfg["router_experts"] = cfg.n_routed_experts
+    rcfg["experts_first"], rcfg["n_routed_experts"] = cfg.experts_held
+    chosen = np.asarray(chosen.larray)
+    errs, missed = [], 0.0
+    for b in range(2):
+        seq = jnp.asarray(np.concatenate([tokens[b], [first_token[b]], chosen[b, :-1]]))
+        want = reference.forward(rcfg, model.params, seq, 4)
+        got = np.asarray(logits.larray[b], np.float64)
+        ref_logits = np.asarray(want["logits"], np.float64)
+        check(np.isfinite(got).all(), "deepseek logits: non-finite values")
+        errs.extend(np.linalg.norm(got - ref_logits, axis=-1) / np.linalg.norm(ref_logits, axis=-1))
+        for layer in range(cfg.num_hidden_layers):
+            read = np.zeros(length + 4, bool)
+            read[selection[layer, b]] = True
+            wanted = np.asarray(want["selected"][layer])
+            missed = max(missed, float((wanted & ~read).sum() / wanted.sum()))
+    median = float(np.median(errs))
+    check(median <= tol, f"deepseek logits vs reference: median error {median:.3e} > {tol:.3e}")
+    check(missed <= (0.0 if REHEARSAL else 0.05),
+          f"deepseek selection: {missed:.4f} of the reference's positions not read")
+    held = session.cache_bytes()["shared"]
+    model.params = None
+    return {"layers": cfg.num_hidden_layers, "prefill_s": round(prefill_s, 3),
+            "decode4_first_s": round(decode_s, 3), "logits_err_median": median,
+            "logits_err_worst": float(max(errs)), "selection_missed": missed,
+            "cache_bytes": held}
+
+
 def st_fence():
     """ROADMAP S1: one chain of matmuls timed by block_until_ready and by a
     scalar readback.  If the two agree and both dwarf the enqueue-only
@@ -907,6 +982,7 @@ def main():
     stage("moe_ffn", st_moe)
     stage("pallas_kernels", st_kernels)
     stage("brumby_serve", st_brumby)
+    stage("deepseek_serve", st_deepseek)
     stage("fence_timing", st_fence)
     if NDEV > 1:
         stage("multichip_schedules", st_multichip)

@@ -6,10 +6,12 @@ from .transformer import TransformerLM, TransformerBlock, MoEMlp
 from .session import DecodeSession
 from .sambay import SambaY, SambaYConfig
 from .brumby import Brumby, BrumbyConfig
+from .deepseek import DeepSeek, DeepSeekConfig
 
 __all__ = [
     "MLP",
     "ResNet", "ResNet18", "ResNet34", "ResNet50", "ResNet101", "ResNet152",
     "TransformerLM", "TransformerBlock", "MoEMlp",
-    "SambaY", "SambaYConfig", "Brumby", "BrumbyConfig", "DecodeSession",
+    "SambaY", "SambaYConfig", "Brumby", "BrumbyConfig", "DeepSeek", "DeepSeekConfig",
+    "DecodeSession",
 ]

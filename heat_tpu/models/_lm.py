@@ -1,9 +1,10 @@
 """What the served language models share below the session: the seeded
 parameter trees (a spec of ``(shape, init)`` leaves, made leaf by leaf on the
 device), the product in the weights' type with float32 accumulation, the
-gated-SiLU MLP, the embedding lookup and the greedy head.  A model
-(:mod:`~heat_tpu.models.sambay`, :mod:`~heat_tpu.models.brumby`) keeps its own
-norms, mixers and programs.
+gated-SiLU MLP, the embedding lookup, the greedy head and the rotary
+frequencies under YaRN.  A model (:mod:`~heat_tpu.models.sambay`,
+:mod:`~heat_tpu.models.brumby`, :mod:`~heat_tpu.models.deepseek`) keeps its
+own norms, mixers and programs.
 """
 
 from __future__ import annotations
@@ -13,6 +14,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _F32 = jnp.float32
 
@@ -90,3 +92,27 @@ def greedy(h, table):
     logits = jax.lax.dot_general(h.astype(table.dtype), table, (((1,), (1,)), ((), ())),
                                  preferred_element_type=_F32)
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
+
+
+# ---------------------------------------------------------------------- rotary
+
+def yarn_frequencies(dim: int, theta: float, factor: float = 1.0, original: int = 4096,
+                     beta_fast: float = 32.0, beta_slow: float = 1.0) -> np.ndarray:
+    """The ``dim / 2`` rotary frequencies ``theta^(-2i / dim)`` stretched by
+    YaRN: a pair that turns more than ``beta_fast`` times over the
+    ``original`` context keeps its frequency, one that turns less than
+    ``beta_slow`` times has it divided by ``factor``, and between the two
+    correction dimensions the two are blended by a linear ramp.  float32;
+    ``factor`` 1 is the plain embedding."""
+    freq = float(theta) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if float(factor) == 1.0:
+        return freq.astype(np.float32)
+
+    def correction_dim(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    span = (high - low) or 0.001
+    stretched = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / span, 0.0, 1.0)
+    return (freq / factor * stretched + freq * (1.0 - stretched)).astype(np.float32)

@@ -16,7 +16,9 @@ programs walks:
 - ``serve_prefill(shared, state, ids, position) -> (shared, state, token,
   logits)``: its jitted prefill programs over a prompt, chunk by chunk;
 - ``serve_decode(shared, state, token, position, steps) -> (shared, state,
-  token, chosen, logits)``: its jitted decode program;
+  token, chosen, logits)``: its jitted decode program; a sixth element, where
+  a model returns one, is a dictionary of device numbers for the ``lm``
+  counters that ride back with the tokens in the one readback;
 - ``serve_notes(session, steps) -> (notes, counts)``: the attributes its
   ``lm.decode`` span carries and what the call adds to the ``lm`` counters;
 - ``serve_bytes(shared, state) -> dict``: the bytes held, by kind.
@@ -51,11 +53,17 @@ __all__ = ["DecodeSession", "Snapshot"]
 # not run and the jax.numpy fallback reads the cache); state_bytes_stepped is
 # the constant-size state read plus written by decode steps whose mixer walks
 # all of it every step (power retention), state_bytes_copied what save and
-# rewind copied; cache_bytes is what the newest session allocated, by kind
+# rewind copied; index_keys_scanned and latent_rows_read are the index keys
+# that a sparse selection scored and the latent rows its attention then read
+# (from shapes), expert_pairs and experts_hit the token-expert pairs computed
+# on the experts held here and the held experts that received a token, summed
+# over a call's steps and layers (counted on the device); cache_bytes is what
+# the newest session allocated, by kind
 _LM = telemetry.register_group(
     "lm",
     {"decode_steps": 0, "prefill_tokens": 0, "cache_keys_visible": 0, "cache_keys_fetched": 0,
      "state_bytes_stepped": 0, "state_bytes_copied": 0,
+     "index_keys_scanned": 0, "latent_rows_read": 0, "expert_pairs": 0, "experts_hit": 0,
      "cache_bytes": {"shared": 0, "window": 0, "state": 0}},
 )
 
@@ -158,21 +166,23 @@ class DecodeSession:
             raise ValueError(f"{self.position} + {steps} positions pass the session's {self.capacity}")
         notes, counts = self.model.serve_notes(self, steps)
         with telemetry.span("lm.decode", **notes):
-            self._shared, self._state, self._token, chosen, logits = self.model.serve_decode(
-                self._shared, self._state, self._token, self.position, steps)
+            self._shared, self._state, self._token, chosen, logits, *counted = (
+                self.model.serve_decode(self._shared, self._state, self._token, self.position,
+                                        steps))
             with telemetry.sync("lm.tokens"):
-                self.tokens = np.asarray(chosen)
+                self.tokens, counted = jax.device_get((chosen, dict(*counted)))
         self.position += steps
         _LM["decode_steps"] += steps
-        for name, count in counts.items():
-            _LM[name] += count
+        for name, count in {**counts, **counted}.items():
+            _LM[name] += int(count)
         return self._wrap(chosen), self._wrap(logits)
 
     def save(self) -> Snapshot:
         """The current position, to :meth:`rewind` to.  Copies the
         constant-size state (window rings and Mamba states; a retention
-        model's whole state); a shared key/value cache is not copied, its
-        entries past a saved position are simply overwritten later."""
+        model's whole state; nothing of a model whose whole cache grows with
+        the context); a shared key/value cache is not copied, its entries
+        past a saved position are simply overwritten later."""
         if self._token is None:
             raise ValueError("nothing to save before the first prefill")
         token, state = _copied((self._token, self._state))

@@ -24,6 +24,16 @@ program; the two all-to-alls ride ICI.  ``mesh=None`` runs the same
 routing on one device; since capacity and drop priority are enforced per
 shard, the two paths agree exactly only while nothing is dropped
 (``fraction_dropped == 0`` — the regime training aims for).
+
+**Which entry point drops tokens.**  :func:`moe_ffn` (softmax top-k, a
+static per-expert capacity, either every expert here or an ``all_to_all``)
+drops the choices that pass an expert's capacity.  :func:`held_experts_ffn`
+never drops one: it serves a model whose experts are shared between chips,
+routes every token over *all* the experts (sigmoid scores, a balancing bias
+in the choice only, groups of experts, :func:`sigmoid_group_routing`), is
+told which consecutive experts this chip holds, and returns their part of
+the routed sum.  It runs no exchange and stands in for none: what the other
+chips' experts would add is theirs to add.
 """
 
 from __future__ import annotations
@@ -38,7 +48,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .collectives import all_to_all, axis_size, psum, shard_map_unchecked
 
-__all__ = ["top_k_routing", "moe_ffn", "expert_capacity"]
+__all__ = ["top_k_routing", "moe_ffn", "expert_capacity", "held_experts_ffn",
+           "sigmoid_group_routing"]
 
 
 def expert_capacity(
@@ -350,3 +361,132 @@ def _moe_ffn_quantized(
         desc=f"moe_ffn t={tokens} d={d} h={qw_in.shape[2]} "
              f"E={gate_w.shape[1]} S={n}",
     )
+
+
+# ------------------------------------------------- one chip's share of experts
+
+# up to this many tokens the held experts are streamed over all of them: the
+# layer is then bound by reading each expert once (256 tokens are 23 GFLOP an
+# expert, as long on one v5e's MXU as the expert's 88 MB are on its HBM), and
+# past it the tokens are sorted by expert
+STREAM_TOKENS = 256
+# rows of one expert's tokens that a step of the grouped products takes
+GROUP_TILE = 256
+
+
+def sigmoid_group_routing(h, router, bias, *, top_k: int, n_group: int, topk_group: int,
+                          scale: float):
+    """Route ``h`` of ``(tokens, d)`` over all ``E`` experts of ``router``
+    ``(d, E)``: scores ``s = sigmoid(h router)``; the choice is made by ``s +
+    bias`` (the balancing bias takes no part in the weights): the experts lie
+    in ``n_group`` groups of consecutive numbers, a group scores the sum of
+    its two largest, the ``topk_group`` best groups stay, and among their
+    experts the ``top_k`` largest are chosen.  Weights ``scale * s_e / sum of
+    the chosen s``.  The product is float32 at ``highest``: a choice is a
+    discontinuity, and the router is the smallest matrix of the layer.
+
+    Returns ``(weights, chosen)``: float32 ``(tokens, top_k)`` and int32
+    ``(tokens, top_k)`` expert numbers."""
+    experts = router.shape[1]
+    s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
+    choice = s + bias.astype(jnp.float32)
+    if n_group > 1:
+        by_group = choice.reshape(-1, n_group, experts // n_group)
+        group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+        kept = jax.lax.top_k(group_score, topk_group)[1]                     # (tokens, topk_group)
+        stays = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+        choice = jnp.where(jnp.repeat(stays, experts // n_group, axis=1), choice, -jnp.inf)
+    chosen = jax.lax.top_k(choice, top_k)[1]
+    weights = jnp.take_along_axis(s, chosen, axis=1)
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True) * scale
+    return weights, chosen.astype(jnp.int32)
+
+
+def _dot(x, w):
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
+
+
+def _streamed(x, mine, experts):
+    """Every held expert over every token, the routing weight (zero where the
+    token did not choose the expert) as the mask: the same device work
+    whatever the router chose."""
+    w_gate, w_up, w_down = experts["w_gate"], experts["w_up"], experts["w_down"]
+    xb = x.astype(w_gate.dtype)
+    gate = jnp.einsum("td,edf->tef", xb, w_gate, preferred_element_type=jnp.float32)
+    up = jnp.einsum("td,edf->tef", xb, w_up, preferred_element_type=jnp.float32)
+    inner = (jax.nn.silu(gate) * up * mine[:, :, None]).astype(w_down.dtype)
+    return jnp.einsum("tef,efd->td", inner, w_down, preferred_element_type=jnp.float32)
+
+
+def _grouped(x, weights, local, mine, experts):
+    """The token-expert pairs sorted by held expert, then one expert's rows
+    ``GROUP_TILE`` at a time: as many steps as the pairs that landed here
+    need, none padded to a capacity and none dropped."""
+    tile = GROUP_TILE
+    tokens, d = x.shape
+    top_k = local.shape[1]
+    count = experts["w_gate"].shape[0]
+    pairs = tokens * top_k
+    key = jnp.where(mine, local, count).reshape(-1)             # pairs of other chips sort last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32)
+    starts = jnp.cumsum(sizes) - sizes
+    tiles = (sizes + tile - 1) // tile
+    tiles_before = jnp.cumsum(tiles)                            # through each expert
+    rows = jnp.take(x.astype(experts["w_gate"].dtype), order // top_k, axis=0)
+    rows = jnp.pad(rows, ((0, tile), (0, 0)))
+
+    def one_tile(i, out):
+        e = jnp.searchsorted(tiles_before, i, side="right").astype(jnp.int32)
+        first = starts[e] + (i - (tiles_before[e] - tiles[e])) * tile
+        part = {name: jax.lax.dynamic_index_in_dim(w, e, keepdims=False)
+                for name, w in experts.items()}
+        h = jax.lax.dynamic_slice_in_dim(rows, first, tile)
+        y = _dot(jax.nn.silu(_dot(h, part["w_gate"])) * _dot(h, part["w_up"]), part["w_down"])
+        here = (first + jnp.arange(tile, dtype=jnp.int32)) < starts[e] + sizes[e]
+        old = jax.lax.dynamic_slice_in_dim(out, first, tile)
+        return jax.lax.dynamic_update_slice_in_dim(out, jnp.where(here[:, None], y, old),
+                                                   first, axis=0)
+
+    out = jax.lax.fori_loop(0, tiles_before[-1], one_tile,
+                            jnp.zeros((pairs + tile, d), jnp.float32))
+    place = jnp.argsort(order)                                  # where each pair's row lies
+    back = jnp.take(out, place, axis=0).reshape(tokens, top_k, d)
+    return jnp.sum(back * jnp.where(mine, weights, 0.0)[:, :, None], axis=1)
+
+
+def held_experts_ffn(x, router, experts, *, held, top_k: int, n_group: int = 1,
+                     topk_group: int = 1, scale: float = 1.0, bias=None):
+    """The held experts' part of a routed gated-SiLU layer: ``sum over e
+    chosen and held of g_e * Expert_e(x)`` for every token of ``x`` ``(tokens,
+    d)``, float32.  ``router`` ``(d, E)`` is over all ``E`` experts
+    (:func:`sigmoid_group_routing`); ``experts`` holds this chip's, ``w_gate``
+    and ``w_up`` of ``(count, d, f)`` and ``w_down`` of ``(count, f, d)``;
+    ``held = (first, count)`` are their numbers among the ``E``.
+
+    Two lowerings of one sum, chosen by the number of tokens alone: up to
+    :data:`STREAM_TOKENS` (a decode step) every held expert is streamed once over
+    all tokens, so that the device's work does not depend on the routing;
+    past it (a prefill chunk) the pairs are sorted by expert and only the
+    pairs that landed here are computed.  No token is ever dropped: there is
+    no capacity.
+
+    Returns ``(y, counts)``; ``counts`` are two int32 device numbers, the
+    token-expert pairs computed here and the held experts that received a
+    token."""
+    first, count = held
+    if bias is None:
+        bias = jnp.zeros((router.shape[1],), jnp.float32)
+    weights, chosen = sigmoid_group_routing(x, router, bias, top_k=top_k, n_group=n_group,
+                                            topk_group=topk_group, scale=scale)
+    local = chosen - first
+    mine = (local >= 0) & (local < count)
+    lands = mine[:, :, None] & (local[:, :, None] == jnp.arange(count))    # (tokens, top_k, count)
+    counts = {"pairs": jnp.sum(mine, dtype=jnp.int32),
+              "hit": jnp.sum(jnp.any(lands, axis=(0, 1)), dtype=jnp.int32)}
+    with jax.named_scope("ht.lm.moe_experts"):      # the held experts' products alone
+        if x.shape[0] <= STREAM_TOKENS:
+            dense = jnp.sum(jnp.where(lands, weights[:, :, None], 0.0), axis=1)
+            return _streamed(x, dense, experts), counts
+        return _grouped(x, weights, local, mine, experts), counts
