@@ -88,7 +88,8 @@ import numpy as np  # noqa: E402
 from heat_tpu import native, serving  # noqa: E402
 from heat_tpu.core import autotune, fusion, telemetry, wire  # noqa: E402
 from heat_tpu.core.dndarray import DNDarray  # noqa: E402
-from heat_tpu.ops import _pallas_common, attention, lasso_sweep, lloyd_pass, qr_panel  # noqa: E402
+from heat_tpu.ops import (_pallas_common, attention, lasso_sweep, latent_attention,  # noqa: E402
+                          lloyd_pass, qr_panel)
 from heat_tpu.ops import cdist as cdist_kernel  # noqa: E402
 from heat_tpu.parallel import overlap, transport  # noqa: E402
 from heat_tpu.utils import compile_cache  # noqa: E402
@@ -107,7 +108,7 @@ FULL = dict(
     resnet=(256, 224), resnet_classes=1000, serve_f=64, serve_reqs=48,
     attn=(16, 4096, 128), moe=(16_384, 1024, 4096), qr_panel=(262_144, 256),
     fence_k=(8, 108), wire_resplit=(16_384, 4096), ring_mm=(4096, 8192, 4096),
-    sp_attn=(2, 8, 4096, 128), pipe=(512, 1024),
+    sp_attn=(2, 8, 4096, 128), pipe=(512, 1024), sparse_cut=(16, 33_024, 2048),
 )
 TOY = dict(
     matmul_n=256, odd=(131, 67), reshape_in=(999, 20), reshape_out=(1998, 10),
@@ -116,7 +117,7 @@ TOY = dict(
     resnet_classes=10, serve_f=16, serve_reqs=24, attn=(2, 256, 32),
     moe=(256, 64, 128), qr_panel=(2048, 128), fence_k=(2, 6),
     wire_resplit=(512, 256), ring_mm=(256, 512, 256), sp_attn=(1, 4, 256, 32),
-    pipe=(32, 64),
+    pipe=(32, 64), sparse_cut=(3, 384, 128),
 )
 SZ = TOY if REHEARSAL else FULL
 
@@ -648,6 +649,25 @@ def st_kernels():
     got, first, steady = first_and_steady(lambda: fused(X, yv, th))
     err = close(got, _cd_sweep(X, yv, th, 0.05), 1e-4, "lasso_sweep kernel vs classic sweep")
     info["lasso_sweep"] = [round(first, 3), round(steady, 4), err]
+
+    # the sparse selection at the benchmark cell's shape: relu'd scores (a third of a row
+    # is one value, so ties straddle the cut in some rows), a short row, a row of zeros
+    batch, capacity, k = SZ["sparse_cut"]
+    form = latent_attention.selection_form(batch, capacity, k)
+    check(form == "cut_kernel", f"sparse_cut form {form!r}")
+    score = np.maximum(rng.standard_normal((batch, capacity)) + 0.4, 0).astype(np.float32)
+    score[0, : capacity // 2] *= 0
+    score[1, k // 2:] = -np.inf
+    score[:, capacity - 244:] = -np.inf
+    score = jnp.asarray(score)
+    fused = jax.jit(lambda v: latent_attention.largest_slots(v, k))
+    got, first, steady = first_and_steady(lambda: fused(score))
+    top, want = jax.lax.top_k(score, k)
+    want = np.sort(np.where(np.asarray(top) > -np.inf, np.asarray(want), -1), axis=-1)
+    got = np.asarray(got)
+    check(np.array_equal(np.sort(got, axis=-1), want), "sparse_cut vs lax.top_k: another set")
+    check(all((np.diff(r[r >= 0]) > 0).all() for r in got), "sparse_cut: not in slot order")
+    info["sparse_cut"] = [round(first, 3), round(steady, 5), 0.0]
     return {"kernel": "[first_s, steady_s, max_err]", **info}
 
 
@@ -733,6 +753,8 @@ def st_deepseek():
     model = deepseek.DeepSeek(cfg, seed=11, comm=one)
     tokens = np.random.default_rng(11).integers(0, cfg.vocab_held, (2, length)).astype(np.int32)
     session = model.session(2, length + 8)
+    form = latent_attention.selection_form(2, session.capacity, cfg.index_topk)
+    check(form == "cut_kernel", f"the selection of a decode step is {form!r}, not the kernel")
     (first, saved), prefill_s = clocked(
         lambda: (session.prefill(tokens), session.save()))
     first_token = np.asarray(jnp.argmax(first.larray, -1))

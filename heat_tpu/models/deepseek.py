@@ -56,7 +56,7 @@ import numpy as np
 from ..core import telemetry
 from ..ops.latent_attention import (KEY_BLOCK, ROPE_PACK, index_scores_chunk, kth_largest,
                                     latent_decode_attention, latent_prefill_attention,
-                                    sparse_select)
+                                    selection_form, sparse_select)
 from ..parallel.expert import held_experts_ffn
 from ._lm import (dot as _dot, embed as _embed, gated_mlp as _gated_mlp, greedy as _greedy,
                   init_tree, spec_size, yarn_frequencies)
@@ -532,13 +532,16 @@ class DeepSeek:
         itemsize = jnp.dtype(cfg.dtype).itemsize
         seen = [session.position + j + 1 for j in range(steps)]
         each = session.batch * cfg.num_hidden_layers
-        notes = dict(batch=session.batch, context=session.position, steps=steps,
+        select = selection_form(session.batch, session.capacity, cfg.index_topk)
+        notes = dict(batch=session.batch, context=session.position, steps=steps, select=select,
                      layers=cfg.num_hidden_layers, moe_layers=cfg.moe_layers,
                      selected=cfg.index_topk, latent_bytes=cfg.latent_width * itemsize,
                      index_bytes=cfg.index_head_dim * itemsize, experts_held=cfg.experts_held[1],
                      expert_bytes=3 * cfg.hidden_size * cfg.moe_intermediate_size * itemsize)
         return notes, {"index_keys_scanned": each * sum(seen),
-                       "latent_rows_read": each * sum(min(cfg.index_topk, s) for s in seen)}
+                       "latent_rows_read": each * sum(min(cfg.index_topk, s) for s in seen),
+                       "selections_by_cut": (steps * cfg.num_hidden_layers
+                                             if select.startswith("cut") else 0)}
 
     def serve_decode(self, shared, state, token, position: int, steps: int):
         shared, token, chosen, logits, self.last_selection, pairs, hit = _decode(

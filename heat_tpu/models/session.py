@@ -55,15 +55,17 @@ __all__ = ["DecodeSession", "Snapshot"]
 # all of it every step (power retention), state_bytes_copied what save and
 # rewind copied; index_keys_scanned and latent_rows_read are the index keys
 # that a sparse selection scored and the latent rows its attention then read
-# (from shapes), expert_pairs and experts_hit the token-expert pairs computed
-# on the experts held here and the held experts that received a token, summed
-# over a call's steps and layers (counted on the device); cache_bytes is what
-# the newest session allocated, by kind
+# (from shapes), selections_by_cut the layer-steps whose selection was a cut and
+# a compaction and not every visible slot, expert_pairs and experts_hit the
+# token-expert pairs computed on the experts held here and the held experts
+# that received a token, summed over a call's steps and layers (counted on the
+# device); cache_bytes is what the newest session allocated, by kind
 _LM = telemetry.register_group(
     "lm",
     {"decode_steps": 0, "prefill_tokens": 0, "cache_keys_visible": 0, "cache_keys_fetched": 0,
      "state_bytes_stepped": 0, "state_bytes_copied": 0,
-     "index_keys_scanned": 0, "latent_rows_read": 0, "expert_pairs": 0, "experts_hit": 0,
+     "index_keys_scanned": 0, "latent_rows_read": 0, "selections_by_cut": 0,
+     "expert_pairs": 0, "experts_hit": 0,
      "cache_bytes": {"shared": 0, "window": 0, "state": 0}},
 )
 

@@ -17,8 +17,8 @@ reads only the ``k`` positions ``s <= t`` of largest
 **A decode step** (one query a session):
 
 - :func:`sparse_select` scores every visible slot of the index cache and takes
-  the exact top ``k``: ``(batch, k)`` slot numbers, ``-1`` where fewer than
-  ``k`` are visible;
+  the exact top ``k``: ``(batch, k)`` slot numbers in slot order, ``-1`` where
+  fewer than ``k`` are visible;
 - :func:`latent_decode_attention` attends in the latent space over those rows:
   the caller has folded ``W_uk`` into the queries (``q_lat = W_uk^T q_nope``)
   and folds ``W_uv`` into what comes back, so that a step reads ``rank + rope``
@@ -32,23 +32,36 @@ per-head form, blockwise with a running softmax: keys and values of a block of
 positions are computed from its latent rows when the block is read, so no
 ``(chunk, context)`` array of all heads is ever alive.
 
-All of it is ``jax.numpy`` here; products take their operands in the caches'
-type and accumulate in float32.  Kernels, where this module gains them, are
-entered by :func:`~heat_tpu.ops._pallas_common.mode` alone, as
-``ops/decode_attention.py`` and ``ops/power_retention.py`` are: on every input
-they accept they replace a lowering that moves more bytes, so there is no
-classic body that could win somewhere and no autotune arm.  What XLA makes of
-``top_k`` and of the row gather at a decode cell's shapes is in PERF.md
-section 6 (PR 34).
+Products take their operands in the caches' type and accumulate in float32.
+The scan, the gather and the prefill path are ``jax.numpy`` (XLA fuses the scan
+into one pass over the index keys at 91% of its bytes' floor).  The selection
+behind the scan is *cut, membership, compaction* and no sort
+(:func:`largest_slots`): on a TPU one Pallas kernel, ``ht_sparse_cut``, entered
+by :func:`~heat_tpu.ops._pallas_common.mode` alone, as
+``ops/decode_attention.py`` and ``ops/power_retention.py`` are, and by what the
+shapes show (:func:`selection_form`); elsewhere the same three parts as
+``jax.numpy``.  There is no classic body that could win somewhere and no
+autotune arm: XLA's ``top_k`` at a decode cell's shapes is a stable sort of
+every score, ten times the kernel's time (PERF.md section 6, PRs 34 and 35,
+which also has what XLA makes of the row gather).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ._pallas_common import LANE
+from ._pallas_common import mode as _mode
 
 __all__ = ["KEY_BLOCK", "ROPE_PACK", "index_scores", "index_scores_chunk", "kth_largest",
-           "latent_decode_attention", "latent_prefill_attention", "rope_rows", "sparse_select"]
+           "largest_slots", "latent_decode_attention", "latent_prefill_attention", "rope_rows",
+           "selection_form", "sparse_select"]
 
 _F32 = jnp.float32
 _NEG = -jnp.inf
@@ -86,13 +99,255 @@ def index_scores(q_idx, w, k_idx_cache, kv_len):
     return jnp.where(jnp.arange(k_idx_cache.shape[1])[None, :] < kv_len, score, _NEG)
 
 
+# ---- the selection: cut, membership, compaction
+
+# slots of one lane group: the compaction's two levels are the groups and the
+# lanes inside one
+_GROUP = LANE
+_INT_MIN = np.int32(-2 ** 31)
+# the kernel holds the call's scores, their integer image, a table of groups x
+# groups and a session's working set in the VMEM every kernel has without
+# asking (asking for more takes it from XLA, which keeps a layer's rope cache
+# there for the gather that follows: PERF.md section 6, PR 35): scores of this
+# many bytes and sessions of this many groups, or fewer
+_VMEM_SCORES = 4 << 20
+_VMEM_GROUPS = 1024
+
+
+def _image(x):
+    """float32 -> int32 whose signed order is the floats' (``-inf`` lowest,
+    the two zeros one value)."""
+    bits = jax.lax.bitcast_convert_type(jnp.where(x == 0, np.float32(0), x), jnp.int32)
+    return bits ^ ((bits >> np.int32(31)) & np.int32(0x7FFFFFFF))
+
+
+_NEG_IMAGE = np.int32(np.array(-np.inf, np.float32).view(np.int32) ^ 0x7FFFFFFF)
+
+
+def _bisect(count_at_least, k, like):
+    """The largest image ``c`` with ``count_at_least(c) >= k``: 32 counting
+    passes, one bit of the order-preserving unsigned image a pass (kept as
+    int32 bits; ``^ _INT_MIN`` is the signed image)."""
+    def one_bit(i, cut):
+        tried = cut | (np.int32(1) << (np.int32(31) - i))
+        return jnp.where(count_at_least(tried ^ _INT_MIN) >= k, tried, cut)
+
+    return jax.lax.fori_loop(np.int32(0), np.int32(32), one_bit, jnp.zeros_like(like)) ^ _INT_MIN
+
+
+def _select_jnp(score, k: int):
+    """The three parts as ``jax.numpy``: ``score`` ``(batch, groups * 128)``
+    float32, ``k`` below the row length."""
+    batch = score.shape[0]
+    groups = score.shape[1] // _GROUP
+    img = _image(score)
+    # 1. the cut: each row's k-th largest, and how many lie strictly above it
+    cut = _bisect(lambda c: jnp.sum(img >= c, axis=-1, keepdims=True, dtype=jnp.int32), k,
+                  img[:, :1])
+    wanted = k - jnp.sum(img > cut, axis=-1, keepdims=True, dtype=jnp.int32)
+    # 2. membership: of the scores equal to the cut, the lowest slots
+    img = img.reshape(batch, groups, _GROUP)
+    cut, wanted = cut[:, :, None], wanted[:, :, None]
+
+    def before(mask):
+        """(count inside the group up to and with each lane, each group's total,
+        the total of the groups before it)"""
+        incl = jnp.cumsum(mask, axis=-1, dtype=jnp.int32)
+        total = incl[:, :, -1]
+        return incl, total, jnp.cumsum(total, axis=-1) - total
+
+    tied = (img == cut) & (img > _NEG_IMAGE)
+    incl, _, passed = before(tied)
+    member = (img > cut) | (tied & (incl - 1 + passed[:, :, None] < wanted))
+    # 3. compaction: place j holds the slot of the member of rank j
+    incl, total, passed = before(member)
+    place = jnp.arange(k, dtype=jnp.int32)[None, :, None]
+    through = (passed + total)[:, None, :] <= place                     # (batch, k, groups)
+    group = jnp.sum(through, axis=-1, dtype=jnp.int32)                   # (batch, k)
+    rank = place[:, :, 0] - jnp.sum(jnp.where(through, total[:, None, :], 0), axis=-1)
+    one_hot = group[:, :, None] == jnp.arange(groups, dtype=jnp.int32)
+    counts = jnp.einsum("bkg,bgl->bkl", one_hot.astype(jnp.bfloat16), incl.astype(jnp.bfloat16),
+                        preferred_element_type=_F32)                      # at most 128: exact
+    lane = jnp.sum(counts <= rank[:, :, None].astype(_F32), axis=-1, dtype=jnp.int32)
+    return jnp.where(group < groups, group * _GROUP + lane, -1)
+
+
+def _cut_kernel(tri_ref, low_ref, score_ref, out_ref, img_ref, cut_ref, wanted_ref, *,
+                k, rows, width):
+    """All of :func:`largest_slots` on scores ``(batch, groups, 128)``: a
+    session's groups along the sublanes, a group's slots along the lanes.
+    ``rows`` groups (a whole number of sublane tiles) hold every real slot, the
+    rest is ``-inf`` up to whole lane tiles of groups, the contraction width of
+    the compaction's products.  ``tri_ref`` ``(128, 384)``: lane ``l'`` of the
+    first block counts the lanes ``l <= l'``, the second every lane, the third
+    is the first turned round; ``low_ref`` ``(groups, groups)``: row ``g``
+    counts the groups before ``g``.  ``out_ref`` ``(batch, places)``, ``width``
+    places at a time."""
+    batch, groups, _ = score_ref.shape
+    tile = 8
+    bf16 = jnp.bfloat16
+
+    # ---- 1. the cut, every session at once: a pass is rows / 8 compares and adds a session
+    for t in range(rows // tile):
+        at = pl.ds(t * tile, tile)
+        img_ref[:, at, :] = _image(score_ref[:, at, :])
+    if groups > rows:
+        img_ref[:, rows:, :] = jnp.full((batch, groups - rows, _GROUP), _NEG_IMAGE, jnp.int32)
+
+    def count(reaches):
+        acc = jnp.zeros((batch, tile, _GROUP), jnp.int32)
+        for t in range(rows // tile):
+            acc = acc + reaches(img_ref[:, pl.ds(t * tile, tile), :]).astype(jnp.int32)
+        acc = jnp.sum(acc.astype(_F32), axis=1, keepdims=True)
+        return jnp.sum(acc, axis=2, keepdims=True).astype(jnp.int32)
+
+    cut = _bisect(lambda c: count(lambda x: x >= c), k, jnp.zeros((batch, 1, 1), jnp.int32))
+    wanted = k - count(lambda x: x > cut)
+    cut_ref[...] = jnp.broadcast_to(cut, cut_ref.shape)
+    wanted_ref[...] = jnp.broadcast_to(wanted, wanted_ref.shape).astype(_F32)
+
+    # ---- 2 and 3, a session at a time
+    group_id = jax.lax.broadcasted_iota(jnp.int32, (groups, width), 0)
+    place_id = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+
+    def counted(mask):
+        """(the mask as numbers, its count inside the group up to and with each
+        lane, each group's total in every lane, the total of the groups before)"""
+        m = mask.astype(_F32).astype(bf16)
+        both = jnp.dot(m, tri_ref[:, :2 * _GROUP], preferred_element_type=_F32)
+        incl, total = both[:, :_GROUP], both[:, _GROUP:]
+        passed = jnp.dot(low_ref[...], total.astype(bf16), preferred_element_type=_F32)
+        return m, incl, total, passed
+
+    def one_session(b, carry):
+        img = img_ref[b]
+        cut = cut_ref[b][:1, :]
+        tied = (img == cut) & (img > _NEG_IMAGE)
+        _, incl, _, passed = counted(tied)
+        member = (img > cut) | (tied & (incl - 1.0 + passed < wanted_ref[b][:1, :]))
+        m, _, total, passed = counted(member)
+        # the running counts with the lanes along the sublanes: (128, groups)
+        running = jax.lax.dot_general(tri_ref[:, 2 * _GROUP:], m, (((1,), (1,)), ((), ())),
+                                      preferred_element_type=_F32).astype(bf16)
+        through = (passed + total)[:rows]
+        through = jnp.concatenate([through] * (width // _GROUP), axis=1)
+        total = jnp.concatenate([total[:rows]] * (width // _GROUP), axis=1)
+        for c in range(out_ref.shape[1] // width):
+            place = (place_id + np.int32(c * width)).astype(_F32)
+            done = through <= place                                            # (rows, width)
+            group = jnp.sum(done.astype(_F32), axis=0, keepdims=True)
+            rank = place - jnp.sum(jnp.where(done, total, 0.0), axis=0, keepdims=True)
+            one_hot = (group_id == group.astype(jnp.int32)).astype(_F32).astype(bf16)
+            counts = jnp.dot(running, one_hot, preferred_element_type=_F32)    # (128, width)
+            lane = jnp.sum((counts <= rank).astype(_F32), axis=0, keepdims=True)
+            slot = jnp.where(group < rows, group * _GROUP + lane, -1.0).astype(jnp.int32)
+            out_ref[pl.ds(b, 1), pl.ds(c * width, width)] = slot
+        return carry
+
+    jax.lax.fori_loop(np.int32(0), np.int32(batch), one_session, np.int32(0))
+
+
+@functools.lru_cache(maxsize=None)
+def _count_tables(groups: int):
+    lane = np.arange(_GROUP)
+    upper = lane[:, None] <= lane[None, :]
+    tri = np.concatenate([upper, np.ones_like(upper), upper.T], axis=1)
+    group = np.arange(groups)
+    return tri.astype(np.float32), (group[None, :] < group[:, None]).astype(np.float32)
+
+
+def _select_pallas(score, k: int, *, interpret: bool):
+    batch, capacity = score.shape
+    rows = -(-capacity // (8 * _GROUP)) * 8           # groups that hold a real slot, whole sublane tiles
+    groups = -(-rows // LANE) * LANE                  # the products' contraction width: whole lane tiles
+    places = -(-k // _GROUP) * _GROUP
+    width = next(w for w in (512, 256, 128) if places % w == 0)
+    score = jnp.pad(score, ((0, 0), (0, groups * _GROUP - capacity)), constant_values=_NEG)
+    tri, low = (jnp.asarray(t, jnp.bfloat16) for t in _count_tables(groups))
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(_cut_kernel, k=np.int32(k), rows=rows, width=width),
+        in_specs=[whole, whole, whole],
+        out_specs=whole,
+        out_shape=jax.ShapeDtypeStruct((batch, places), jnp.int32),
+        scratch_shapes=[
+            pltpu.VMEM((batch, groups, _GROUP), jnp.int32),
+            pltpu.VMEM((batch, 8, _GROUP), jnp.int32),
+            pltpu.VMEM((batch, 8, _GROUP), _F32),
+        ],
+        cost_estimate=pl.CostEstimate(
+            flops=batch * (34 * 3 * rows * _GROUP + 2 * places * (groups + rows) * _GROUP),
+            bytes_accessed=4 * batch * (groups * _GROUP + places),
+            transcendentals=0,
+        ),
+        interpret=interpret,
+        name="ht_sparse_cut",
+    )(tri, low, score.reshape(batch, groups, _GROUP))
+    return out[:, :k]
+
+
+def _kernel_takes(batch: int, capacity: int, how: str) -> bool:
+    return how == "interpret" or (how == "tpu" and capacity % _GROUP == 0
+                                  and capacity <= _VMEM_GROUPS * _GROUP
+                                  and batch * capacity * 4 <= _VMEM_SCORES)
+
+
+def selection_form(batch: int, capacity: int, k: int) -> str:
+    """Which lowering :func:`sparse_select` takes at these shapes:
+    ``cut_kernel`` (the Pallas kernel ``ht_sparse_cut``), ``cut_jnp`` (the same
+    three parts as ``jax.numpy``), ``all`` (``k`` reaches the capacity: every
+    visible slot, nothing to select)."""
+    if k >= capacity:
+        return "all"
+    return "cut_kernel" if _kernel_takes(batch, capacity, _mode()) else "cut_jnp"
+
+
+def largest_slots(score, k: int):
+    """The slots of each row's ``k`` largest scores, exact, by *cut,
+    membership, compaction* and no sort: ``score`` ``(batch, capacity)``
+    float32 with ``-inf`` where a slot is not visible.  Returns int32 ``(batch,
+    min(k, capacity))`` in slot order, ``-1`` from the number of visible slots
+    on.  It is the set ``jax.lax.top_k`` returns: of equal scores the lowest
+    slots win, the two zeros are equal, ``-inf`` is never chosen.  NaN scores
+    are outside the contract.
+
+    1. The cut: the row's ``k``-th largest value by bisection on the
+       order-preserving integer image of the float bits, 32 counting passes,
+       and the count of scores strictly above it.
+    2. Membership: ``score > cut``, or ``score == cut`` and fewer than ``k -
+       above`` equal scores lie at lower slots.
+    3. Compaction, two levels over lane groups of 128 slots: the running count
+       of members inside each group and the groups' running totals; output
+       place ``j`` finds its group by comparing ``j`` with the totals, fetches
+       the group's 128 running counts by a one-hot product and finds its lane
+       by comparing them with its rank in the group.  Dense compares and small
+       products only: no sort, no scatter, no gather.
+
+    On a TPU all of it is one Pallas kernel, ``ht_sparse_cut``, which holds the
+    call's scores in VMEM; elsewhere, and for shapes the kernel does not take
+    (:func:`selection_form`), the same parts run as ``jax.numpy`` on scores
+    padded with ``-inf`` to whole groups."""
+    batch, capacity = score.shape
+    k = int(k)
+    form = selection_form(batch, capacity, k)
+    if form == "all":
+        slot = jnp.arange(capacity, dtype=jnp.int32)[None, :]
+        return jnp.where(score > _NEG, slot, -1)
+    score = score.astype(_F32)
+    if form == "cut_kernel":
+        return _select_pallas(score, k, interpret=(_mode() == "interpret"))
+    pad = (-capacity) % _GROUP
+    if pad:
+        score = jnp.pad(score, ((0, 0), (0, pad)), constant_values=_NEG)
+    return _select_jnp(score, k)
+
+
 def sparse_select(q_idx, w, k_idx_cache, kv_len, k: int):
     """The ``k`` visible slots of largest index score for each session's one
-    query position, exact: int32 ``(batch, min(k, capacity))``, in no
-    particular order, ``-1`` where fewer than ``k`` slots are visible."""
-    score = index_scores(q_idx, w, k_idx_cache, kv_len)
-    top, slot = jax.lax.top_k(score, min(int(k), score.shape[-1]))
-    return jnp.where(top > _NEG, slot, -1).astype(jnp.int32)
+    query position, exact: int32 ``(batch, min(k, capacity))``, in slot order,
+    ``-1`` where fewer than ``k`` slots are visible.  The scan is
+    :func:`index_scores`, the selection :func:`largest_slots`."""
+    return largest_slots(index_scores(q_idx, w, k_idx_cache, kv_len), k)
 
 
 def latent_decode_attention(q_lat, q_pe, latent_cache, rope_cache, chosen, scale: float):

@@ -16,6 +16,7 @@ and a threshold (prefill) and a ``top_k`` (decode) rightly differ there.
 import json
 import math
 import os
+import re
 import sys
 
 import jax
@@ -234,6 +235,107 @@ def test_kth_largest_is_the_sorted_rows(visible, k):
     assert np.array_equal(got, want)
 
 
+# ---- the selection by itself: cut, membership, compaction
+
+def selection_scores(kind, batch, capacity, k, seed):
+    """Scores ``(batch, capacity)`` with ``-inf`` from the visible count on,
+    and that count."""
+    rng = np.random.default_rng(seed)
+    x = (rng.standard_normal((batch, capacity)) * 3).astype(np.float32)
+    visible = capacity - 5                                      # off a group boundary
+    if kind == "quantised":                                     # ties straddle the cut
+        x = np.round(x / 2) * 2
+    elif kind == "zeros":
+        x[:] = 0.0
+    elif kind == "both_zeros":                                  # a relu'd sum: half the row is a zero
+        zero = rng.choice(np.float32([0.0, -0.0]), x.shape)
+        x = np.where(rng.random(x.shape) < k / capacity / 3, np.abs(x) + 1, zero)
+        assert (np.sort(x[:, :visible], axis=-1)[:, ::-1][:, min(k, visible) - 1] == 0).all()
+        assert np.signbit(x[x == 0]).any() and not np.signbit(x[x == 0]).all()
+    elif kind == "short":                                       # fewer than k visible
+        visible = k // 2 + 3
+    elif kind == "one_visible":
+        visible = 1
+    elif kind == "k_visible":
+        visible = min(k, capacity)
+    elif kind == "full":
+        visible = capacity
+    x[:, visible:] = -np.inf
+    return x, visible
+
+
+SELECTION_SHAPES = [(64, 12), (384, 128), (33024, 2048), (64, 64), (64, 100), (200, 7)]
+
+
+@pytest.mark.parametrize("lowering", ["jnp", "kernel"])
+@pytest.mark.parametrize("kind", ["random", "quantised", "zeros", "both_zeros", "short",
+                                  "one_visible", "k_visible", "full"])
+@pytest.mark.parametrize("capacity,k", SELECTION_SHAPES,
+                         ids=[f"{c}to{k}" for c, k in SELECTION_SHAPES])
+def test_the_selection_is_top_ks_set_in_slot_order(capacity, k, kind, lowering, monkeypatch):
+    """``largest_slots`` returns the set ``lax.top_k`` returns (of equal scores
+    the lowest slots), in slot order, ``-1`` from the visible count on; the
+    yardstick that calls the two zeros equal is numpy's stable sort."""
+    monkeypatch.setenv("HEAT_TPU_PALLAS", "interpret" if lowering == "kernel" else "off")
+    batch = 3
+    x, visible = selection_scores(kind, batch, capacity, k, seed=capacity + k)
+    form = la.selection_form(batch, capacity, k)
+    assert form == ("all" if k >= capacity else "cut_kernel" if lowering == "kernel" else "cut_jnp")
+    got = np.asarray(la.largest_slots(jnp.asarray(x), k))
+    picked = min(k, capacity)
+    assert got.shape == (batch, picked) and got.dtype == np.int32
+    count = min(picked, visible)
+    for row, slots in zip(x, got):
+        assert (slots[count:] == -1).all()
+        slots = slots[:count]
+        assert (np.diff(slots) > 0).all() and slots.min(initial=0) >= 0      # none twice
+        assert slots.max(initial=0) < visible
+        assert np.array_equal(slots, np.sort(np.argsort(-row, kind="stable")[:count]))
+    if kind != "both_zeros":      # the CPU's top_k puts -0.0 below 0.0
+        top, slot = jax.lax.top_k(jnp.asarray(x), picked)
+        theirs = np.where(np.asarray(top) > -np.inf, np.asarray(slot), -1)
+        assert np.array_equal(np.sort(theirs, axis=-1), np.sort(got, axis=-1))
+
+
+@pytest.mark.parametrize("capacity,k", [(64, 12), (384, 128), (33024, 2048)])
+def test_the_two_lowerings_of_the_selection_agree_slot_for_slot(capacity, k, monkeypatch):
+    x, _ = selection_scores("quantised", 4, capacity, k, seed=k)
+    x[1], _ = selection_scores("short", 1, capacity, k, seed=1)
+    x[2, : capacity // 2] = 0.0
+    got = {}
+    for how in ("off", "interpret"):
+        monkeypatch.setenv("HEAT_TPU_PALLAS", how)
+        got[how] = np.asarray(la.largest_slots(jnp.asarray(x), k))
+    assert np.array_equal(got["off"], got["interpret"])
+
+
+@pytest.mark.parametrize("how,batch,capacity,form", [
+    ("tpu", 16, 33024, "cut_kernel"), ("tpu", 16, 64, "cut_jnp"), ("tpu", 16, 33024 + 64, "cut_jnp"),
+    ("tpu", 16, 1 << 17, "cut_jnp"), ("tpu", 8, 1 << 17, "cut_kernel"),
+    ("tpu", 1, 1 << 20, "cut_jnp"), ("interpret", 16, 64, "cut_kernel"),
+    ("off", 16, 33024, "cut_jnp")])
+def test_the_kernel_takes_whole_groups_that_fit_its_memory(how, batch, capacity, form,
+                                                           monkeypatch):
+    monkeypatch.setenv("HEAT_TPU_PALLAS", how)
+    assert la.selection_form(batch, capacity, 2048 if capacity > 2048 else 12) == form
+    assert la.selection_form(batch, capacity, capacity) == "all"
+
+
+def test_sparse_select_scans_then_selects(monkeypatch):
+    """The scan's scores, then their largest: a decode step's selection equals
+    ``top_k`` of ``index_scores`` as a set, whatever the lowering."""
+    rng = np.random.default_rng(3)
+    batch, heads, width, capacity, k, kv_len = 2, 16, 16, 256, 24, 131
+    q = jnp.asarray(rng.standard_normal((batch, heads, width)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((batch, heads)), jnp.float32)
+    cache = jnp.asarray(rng.standard_normal((batch, capacity, width)), jnp.float32)
+    _, theirs = jax.lax.top_k(la.index_scores(q, w, cache, kv_len), k)
+    for how in ("off", "interpret"):
+        monkeypatch.setenv("HEAT_TPU_PALLAS", how)
+        got = np.asarray(la.sparse_select(q, w, cache, kv_len, k))
+        assert np.array_equal(got, np.sort(np.asarray(theirs), axis=-1))
+
+
 # ---- the router and the held experts
 
 def routed(tokens=40, experts=8, d=32, seed=0, bias_std=0.3):
@@ -426,17 +528,18 @@ def test_spans_counters_and_one_sync_a_decode(model):
                                          "lm.decode", "sync:lm.tokens"]
     decode = {e["name"]: e for e in begun}["lm.decode"]
     assert {k: decode[k] for k in ("batch", "context", "steps", "layers", "moe_layers", "selected",
-                                   "latent_bytes", "index_bytes", "experts_held", "expert_bytes")
+                                   "select", "latent_bytes", "index_bytes", "experts_held",
+                                   "expert_bytes")
             } == dict(batch=2, context=11, steps=2, layers=3, moe_layers=2, selected=12,
-                      latent_bytes=4 * 40, index_bytes=4 * 16, experts_held=2,
+                      select="cut_jnp", latent_bytes=4 * 40, index_bytes=4 * 16, experts_held=2,
                       expert_bytes=4 * 3 * 64 * 32)
     lm = {k: after["lm"][k] - before["lm"][k] for k in
           ("decode_steps", "prefill_tokens", "index_keys_scanned", "latent_rows_read",
-           "state_bytes_copied", "state_bytes_stepped")}
+           "selections_by_cut", "state_bytes_copied", "state_bytes_stepped")}
     seen = [12, 13, 14, 12, 13]
     assert lm == {"decode_steps": 5, "prefill_tokens": 22, "index_keys_scanned": 2 * 3 * sum(seen),
                   "latent_rows_read": 2 * 3 * sum(min(12, s) for s in seen),
-                  "state_bytes_copied": 0, "state_bytes_stepped": 0}
+                  "selections_by_cut": 3 * 5, "state_bytes_copied": 0, "state_bytes_stepped": 0}
     pairs = after["lm"]["expert_pairs"] - before["lm"]["expert_pairs"]
     hit = after["lm"]["experts_hit"] - before["lm"]["experts_hit"]
     assert 0 <= hit <= 5 * 2 * 2 and hit <= pairs <= 5 * 2 * 2 * 2
@@ -664,3 +767,29 @@ def test_the_caches_lie_rows_major_on_the_chip(v5e):
     assert memory.temp_size_in_bytes < held // 8
     for fmt in compiled.output_formats[:3]:
         assert tuple(fmt.layout.major_to_minor) == (0, 1, 2)
+
+
+def test_the_selection_compiled_for_the_chip_holds_no_sort(v5e, monkeypatch):
+    """``sparse_select`` at the cell's 16 x 33,024 -> 2,048 compiled for a v5e
+    is the scan and the kernel ``ht_sparse_cut``: no sort in the optimised
+    program, and no temporary larger than the scores themselves."""
+    monkeypatch.setenv("HEAT_TPU_PALLAS", "tpu")
+    batch, heads, width, capacity, k = 16, 64, 128, 33024, 2048
+    assert la.selection_form(batch, capacity, k) == "cut_kernel"
+
+    def select(q, w, cache, kv_len):
+        return la.sparse_select(q, w, cache, kv_len, k)
+
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(s, dtype, sharding=v5e)  # noqa: E731
+    with jax.enable_x64(False):
+        compiled = jax.jit(select).lower(
+            shape(batch, heads, width), shape(batch, heads, dtype=jnp.float32),
+            shape(batch, capacity, width), shape(dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert not re.search(r"\bsort\(|topk", text, re.I) and "ht_sparse_cut" in text
+    scores = batch * (-(-capacity // (128 * 128)) * 128 * 128) * 4     # padded to the kernel's groups
+    assert compiled.memory_analysis().temp_size_in_bytes <= scores
+    # the kernel reserves no more VMEM than any kernel has: with 64 MiB reserved, XLA could no
+    # longer keep a layer's rope cache (67 MB) in VMEM for the gather behind it (PERF.md, PR 35)
+    call = next(line for line in text.splitlines() if "ht_sparse_cut" in line and "custom-call(" in line)
+    assert max(map(int, re.findall(r'"size":"(\d+)"', call)), default=0) <= 16 << 20
