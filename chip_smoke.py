@@ -794,7 +794,45 @@ def st_deepseek():
     return {"layers": cfg.num_hidden_layers, "prefill_s": round(prefill_s, 3),
             "decode4_first_s": round(decode_s, 3), "logits_err_median": median,
             "logits_err_worst": float(max(errs)), "selection_missed": missed,
-            "cache_bytes": held}
+            "cache_bytes": held, "prefill_attention": prefill_forms(cfg, session.capacity)}
+
+
+def prefill_forms(cfg, capacity):
+    """One short prefill chunk's attention (the last 256 positions of the
+    session's capacity, all of the model's heads) through the kernel
+    ``ht_sparse_prefill_attn`` and through the ``jax.numpy`` form, compared:
+    tier-1 runs the kernel only through the interpreter."""
+    chunk, heads = 256 if not REHEARSAL else 32, cfg.num_attention_heads
+    widths = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank)
+    dtype = jnp.dtype(cfg.dtype)
+    keys = jax.random.split(jax.random.key(13), 8)
+    normal = lambda i, *shape: jax.random.normal(keys[i], shape, jnp.float32)  # noqa: E731
+    pos0 = capacity - chunk
+    scores = jnp.where(jnp.arange(capacity)[None, :] <= pos0 + jnp.arange(chunk)[:, None],
+                       jnp.round(normal(0, chunk, capacity) * 4) / 4, -jnp.inf)
+    args = (normal(1, chunk, heads, widths[0]), normal(2, chunk, heads, widths[1]),
+            normal(3, capacity, widths[3]).astype(dtype),
+            normal(4, capacity // latent_attention.ROPE_PACK,
+                   latent_attention.ROPE_PACK * widths[1]).astype(dtype),
+            scores, latent_attention.kth_largest(scores, cfg.index_topk), np.int32(pos0),
+            (normal(5, heads, widths[0], widths[3]) * widths[3] ** -0.5).astype(dtype),
+            (normal(6, heads, widths[3], widths[2]) * widths[3] ** -0.5).astype(dtype))
+    got, info = {}, {}
+    for how in (KERNEL_MODE, "off"):
+        os.environ["HEAT_TPU_PALLAS"] = how
+        try:
+            form = latent_attention.prefill_form(chunk, capacity, heads, widths, dtype.itemsize)
+            # a function of its own a form: the mode is read when the call is traced
+            attend = jax.jit(lambda *a: latent_attention.latent_prefill_attention(
+                *a, cfg.softmax_scale))
+            got[form], first, steady = first_and_steady(lambda: attend(*args))
+            info[form] = [round(first, 3), round(steady, 5)]
+        finally:
+            os.environ["HEAT_TPU_PALLAS"] = KERNEL_MODE
+    check(set(got) == {"flash_kernel", "blockwise_jnp"}, f"prefill forms {sorted(got)}")
+    info["max_err"] = close(got["flash_kernel"], got["blockwise_jnp"], 1e-3,
+                            "prefill attention kernel vs jax.numpy form")
+    return info
 
 
 def st_fence():

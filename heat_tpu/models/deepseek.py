@@ -13,9 +13,9 @@ untied head.
   numbers a layer in the cache (the normed low-rank projection ``c_kv`` and
   one rotary key shared by all heads), read by every head as key and value.
   A prefill chunk computes keys and values a head from those rows (the
-  published form); a decode step folds ``W_uk`` into the query and ``W_uv``
-  into the output and attends in the latent space
-  (:mod:`heat_tpu.ops.latent_attention`).
+  published form; on a TPU one Pallas kernel, ``ht_sparse_prefill_attn``); a
+  decode step folds ``W_uk`` into the query and ``W_uv`` into the output and
+  attends in the latent space (:mod:`heat_tpu.ops.latent_attention`).
 - **The selection.**  A second cache holds one index key a token and layer;
   ``index_n_heads`` small heads score every visible position and a query
   reads only the ``index_topk`` positions of largest score: an exact top-k at
@@ -54,9 +54,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import telemetry
-from ..ops.latent_attention import (KEY_BLOCK, ROPE_PACK, index_scores_chunk, kth_largest,
-                                    latent_decode_attention, latent_prefill_attention,
-                                    selection_form, sparse_select)
+from ..ops.latent_attention import (KEY_BLOCK, ROPE_PACK, index_scores_chunk, key_blocks,
+                                    kth_largest, latent_decode_attention,
+                                    latent_prefill_attention, prefill_form, selection_form,
+                                    sparse_select)
 from ..parallel.expert import held_experts_ffn
 from ._lm import (dot as _dot, embed as _embed, gated_mlp as _gated_mlp, greedy as _greedy,
                   init_tree, spec_size, yarn_frequencies)
@@ -513,11 +514,25 @@ class DeepSeek:
         return {"shared": tree_bytes(shared)}
 
     def serve_prefill(self, shared, state, ids, position: int):
+        """The prompt in programs of :data:`PREFILL_ROWS` token rows.  Also
+        returns the ``lm.prefill`` span's ``attend`` (the form of the chunks'
+        attention: ``flash_kernel``, ``blockwise_jnp``, or ``mixed`` where a
+        short last chunk differs) and ``prefill_blocks_by_kernel``, the layers x
+        key blocks the kernel folded for the sessions."""
         cfg, params = self.cfg, self.params
         batch, n = int(ids.shape[0]), int(ids.shape[1])
+        capacity = int(shared["latent"][0].shape[1])
         chunk = min(n, PREFILL_ROWS)
         group = max(1, min(batch, PREFILL_ROWS // chunk))
+        widths = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank)
+        forms, folded = set(), 0
         for start in range(0, n, chunk):
+            rows = min(chunk, n - start)
+            form = prefill_form(rows, capacity, cfg.num_attention_heads, widths,
+                                jnp.dtype(cfg.dtype).itemsize)
+            forms.add(form)
+            if form == "flash_kernel":
+                folded += batch * cfg.num_hidden_layers * key_blocks(position + start, rows, capacity)
             last = []
             for first in range(0, batch, group):
                 shared, x = _prefill_chunk(
@@ -525,7 +540,8 @@ class DeepSeek:
                     np.int32(first), np.int32(position + start))
                 last.append(x)
         token, logits = _prefill_finish(cfg, params, jnp.concatenate(last))
-        return shared, state, token, logits
+        told = {"attend": forms.pop() if len(forms) == 1 else "mixed"}
+        return shared, state, token, logits, (told, {"prefill_blocks_by_kernel": folded})
 
     def serve_notes(self, session: DecodeSession, steps: int):
         cfg = self.cfg

@@ -14,7 +14,10 @@ programs walks:
   context and that a snapshot never copies (later positions are simply
   overwritten), and the constant-size state that a snapshot copies;
 - ``serve_prefill(shared, state, ids, position) -> (shared, state, token,
-  logits)``: its jitted prefill programs over a prompt, chunk by chunk;
+  logits)``: its jitted prefill programs over a prompt, chunk by chunk; a
+  fifth element, where a model returns one, is ``(notes, counts)``: what the
+  ``lm.prefill`` span's end event carries and what the call adds to the
+  ``lm`` counters;
 - ``serve_decode(shared, state, token, position, steps) -> (shared, state,
   token, chosen, logits)``: its jitted decode program; a sixth element, where
   a model returns one, is a dictionary of device numbers for the ``lm``
@@ -58,14 +61,16 @@ __all__ = ["DecodeSession", "Snapshot"]
 # shapes), selections_by_cut the layer-steps whose selection was a cut and a compaction and not
 # every visible slot, expert_pairs and experts_hit the token-expert pairs computed on the experts
 # held here and the held experts that received a token, summed over a call's steps and layers
-# (counted on the device); cache_bytes is what the newest session allocated, by kind
+# (counted on the device); prefill_blocks_by_kernel the layers x key blocks of a sparse model's
+# prefills that the attention kernel folded (from shapes); cache_bytes is what the newest session
+# allocated, by kind
 _LM = telemetry.register_group(
     "lm",
     {"decode_steps": 0, "prefill_tokens": 0, "prefill_s": 0.0,
      "cache_keys_visible": 0, "cache_keys_fetched": 0,
      "state_bytes_stepped": 0, "state_bytes_copied": 0,
      "index_keys_scanned": 0, "latent_rows_read": 0, "selections_by_cut": 0,
-     "expert_pairs": 0, "experts_hit": 0,
+     "expert_pairs": 0, "experts_hit": 0, "prefill_blocks_by_kernel": 0,
      "cache_bytes": {"shared": 0, "window": 0, "state": 0}},
 )
 
@@ -149,9 +154,12 @@ class DecodeSession:
         if self.position + n > self.capacity:
             raise ValueError(f"{self.position} + {n} positions pass the session's {self.capacity}")
         started = time.perf_counter()
-        with telemetry.span("lm.prefill", tokens=self.batch * n, chunk=self.model.prefill_chunk):
-            self._shared, self._state, self._token, logits = self.model.serve_prefill(
+        with telemetry.span("lm.prefill", tokens=self.batch * n,
+                            chunk=self.model.prefill_chunk) as span:
+            self._shared, self._state, self._token, logits, *told = self.model.serve_prefill(
                 self._shared, self._state, ids, self.position)
+            notes, counts = told[0] if told else ({}, {})
+            span.note(**notes)
             # the span ends when the first token is ready, as a serving loop
             # waits for it before it streams: the session's time to first token
             with telemetry.sync("lm.prefill"):
@@ -160,6 +168,8 @@ class DecodeSession:
         if telemetry.ledger_enabled():
             _LM["prefill_s"] += time.perf_counter() - started
         _LM["prefill_tokens"] += self.batch * n
+        for name, count in counts.items():
+            _LM[name] += count
         return self._wrap(logits)
 
     def decode(self, steps: int):

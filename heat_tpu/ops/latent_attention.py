@@ -30,11 +30,13 @@ fills the chunk's scores against the visible prefix block by block,
 ``score >= cut``), and :func:`latent_prefill_attention` is the published
 per-head form, blockwise with a running softmax: keys and values of a block of
 positions are computed from its latent rows when the block is read, so no
-``(chunk, context)`` array of all heads is ever alive.
+``(chunk, context)`` array of all heads is ever alive.  On a TPU that form is
+one Pallas kernel, ``ht_sparse_prefill_attn``, which keeps a block's logits,
+mask and weights in VMEM (:func:`prefill_form` says where it is taken).
 
 Products take their operands in the caches' type and accumulate in float32.
-The scan, the gather and the prefill path are ``jax.numpy`` (XLA fuses the scan
-into one pass over the index keys at 91% of its bytes' floor).  The selection
+The scan, the gather and the prefill's scores and cut are ``jax.numpy`` (XLA
+fuses the scan into one pass over the index keys at 91% of its bytes' floor).  The selection
 behind the scan is *cut, membership, compaction* and no sort
 (:func:`largest_slots`): on a TPU one Pallas kernel, ``ht_sparse_cut``, entered
 by :func:`~heat_tpu.ops._pallas_common.mode` alone, as
@@ -59,9 +61,9 @@ from jax.experimental.pallas import tpu as pltpu
 from ._pallas_common import LANE
 from ._pallas_common import mode as _mode
 
-__all__ = ["KEY_BLOCK", "ROPE_PACK", "index_scores", "index_scores_chunk", "kth_largest",
-           "largest_slots", "latent_decode_attention", "latent_prefill_attention", "rope_rows",
-           "selection_form", "sparse_select"]
+__all__ = ["KEY_BLOCK", "ROPE_PACK", "index_scores", "index_scores_chunk", "key_blocks",
+           "kth_largest", "largest_slots", "latent_decode_attention", "latent_prefill_attention",
+           "prefill_form", "rope_rows", "selection_form", "sparse_select"]
 
 _F32 = jnp.float32
 _NEG = -jnp.inf
@@ -393,8 +395,8 @@ def index_scores_chunk(q_idx, w, k_idx_cache, pos0):
         score = jnp.where(slot[None, :] <= position[:, None], score, _NEG)
         return jax.lax.dynamic_update_slice_in_dim(scores, score, j * block, axis=1)
 
-    blocks = (pos0 + chunk + block - 1) // block
-    return jax.lax.fori_loop(0, blocks, one_block, jnp.full((chunk, capacity), _NEG, _F32))
+    return jax.lax.fori_loop(0, key_blocks(pos0, chunk, capacity), one_block,
+                             jnp.full((chunk, capacity), _NEG, _F32))
 
 
 def _ordered_bits(x):
@@ -420,6 +422,211 @@ def kth_largest(scores, k: int):
     return jax.lax.bitcast_convert_type(back, _F32)
 
 
+def key_blocks(pos0, chunk: int, capacity: int):
+    """Blocks of :data:`KEY_BLOCK` slots a chunk of ``chunk`` positions from
+    ``pos0`` reads: those up to the one that holds its last position (plain or
+    traced ``pos0``)."""
+    block = min(KEY_BLOCK, capacity)
+    return (pos0 + chunk + block - 1) // block
+
+
+# ---- the prefill attention as one kernel: a key block's logits, mask and weights stay in VMEM
+
+# query rows a fold takes at once, and heads a grid step holds (all of the chunk's queries are
+# resident, so that a head's keys and values are computed from the latent rows once a block)
+_QUERY_TILE = 256
+_HEADS_A_STEP = 4
+# the VMEM the kernel asks for: it runs in the prefill program alone, never in the decode
+# program, where XLA keeps the layers' rope caches in VMEM (PERF.md section 6)
+_VMEM_PREFILL = 64 << 20
+_NEG_F32, _ZERO_F32 = np.float32(-np.inf), np.float32(0)
+
+
+def _prefill_tiles(chunk: int, capacity: int, heads: int, widths, itemsize: int):
+    """``(key block, query rows a fold, heads a grid step, VMEM bytes)`` of the
+    kernel at these shapes; ``widths`` is ``(nope, rope, v, rank)``."""
+    nope, rope, v, rank = widths
+    block = min(KEY_BLOCK, capacity)
+    rows = next((r for r in (_QUERY_TILE, 128, 64, 32, 16) if chunk % r == 0), 0)
+    group = next(g for g in (_HEADS_A_STEP, 2, 1) if heads % g == 0)
+    depth = nope + -(-ROPE_PACK * rope // LANE) * LANE          # a query's two parts, whole lane tiles
+    fetched = (chunk * group * depth * itemsize                  # queries
+               + chunk * (LANE + block) * 4                      # cut, scores
+               + block * (rank + rope) * itemsize                # latent, rope rows
+               + group * rank * (nope + v) * itemsize)           # W_uk, W_uv
+    held = (2 * (fetched + chunk * group * v * 4)               # two buffers each
+            + 2 * group * chunk * LANE * 4                       # running max, total
+            + group * block * (depth + v) * itemsize             # keys (turned), values
+            + 8 * rows * block * 4)                              # a fold's values
+    return block, rows, group, held
+
+
+def prefill_form(chunk: int, capacity: int, heads: int, widths, itemsize: int) -> str:
+    """Which form :func:`latent_prefill_attention` takes at these shapes
+    (``widths``: ``(nope, rope, v, rank)``): ``flash_kernel`` (the Pallas
+    kernel ``ht_sparse_prefill_attn``) where the Pallas mode is ``tpu`` or
+    ``interpret``, the chunk is whole query tiles and, on a TPU, the widths
+    are whole lane tiles and the tiles fit the kernel's VMEM; else
+    ``blockwise_jnp`` (the same blocks and rounding in ``jax.numpy``)."""
+    how = _mode()
+    block, rows, _, held = _prefill_tiles(chunk, capacity, heads, widths, itemsize)
+    whole = rows > 0 and capacity % block == 0 and block % ROPE_PACK == 0
+    nope, rope, v, rank = widths
+    if how == "interpret" and whole:
+        return "flash_kernel"
+    if (how == "tpu" and whole and held <= _VMEM_PREFILL
+            and all(n % LANE == 0 for n in (nope, v, rank, block, ROPE_PACK * rope))):
+        return "flash_kernel"
+    return "blockwise_jnp"
+
+
+def _prefill_kernel(plan_ref, q_ref, cut_ref, score_ref, ckv_ref, rope_ref, wuk_ref, wuv_ref,
+                    out_ref, top_ref, total_ref, kt_ref, v_ref, *, rows, rope):
+    """One head group's fold of one key block: grid ``(head groups, key
+    blocks)``, the blocks in ascending order, none past ``plan_ref[0]``
+    (``plan_ref[1]`` is the chunk's first position).
+    ``q_ref`` ``(group, chunk, depth)``: a query's no-position part, then its
+    rotary part once for each position a rope row holds; ``kt_ref`` ``(group,
+    depth, block)`` the keys turned the same way, a slot's rotary key in its
+    own lanes and zeros in its neighbours'.  ``out_ref`` ``(chunk, group *
+    v)`` holds the running value sums and, after the last block, the
+    attention; ``top_ref``, ``total_ref`` ``(group, chunk, 1)`` the running
+    max and total."""
+    j = pl.program_id(1)
+    group, chunk, _ = top_ref.shape
+    block = ckv_ref.shape[0]
+    nope, v = wuk_ref.shape[0] // group, v_ref.shape[1] // group
+    width = kt_ref.shape[1] - nope
+    dtype = ckv_ref.dtype
+    tiles = np.int32(chunk // rows)
+
+    def rows_of(t):
+        return pl.ds(pl.multiple_of(t * np.int32(rows), rows), rows)
+
+    @pl.when(j == 0)
+    def _():
+        top_ref[...] = jnp.full(top_ref.shape, _NEG_F32, _F32)
+        total_ref[...] = jnp.zeros(total_ref.shape, _F32)
+        out_ref[...] = jnp.zeros(out_ref.shape, _F32)
+
+    @pl.when(j < plan_ref[0])
+    def _():
+        # the group's keys and values from the block's latent rows, rounded as the cache is
+        c_kv = ckv_ref[...]
+        k_nope = jax.lax.dot_general(wuk_ref[...], c_kv, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=_F32).astype(dtype)
+        v_ref[...] = jnp.dot(c_kv, wuv_ref[...], preferred_element_type=_F32).astype(dtype)
+        # each slot's row of the rope block (a product with a 0/1 table: exact), its own lanes
+        # kept and its neighbour's zeroed
+        shape = (block, block // ROPE_PACK)
+        slot = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (block, width), 1)
+        k_pe = jnp.zeros((block, width), _F32)
+        for i in range(ROPE_PACK):
+            spread = jnp.dot((slot == row * ROPE_PACK + i).astype(dtype), rope_ref[...],
+                             preferred_element_type=_F32)
+            k_pe = k_pe + jnp.where((lane >= i * rope) & (lane < (i + 1) * rope), spread, _ZERO_F32)
+        k_pe = k_pe.T.astype(dtype)
+        for h in range(group):
+            kt_ref[h, :nope, :] = k_nope[h * nope:(h + 1) * nope]
+            kt_ref[h, nope:, :] = k_pe
+
+        def one_tile(t, carry):
+            at = rows_of(t)
+            score = score_ref[at, :]
+            read = (score >= cut_ref[at, :]) & (score > _NEG_F32)
+            for h in range(group):
+                logit = jnp.dot(q_ref[h, at, :], kt_ref[h], preferred_element_type=_F32)
+                logit = jnp.where(read, logit, _NEG_F32)
+                top = top_ref[h, at, :]
+                new_top = jnp.maximum(top, jnp.max(logit, axis=1, keepdims=True))
+                safe = jnp.where(new_top > _NEG_F32, new_top, _ZERO_F32)
+                weight = jnp.exp(logit - safe)
+                fade = jnp.exp(top - safe)
+                total_ref[h, at, :] = (total_ref[h, at, :] * fade
+                                       + jnp.sum(weight, axis=1, keepdims=True))
+                lanes = slice(h * v, (h + 1) * v)
+                out_ref[at, lanes] = out_ref[at, lanes] * fade + jnp.dot(
+                    weight.astype(dtype), v_ref[:, lanes], preferred_element_type=_F32)
+                top_ref[h, at, :] = new_top
+            return carry
+
+        # query tiles wholly before the block's first slot see none of it: the fold would add
+        # exactly nothing to them
+        first = jnp.maximum(j * np.int32(block) - plan_ref[1], 0) // np.int32(rows)
+        jax.lax.fori_loop(first, tiles, one_tile, np.int32(0))
+
+    @pl.when(j == plan_ref[0] - 1)
+    def _():
+        def one_tile(t, carry):
+            at = rows_of(t)
+            for h in range(group):
+                lanes = slice(h * v, (h + 1) * v)
+                out_ref[at, lanes] = out_ref[at, lanes] / total_ref[h, at, :]
+            return carry
+
+        jax.lax.fori_loop(np.int32(0), tiles, one_tile, np.int32(0))
+
+
+def _prefill_pallas(q_nope, q_pe, latent_cache, rope_cache, scores, cut, pos0, w_uk, w_uv, *,
+                    interpret: bool):
+    chunk, heads, nope = q_nope.shape
+    capacity, rank = latent_cache.shape
+    rope, v = q_pe.shape[-1], w_uv.shape[-1]
+    dtype = latent_cache.dtype
+    block, rows, group, _ = _prefill_tiles(chunk, capacity, heads, (nope, rope, v, rank),
+                                           dtype.itemsize)
+    width = ROPE_PACK * rope
+    q = jnp.moveaxis(jnp.concatenate([q_nope, jnp.tile(q_pe, (1, 1, ROPE_PACK))], axis=-1), 1, 0)
+    pos0 = jnp.asarray(pos0, jnp.int32)
+    plan = jnp.stack([key_blocks(pos0, chunk, capacity), pos0])
+
+    def key(g, j, n):
+        """the block a step holds: past the chunk's last block the pipeline keeps its last"""
+        return jnp.minimum(j, n[0] - 1)
+
+    depth, passes = nope + width, heads // group
+    return pl.pallas_call(
+        functools.partial(_prefill_kernel, rows=rows, rope=rope),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(passes, capacity // block),
+            in_specs=[
+                pl.BlockSpec((group, chunk, depth), lambda g, j, n: (g, 0, 0)),
+                pl.BlockSpec((chunk, 1), lambda g, j, n: (0, 0)),
+                pl.BlockSpec((chunk, block), lambda g, j, n: (0, key(g, j, n))),
+                pl.BlockSpec((block, rank), lambda g, j, n: (key(g, j, n), 0)),
+                pl.BlockSpec((block // ROPE_PACK, ROPE_PACK * rope),
+                             lambda g, j, n: (key(g, j, n), 0)),
+                pl.BlockSpec((group * nope, rank), lambda g, j, n: (g, 0)),
+                pl.BlockSpec((rank, group * v), lambda g, j, n: (0, g)),
+            ],
+            out_specs=pl.BlockSpec((chunk, group * v), lambda g, j, n: (0, g)),
+            scratch_shapes=[
+                pltpu.VMEM((group, chunk, 1), _F32),
+                pltpu.VMEM((group, chunk, 1), _F32),
+                pltpu.VMEM((group, depth, block), dtype),
+                pltpu.VMEM((block, group * v), dtype),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((chunk, heads * v), _F32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"),
+                                             vmem_limit_bytes=_VMEM_PREFILL),
+        # the capacity, not the blocks a chunk reads (pos0 is traced): the bound the scheduler
+        # plans with
+        cost_estimate=pl.CostEstimate(
+            flops=2 * heads * capacity * (chunk * (depth + v) + rank * (nope + v)),
+            transcendentals=heads * chunk * capacity,
+            bytes_accessed=(passes * (4 * chunk * capacity + capacity * (rank + rope) * dtype.itemsize)
+                            + (chunk * depth + rank * (nope + v)) * heads * dtype.itemsize
+                            + 4 * chunk * heads * v)),
+        interpret=interpret,
+        name="ht_sparse_prefill_attn",
+    )(plan, q, cut[:, None], scores, latent_cache, rope_cache, w_uk.reshape(heads * nope, rank),
+      jnp.moveaxis(w_uv, 0, 1).reshape(rank, heads * v)).reshape(chunk, heads, v)
+
+
 def latent_prefill_attention(q_nope, q_pe, latent_cache, rope_cache, scores, cut, pos0,
                              w_uk, w_uv, scale: float):
     """The per-head attention of a chunk of one session's queries over the
@@ -427,13 +634,29 @@ def latent_prefill_attention(q_nope, q_pe, latent_cache, rope_cache, scores, cut
     ``(chunk, heads, rope)``, ``latent_cache`` ``(capacity, rank)``, ``rope_cache``
     ``(capacity / ROPE_PACK, ROPE_PACK * rope)``, ``scores`` ``(chunk, capacity)`` with ``-inf`` where a slot is not visible,
     ``cut`` ``(chunk,)``, ``w_uk`` ``(heads, nope, rank)``, ``w_uv`` ``(heads,
-    rank, v)``.  Returns float32 ``(chunk, heads, v)``."""
-    chunk, heads = q_nope.shape[:2]
-    capacity, rope = latent_cache.shape[0], q_pe.shape[-1]
+    rank, v)``.  Returns float32 ``(chunk, heads, v)``.
+
+    Blockwise with a running softmax, the blocks up to the chunk's last
+    position in ascending order: a block's keys and values computed from its
+    latent rows and rounded to the cache's type, float32 logits of the queries
+    (the scale folded in, rounded likewise) masked by the selection, running
+    max, fade and total in float32 (a row with nothing selected in a block adds
+    exactly 0), the weights rounded to the cache's type for the value product.
+    On a TPU it is one Pallas kernel, ``ht_sparse_prefill_attn``, that keeps
+    the logits in VMEM; the same steps in ``jax.numpy`` elsewhere
+    (:func:`prefill_form`)."""
+    chunk, heads, nope = q_nope.shape
+    capacity, rank = latent_cache.shape
+    rope, dtype = q_pe.shape[-1], latent_cache.dtype
+    # the scale folded into the queries, each part rounded as the two side by side would be
+    q_nope, q_pe = ((q.astype(_F32) * scale).astype(dtype) for q in (q_nope, q_pe))
+    how = prefill_form(chunk, capacity, heads, (nope, rope, w_uv.shape[-1], rank), dtype.itemsize)
+    if how == "flash_kernel":
+        return _prefill_pallas(q_nope, q_pe, latent_cache, rope_cache, scores, cut, pos0, w_uk,
+                               w_uv, interpret=(_mode() == "interpret"))
     block = min(KEY_BLOCK, capacity)
-    dtype = latent_cache.dtype
-    # one product a block: the two parts of a query side by side, the scale folded in
-    q = (jnp.concatenate([q_nope, q_pe], axis=-1).astype(_F32) * scale).astype(dtype)
+    # one product a block: the two parts of a query side by side
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
 
     def one_block(j, carry):
         top, total, acc = carry
@@ -458,6 +681,5 @@ def latent_prefill_attention(q_nope, q_pe, latent_cache, rope_cache, scores, cut
 
     start = (jnp.full((heads, chunk), _NEG, _F32), jnp.zeros((heads, chunk), _F32),
              jnp.zeros((heads, chunk, w_uv.shape[-1]), _F32))
-    blocks = (pos0 + chunk + block - 1) // block
-    _, total, acc = jax.lax.fori_loop(0, blocks, one_block, start)
+    _, total, acc = jax.lax.fori_loop(0, key_blocks(pos0, chunk, capacity), one_block, start)
     return jnp.moveaxis(acc / total[..., None], 0, 1)

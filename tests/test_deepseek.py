@@ -105,6 +105,21 @@ def rows(monkeypatch):
     return set_rows
 
 
+@pytest.fixture
+def pallas(monkeypatch):
+    """Sets the Pallas mode for a test; the serving programs traced under
+    another mode are dropped before the test and after it."""
+    programs = (deepseek._prefill_chunk, deepseek._decode)
+
+    def set_mode(how):
+        monkeypatch.setenv("HEAT_TPU_PALLAS", how)
+        for program in programs:
+            program.clear_cache()
+    yield set_mode
+    for program in programs:
+        program.clear_cache()
+
+
 def served(mdl, batch, prompt, steps, seed=0):
     """A session that prefilled ``prompt`` tokens and decoded ``steps``; and,
     for every sequence, the reference's full forward pass over the prompt plus
@@ -220,6 +235,65 @@ def test_prefill_chunk_reads_the_references_selection(pos0, chunk, topk, monkeyp
                                     cfg.softmax_scale)
     got = jnp.dot(o.reshape(chunk, -1), params["attn"]["w_o"])
     assert rel_err(got, out[pos0:]) < F32_TOL
+
+
+def prefill_inputs(dtype, pos0, chunk, heads, kind, capacity=128, seed=0):
+    """Random queries, caches and weights of one session at toy widths (nope 16,
+    rope 8, v 16, rank 32), scores ``-inf`` past each query's position, and the
+    cut at the 24th largest (``ties``: scores on a grid of halves, so that ties
+    straddle the cut; ``short``: a cut at the capacity, more than a row sees)."""
+    rng = np.random.default_rng(seed + pos0 + chunk)
+    dt = jnp.dtype(dtype)
+    normal = lambda *shape: rng.standard_normal(shape).astype(np.float32)  # noqa: E731
+    scores = normal(chunk, capacity)
+    if kind == "ties":
+        scores = np.round(scores * 2) / 2
+    scores[np.arange(capacity)[None] > pos0 + np.arange(chunk)[:, None]] = -np.inf
+    scores = jnp.asarray(scores)
+    cut = la.kth_largest(scores, capacity if kind == "short" else 24)
+    if kind == "short":                   # every row that sees fewer than all slots reads them all
+        assert bool(jnp.all((cut == -jnp.inf) | (pos0 + jnp.arange(chunk) == capacity - 1)))
+    return (jnp.asarray(normal(chunk, heads, 16)), jnp.asarray(normal(chunk, heads, 8)),
+            jnp.asarray(normal(capacity, 32), dt),
+            jnp.asarray(normal(capacity // la.ROPE_PACK, la.ROPE_PACK * 8), dt), scores, cut,
+            np.int32(pos0), jnp.asarray(normal(heads, 16, 32) / 6, dt),
+            jnp.asarray(normal(heads, 32, 16) / 6, dt))
+
+
+@pytest.mark.parametrize("kind", ["random", "ties", "short"])
+@pytest.mark.parametrize("pos0,chunk,heads", [(0, 16, 4), (0, 48, 6), (32, 32, 4), (96, 32, 2)],
+                         ids=["first_one_block", "first_three_blocks", "mid_prefix", "last_chunk"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_prefill_kernel_is_the_blockwise_form(dtype, pos0, chunk, heads, kind, monkeypatch):
+    """The kernel ``ht_sparse_prefill_attn`` (through the interpreter) against
+    the ``jax.numpy`` form on the same inputs, blocks of 16 slots in a capacity
+    of 128: the same blocks in the same order and the same rounding points, so
+    the two differ by float32 reassociation alone (and, in bfloat16, by the
+    rounding of a weight that reassociation moved across a bfloat16 step)."""
+    monkeypatch.setattr(la, "KEY_BLOCK", 16)
+    args = prefill_inputs(dtype, pos0, chunk, heads, kind)
+    got = {}
+    for how, form in (("off", "blockwise_jnp"), ("interpret", "flash_kernel")):
+        monkeypatch.setenv("HEAT_TPU_PALLAS", how)
+        assert la.prefill_form(chunk, 128, heads, (16, 8, 16, 32), jnp.dtype(dtype).itemsize) == form
+        got[how] = la.latent_prefill_attention(*args, 0.3)
+    assert got["interpret"].shape == (chunk, heads, 16) and got["interpret"].dtype == jnp.float32
+    assert rel_err(got["interpret"], got["off"]) < (F32_TOL if dtype == "float32" else 4e-3)
+
+
+@pytest.mark.parametrize("how,chunk,heads,widths,form", [
+    ("tpu", 2048, 128, (128, 64, 128, 512), "flash_kernel"),       # the cell's chunk
+    ("tpu", 256, 128, (128, 64, 128, 512), "flash_kernel"),
+    ("tpu", 2040, 128, (128, 64, 128, 512), "blockwise_jnp"),      # no whole query tiles
+    ("tpu", 8, 128, (128, 64, 128, 512), "blockwise_jnp"),         # under a tile of 16 rows
+    ("tpu", 16384, 128, (128, 64, 128, 512), "blockwise_jnp"),     # tiles past the kernel's VMEM
+    ("tpu", 2048, 128, (16, 8, 16, 32), "blockwise_jnp"),          # widths no whole lane tiles
+    ("interpret", 32, 4, (16, 8, 16, 32), "flash_kernel"),
+    ("interpret", 40, 4, (16, 8, 16, 32), "blockwise_jnp"),
+    ("off", 2048, 128, (128, 64, 128, 512), "blockwise_jnp")])
+def test_the_prefill_form_follows_the_shapes(how, chunk, heads, widths, form, monkeypatch):
+    monkeypatch.setenv("HEAT_TPU_PALLAS", how)
+    assert la.prefill_form(chunk, 33024, heads, widths, 2) == form
 
 
 @pytest.mark.parametrize("k", [1, 7, 100, 256, 300])
@@ -434,12 +508,16 @@ def test_the_two_lowerings_agree_and_drop_nothing(tokens, tile, routing, monkeyp
 
 # ---- the session
 
+@pytest.mark.parametrize("how", ["off", "interpret"], ids=["blockwise_jnp", "flash_kernel"])
 @pytest.mark.parametrize("prompt,steps,prefill_rows", [(40, 5, 2048), (40, 3, 16), (33, 4, 20),
-                                                       (9, 6, 2048)])
-def test_prefill_then_decode_equals_the_full_forward_pass(model, rows, prompt, steps,
+                                                       (9, 6, 2048), (32, 4, 2048)])
+def test_prefill_then_decode_equals_the_full_forward_pass(model, rows, pallas, how, prompt, steps,
                                                           prefill_rows):
-    """Logits of every decoded position, and every position of the caches."""
+    """Logits of every decoded position, and every position of the caches;
+    under ``interpret`` the chunks of whole query tiles (16 and 32 positions)
+    go through the prefill kernel."""
     rows(prefill_rows)
+    pallas(how)
     session, first, logits, wanted = served(model, 2, prompt, steps, seed=prompt)
     n = prompt + steps
     for b, want in enumerate(wanted):
@@ -457,8 +535,10 @@ def test_prefill_then_decode_equals_the_full_forward_pass(model, rows, prompt, s
             assert np.array_equal(read, np.asarray(want["selected"][layer]))
 
 
-def test_a_prompt_in_two_calls_is_the_prompt_in_one(model, rows):
+@pytest.mark.parametrize("how", ["off", "interpret"], ids=["blockwise_jnp", "flash_kernel"])
+def test_a_prompt_in_two_calls_is_the_prompt_in_one(model, rows, pallas, how):
     rows(16)
+    pallas(how)
     tokens = prompts(model.cfg, 2, 31, seed=4)
     one = model.session(2, 40)
     whole = one.prefill(ht.array(tokens))
@@ -547,6 +627,28 @@ def test_spans_counters_and_one_sync_a_decode(model):
     assert after["sync"]["count"] - before["sync"]["count"] == 3  # one a prefill, one a decode
     assert session.tokens.shape == (2, 2) and session.position == 13
     assert model.last_selection.shape == (3, 2, 12)
+
+
+@pytest.mark.parametrize("how,prompt,attend,folded", [
+    ("off", 32, "blockwise_jnp", 0), ("interpret", 32, "flash_kernel", 2 * 3 * (1 + 2)),
+    ("interpret", 40, "mixed", 2 * 3 * (1 + 2))])
+def test_the_prefill_span_says_which_form_attended(model, rows, pallas, monkeypatch, how, prompt,
+                                                   attend, folded):
+    """``lm.prefill``'s end event carries ``attend``, and ``lm`` counts the
+    layers x key blocks the kernel folded: chunks of 16 positions over blocks
+    of 16 slots read 1, 2, 3 blocks; a last chunk of 8 rows is no whole query
+    tile and goes to ``jax.numpy``."""
+    rows(16)
+    pallas(how)
+    monkeypatch.setattr(la, "KEY_BLOCK", 16)
+    session = model.session(2, 64)
+    before = telemetry.snapshot()["lm"]["prefill_blocks_by_kernel"]
+    with telemetry.telemetry_level("events"):
+        telemetry.clear_events()
+        session.prefill(ht.array(prompts(model.cfg, 2, prompt)))
+        ended = [e for e in telemetry.events("span_end") if e["name"] == "lm.prefill"]
+    assert [e["attend"] for e in ended] == [attend]
+    assert telemetry.snapshot()["lm"]["prefill_blocks_by_kernel"] - before == folded
 
 
 def test_counted_pairs_are_the_references(model):
@@ -768,6 +870,46 @@ def test_the_caches_lie_rows_major_on_the_chip(v5e):
     assert memory.temp_size_in_bytes < held // 8
     for fmt in compiled.output_formats[:3]:
         assert tuple(fmt.layout.major_to_minor) == (0, 1, 2)
+
+
+def test_the_prefill_attention_compiled_for_the_chip_is_the_kernel(v5e, monkeypatch):
+    """``latent_prefill_attention`` at the cell's shapes (2,048 queries, 128
+    heads, a capacity of 33,024) compiled for a v5e is the kernel
+    ``ht_sparse_prefill_attn``, with ``pos0`` an operand (one program for every
+    chunk), and holds less than one key block's float32 logits of all heads
+    (the ``jax.numpy`` form writes three such arrays a block); the decode step
+    holds no such kernel."""
+    monkeypatch.setenv("HEAT_TPU_PALLAS", "tpu")
+    chunk, heads, capacity = 2048, 128, 33024
+    assert la.prefill_form(chunk, capacity, heads, (128, 64, 128, 512), 2) == "flash_kernel"
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(s, dtype, sharding=v5e)  # noqa: E731
+
+    def attend(q_nope, q_pe, latent, rope, scores, cut, pos0, w_uk, w_uv):
+        return la.latent_prefill_attention(q_nope, q_pe, latent, rope, scores, cut, pos0, w_uk,
+                                           w_uv, 0.135)
+
+    with jax.enable_x64(False):
+        compiled = jax.jit(attend).lower(
+            shape(chunk, heads, 128), shape(chunk, heads, 64), shape(capacity, 512),
+            shape(capacity // 2, 128), shape(chunk, capacity, dtype=jnp.float32),
+            shape(chunk, dtype=jnp.float32), shape(dtype=jnp.int32), shape(heads, 128, 512),
+            shape(heads, 512, 128)).compile()
+    text = compiled.as_text()
+    assert "ht_sparse_prefill_attn" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < heads * chunk * la.KEY_BLOCK * 4
+
+    # a decode step at the published widths, a short cache: the selection's kernel, not this one
+    cfg = deepseek.DeepSeekConfig.from_dict(PUBLISHED, **CUT)
+    params = jax.eval_shape(lambda: deepseek.init_params(cfg, jax.random.key(0)))["layers"][1]
+    params = jax.tree.map(lambda x: shape(*x.shape, dtype=x.dtype), params)
+    batch, capacity = 16, 4096
+    caches = (shape(batch, capacity, 512), shape(batch, capacity // 2, 128),
+              shape(batch, capacity, 128))
+    with jax.enable_x64(False):
+        step = jax.jit(lambda p, x, pos, c: deepseek._attention_step(cfg, p, x, pos, c)).lower(
+            params, shape(batch, cfg.hidden_size, dtype=jnp.float32), shape(dtype=jnp.int32),
+            caches).compile().as_text()
+    assert "ht_sparse_cut" in step and "ht_sparse_prefill_attn" not in step
 
 
 def test_the_selection_compiled_for_the_chip_holds_no_sort(v5e, monkeypatch):
