@@ -89,7 +89,7 @@ from heat_tpu import native, serving  # noqa: E402
 from heat_tpu.core import autotune, fusion, telemetry, wire  # noqa: E402
 from heat_tpu.core.dndarray import DNDarray  # noqa: E402
 from heat_tpu.ops import (_pallas_common, attention, lasso_sweep, latent_attention,  # noqa: E402
-                          lloyd_pass, qr_panel)
+                          latent_stream, lloyd_pass, qr_panel)
 from heat_tpu.ops import cdist as cdist_kernel  # noqa: E402
 from heat_tpu.parallel import overlap, transport  # noqa: E402
 from heat_tpu.utils import compile_cache  # noqa: E402
@@ -835,6 +835,80 @@ def prefill_forms(cfg, capacity):
     return info
 
 
+def st_glm_drafter():
+    """GLM-4.7-Flash as the benchmark's configuration cuts it (one dense and
+    four expert layers of 64 experts and the MTP module at the published
+    widths, the whole vocabulary: 7.8 GB of the cell's seeded weights, whose
+    drafts mostly stand), on one device: prefill of two sessions of 4,096 and
+    3,001 tokens (positions of their own), a save, four speculative steps, a
+    rewind and the same four steps again, against the plain reference's full
+    forward pass and MTP pass (per-head attention over every earlier position,
+    float32); the full read is the kernel ``ht_latent_stream``."""
+    from heat_tpu.models import deepseek
+    from perf import manifest
+    from perf.drivers import spec_decode
+    from perf.generators import glm_weights
+    from perf.reference import glm as reference
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "perf", "configs", "glm-4.7-flash-5L-mtp-bf16.json")) as fh:
+        config = json.load(fh)
+    if REHEARSAL:
+        config = manifest.merged(config, config["rehearse"])
+        lengths, tol = [40, 29], 1e-4
+    else:
+        lengths, tol = [4096, 3001], 3e-2
+    cfg = spec_decode.model_config(config)
+    params = glm_weights.weights(config, jax.random.key(11), lambda leaf: leaf)
+    model = deepseek.DeepSeek(cfg, params)
+    steps = 4
+    tokens = np.random.default_rng(11).integers(0, cfg.vocab_held, (2, max(lengths))).astype(np.int32)
+    session = model.session(2, max(lengths) + 2 * steps)
+    form = latent_stream.stream_form(session.capacity, cfg.kv_lora_rank, cfg.qk_rope_head_dim)
+    check(REHEARSAL or form == "stream_kernel", f"the full read of a decode step is {form!r}")
+    (first, saved), prefill_s = clocked(lambda: (session.prefill(tokens, lengths=lengths),
+                                                 session.save()))
+    first_token = np.asarray(jnp.argmax(first.larray, -1))
+    (emitted, logits), decode_s = clocked(lambda: session.decode(steps))
+    emitted, logits = np.asarray(emitted.larray), np.asarray(logits.larray)
+    accepted, counts = session.accepted.copy(), session.emitted.copy()
+    drafts = np.asarray(model.last_drafts["logits"])
+    session.rewind(saved)
+    again, _ = session.decode(steps)
+    check(np.array_equal(np.asarray(again.larray), emitted),
+          "speculative decode after a rewind does not repeat itself")
+    check(session.positions.tolist() == [n + int(c) for n, c in zip(lengths, counts)],
+          "the sessions did not advance by what they emitted")
+    rcfg = spec_decode.reference_config(config)
+    errs, draft_errs = [], []
+    for b, n in enumerate(lengths):
+        count = int(counts[b])
+        seq = jnp.asarray(np.concatenate([tokens[b, :n], [first_token[b]], emitted[b, :count]]))
+        rows, mtp_rows, at = [], [], n
+        for j in range(steps):
+            rows += [(j, i, at + i) for i in range(1 + int(accepted[b, j]))]
+            at += 1 + int(accepted[b, j])
+            mtp_rows.append(at - 1)
+        want = reference.forward(rcfg, model.params, seq, [r[2] for r in rows], mtp_rows)
+        got = np.stack([logits[b, j, i] for j, i, _ in rows]).astype(np.float64)
+        ref_logits = np.asarray(want["logits"], np.float64)
+        check(np.isfinite(got).all(), "glm logits: non-finite values")
+        errs.extend(np.linalg.norm(got - ref_logits, axis=-1) / np.linalg.norm(ref_logits, axis=-1))
+        ref_drafts = np.asarray(want["draft_logits"], np.float64)
+        draft_errs.extend(np.linalg.norm(drafts[b] - ref_drafts, axis=-1)
+                          / np.linalg.norm(ref_drafts, axis=-1))
+    median, draft_median = float(np.median(errs)), float(np.median(draft_errs))
+    check(median <= tol, f"glm logits vs reference: median error {median:.3e} > {tol:.3e}")
+    check(draft_median <= tol, f"glm drafts vs reference: median error {draft_median:.3e}")
+    held = session.cache_bytes()["shared"]
+    model.params = None
+    return {"layers": cfg.num_hidden_layers, "prefill_s": round(prefill_s, 3),
+            "decode4_first_s": round(decode_s, 3), "stream": form,
+            "acceptance": float(accepted.mean()), "positions": session.positions.tolist(),
+            "logits_err_median": median, "logits_err_worst": float(max(errs)),
+            "draft_logits_err_median": draft_median, "cache_bytes": held}
+
+
 def st_fence():
     """ROADMAP S1: one chain of matmuls timed by block_until_ready and by a
     scalar readback.  If the two agree and both dwarf the enqueue-only
@@ -1043,6 +1117,7 @@ def main():
     stage("pallas_kernels", st_kernels)
     stage("brumby_serve", st_brumby)
     stage("deepseek_serve", st_deepseek)
+    stage("glm_drafter_serve", st_glm_drafter)
     stage("fence_timing", st_fence)
     if NDEV > 1:
         stage("multichip_schedules", st_multichip)

@@ -1,13 +1,16 @@
 """The session that serves a language model: a batch of sequences advancing
-in lockstep through a cache on the device.
+through a cache on the device, each at its own position.
 
 One :class:`DecodeSession` serves every model of :mod:`heat_tpu.models`:
 ``prefill(tokens)`` walks a prompt through the model in chunks,
 ``decode(steps)`` generates greedily on the device and reads the chosen
-tokens back once, ``save()`` / ``rewind(snapshot)`` return to an earlier
-position.  What differs between models is what the model supplies, the five
-``serve_*`` methods and ``prefill_chunk``, the positions one of its prefill
-programs walks:
+tokens back once, ``save()`` / ``rewind(snapshot)`` return to earlier
+positions.  A model whose step yields one token advances every sequence in
+lockstep; a model that drafts (``drafts`` 1: a decode step checks the pending
+token and a draft, and yields one token or two) advances each by what it
+accepted, and its prompts may differ in length.  What differs between models
+is what the model supplies, the five ``serve_*`` methods and
+``prefill_chunk``, the positions one of its prefill programs walks:
 
 - ``serve_cache(batch, max_context) -> (capacity, shared, state)``: how many
   positions the session can hold, the part of the cache that grows with the
@@ -21,7 +24,11 @@ programs walks:
 - ``serve_decode(shared, state, token, position, steps) -> (shared, state,
   token, chosen, logits)``: its jitted decode program; a sixth element, where
   a model returns one, is a dictionary of device numbers for the ``lm``
-  counters that ride back with the tokens in the one readback;
+  counters that ride back with the tokens in the one readback (and that the
+  ``lm.decode`` span's end event carries).  A model that drafts takes every
+  sequence's position, ``lengths`` in ``serve_prefill``, a pending token that
+  is the pair ``(token, draft)``, and returns as ``chosen`` the pair
+  ``(emitted, accepted)``;
 - ``serve_notes(session, steps) -> (notes, counts)``: the attributes its
   ``lm.decode`` span carries and what the call adds to the ``lm`` counters;
 - ``serve_bytes(shared, state) -> dict``: the bytes held, by kind.
@@ -62,8 +69,11 @@ __all__ = ["DecodeSession", "Snapshot"]
 # every visible slot, expert_pairs and experts_hit the token-expert pairs computed on the experts
 # held here and the held experts that received a token, summed over a call's steps and layers
 # (counted on the device); prefill_blocks_by_kernel the layers x key blocks of a sparse model's
-# prefills that the attention kernel folded (from shapes); cache_bytes is what the newest session
-# allocated, by kind
+# prefills that the attention kernel folded (from shapes); drafts_made and drafts_accepted the
+# drafts a drafting model's steps checked and those that stood (counted on the device), and
+# latent_rows_streamed the latent rows its full reads visited, layers x sessions x visible rows
+# summed over steps (counted on the device); cache_bytes is what the newest session allocated, by
+# kind
 _LM = telemetry.register_group(
     "lm",
     {"decode_steps": 0, "prefill_tokens": 0, "prefill_s": 0.0,
@@ -71,6 +81,7 @@ _LM = telemetry.register_group(
      "state_bytes_stepped": 0, "state_bytes_copied": 0,
      "index_keys_scanned": 0, "latent_rows_read": 0, "selections_by_cut": 0,
      "expert_pairs": 0, "experts_hit": 0, "prefill_blocks_by_kernel": 0,
+     "drafts_made": 0, "drafts_accepted": 0, "latent_rows_streamed": 0,
      "cache_bytes": {"shared": 0, "window": 0, "state": 0}},
 )
 
@@ -107,30 +118,45 @@ def _restored(live, saved):
 
 
 class Snapshot(NamedTuple):
-    """A saved position of a :class:`DecodeSession`: the position, the token
-    waiting to be fed there, and a copy of the constant-size state."""
+    """Saved positions of a :class:`DecodeSession`: the furthest position,
+    the token waiting to be fed at each sequence's (a model that drafts: the
+    token and its draft), a copy of the constant-size state, and every
+    sequence's position."""
 
     position: int
-    token: jax.Array
+    token: Any
     state: Any
+    positions: Any = None
 
 
 class DecodeSession:
-    """The cache of ``batch`` sequences that advance in lockstep, and the
-    calls that move it: :meth:`prefill`, :meth:`decode`, :meth:`save`,
-    :meth:`rewind`.  ``capacity`` is how many positions it can hold (the
-    model says: whole blocks of a key/value cache, or the positions its
-    encoding reaches)."""
+    """The cache of ``batch`` sequences, and the calls that move it:
+    :meth:`prefill`, :meth:`decode`, :meth:`save`, :meth:`rewind`.
+    ``capacity`` is how many positions it can hold (the model says: whole
+    blocks of a key/value cache, or the positions its encoding reaches);
+    ``positions`` is each sequence's position, ``position`` the furthest
+    (the one position of sequences in lockstep)."""
 
     def __init__(self, model, batch: int, max_context: int):
         self.model = model
         self.cfg = model.cfg
         self.batch = int(batch)
+        self.drafts = int(getattr(model, "drafts", 0))
         self.capacity, self._shared, self._state = model.serve_cache(self.batch, int(max_context))
-        self.position = 0
-        self._token = None  # the token waiting to be fed at `position`
+        self.positions = np.zeros(self.batch, np.int64)
+        self._token = None  # the token waiting to be fed at each position (and its draft)
         self.tokens = None  # the newest decode call's tokens, on the host
+        self.emitted = None  # a drafting model's newest call: tokens emitted a sequence, on the host
+        self.accepted = None  # and whether each step's draft stood, (batch, steps)
         _LM["cache_bytes"].update(dict.fromkeys(_LM["cache_bytes"], 0), **self.cache_bytes())
+
+    @property
+    def position(self) -> int:
+        return int(self.positions.max())
+
+    @position.setter
+    def position(self, value: int) -> None:
+        self.positions = np.full(self.batch, int(value), np.int64)
 
     def cache_bytes(self) -> dict:
         """Bytes this session holds on the device, by kind of state."""
@@ -142,76 +168,110 @@ class DecodeSession:
         return DNDarray(array, tuple(array.shape), types.canonical_heat_type(array.dtype),
                         None, get_device(), self.model.comm)
 
-    def prefill(self, tokens) -> DNDarray:
-        """Append a prompt of ``(batch, n)`` token ids at the current position.
-        Returns the logits of its last position, ``(batch, vocab)``; their
-        argmax is the token the next :meth:`decode` feeds first."""
+    def prefill(self, tokens, lengths=None) -> DNDarray:
+        """Append a prompt of ``(batch, n)`` token ids at the current position
+        (one for every sequence).  Returns the logits of its last position,
+        ``(batch, vocab)``; their argmax is the token the next :meth:`decode`
+        feeds first.  ``lengths`` (a model that drafts): sequence ``b``'s
+        prompt is ``tokens[b, :lengths[b]]``, and the logits are those of its
+        last position."""
         ids = tokens.larray if isinstance(tokens, DNDarray) else jnp.asarray(tokens)
         ids = ids.astype(jnp.int32)
         if ids.ndim != 2 or ids.shape[0] != self.batch or ids.shape[1] < 1:
             raise ValueError(f"prefill takes (batch={self.batch}, n >= 1) token ids, got {ids.shape}")
         n = int(ids.shape[1])
+        if lengths is not None:
+            if not self.drafts:
+                raise ValueError("the sequences of a model whose step yields one token advance in "
+                                 "lockstep: prefill takes no lengths")
+            lengths = np.asarray(lengths, np.int64)
+            if lengths.shape != (self.batch,) or lengths.min() < 1 or lengths.max() > n:
+                raise ValueError(f"lengths are (batch={self.batch},) numbers in 1 .. {n}")
+        if self.positions.min() != self.positions.max():
+            raise ValueError("prefill appends at one position for every sequence")
         if self.position + n > self.capacity:
             raise ValueError(f"{self.position} + {n} positions pass the session's {self.capacity}")
+        advance = np.full(self.batch, n, np.int64) if lengths is None else lengths
         started = time.perf_counter()
-        with telemetry.span("lm.prefill", tokens=self.batch * n,
+        with telemetry.span("lm.prefill", tokens=int(advance.sum()),
                             chunk=self.model.prefill_chunk) as span:
+            extra = () if lengths is None else (lengths,)
             self._shared, self._state, self._token, logits, *told = self.model.serve_prefill(
-                self._shared, self._state, ids, self.position)
+                self._shared, self._state, ids, self.position, *extra)
             notes, counts = told[0] if told else ({}, {})
             span.note(**notes)
             # the span ends when the first token is ready, as a serving loop
             # waits for it before it streams: the session's time to first token
             with telemetry.sync("lm.prefill"):
                 jax.block_until_ready(self._token)
-            self.position += n
+            self.positions = self.positions + advance
         if telemetry.ledger_enabled():
             _LM["prefill_s"] += time.perf_counter() - started
-        _LM["prefill_tokens"] += self.batch * n
+        _LM["prefill_tokens"] += int(advance.sum())
         for name, count in counts.items():
             _LM[name] += count
         return self._wrap(logits)
 
     def decode(self, steps: int):
-        """``steps`` greedy tokens for every sequence.  Returns ``(tokens,
+        """``steps`` greedy steps for every sequence.  Returns ``(tokens,
         logits)``: the tokens chosen, ``(batch, steps)`` int32, and the logits
         they were chosen from, ``(batch, steps, vocab)`` float32; ``logits[:,
         j]`` are those of position ``position + j``.  The tokens are read back
-        once (``session.tokens``: what a serving loop looks at)."""
+        once (``session.tokens``: what a serving loop looks at).
+
+        A model that drafts: a step checks two positions a sequence and emits
+        one token or two.  ``tokens`` ``(batch, 2 steps)`` are each sequence's
+        emitted tokens, then -1 (``session.emitted``: how many, read back with
+        them, as ``session.accepted``, ``(batch, steps)``, whether each step's
+        draft stood); ``logits`` ``(batch, steps, 2, vocab)`` are those of the
+        positions each step checked, the pending token's and the draft's."""
         steps = int(steps)
         if self._token is None:
             raise ValueError("decode needs a prompt: call prefill first")
-        if self.position + steps > self.capacity:
-            raise ValueError(f"{self.position} + {steps} positions pass the session's {self.capacity}")
+        reach = (1 + self.drafts) * steps
+        if self.position + reach > self.capacity:
+            raise ValueError(f"{self.position} + {reach} positions pass the session's {self.capacity}")
         notes, counts = self.model.serve_notes(self, steps)
-        with telemetry.span("lm.decode", **notes):
+        at = self.positions if self.drafts else self.position
+        with telemetry.span("lm.decode", **notes) as span:
             self._shared, self._state, self._token, chosen, logits, *counted = (
-                self.model.serve_decode(self._shared, self._state, self._token, self.position,
-                                        steps))
+                self.model.serve_decode(self._shared, self._state, self._token, at, steps))
             with telemetry.sync("lm.tokens"):
                 self.tokens, counted = jax.device_get((chosen, dict(*counted)))
-        self.position += steps
+            counted = {name: int(count) for name, count in counted.items()}
+            advance = steps
+            if self.drafts:
+                self.tokens, self.accepted = self.tokens
+                self.emitted = advance = steps + self.accepted.sum(axis=1)
+                chosen = chosen[0]
+                span.note(acceptance=counted["drafts_accepted"] / (self.batch * steps))
+            span.note(**counted)
+        self.positions = self.positions + advance
         _LM["decode_steps"] += steps
         for name, count in {**counts, **counted}.items():
             _LM[name] += int(count)
         return self._wrap(chosen), self._wrap(logits)
 
     def save(self) -> Snapshot:
-        """The current position, to :meth:`rewind` to.  Copies the
+        """The current positions, to :meth:`rewind` to.  Copies the
         constant-size state (window rings and Mamba states; a retention
         model's whole state; nothing of a model whose whole cache grows with
-        the context); a shared key/value cache is not copied, its entries
-        past a saved position are simply overwritten later."""
+        the context) and the pending tokens (and drafts); a shared key/value
+        cache is not copied, its entries past a saved position are simply
+        overwritten later."""
         if self._token is None:
             raise ValueError("nothing to save before the first prefill")
         token, state = _copied((self._token, self._state))
         _LM["state_bytes_copied"] += tree_bytes(state)
-        return Snapshot(self.position, token, state)
+        return Snapshot(self.position, token, state, self.positions.copy())
 
     def rewind(self, snapshot: Snapshot) -> None:
-        """Back to a saved position of this session."""
+        """Back to saved positions of this session."""
         with telemetry.span("lm.rewind", position=snapshot.position):
             live, self._token, self._state = (self._token, self._state), None, None
             self._token, self._state = _restored(live, (snapshot.token, snapshot.state))
-            self.position = snapshot.position
+            if snapshot.positions is None:
+                self.position = snapshot.position
+            else:
+                self.positions = np.array(snapshot.positions, np.int64)
         _LM["state_bytes_copied"] += tree_bytes(self._state)

@@ -442,6 +442,12 @@ _VMEM_PREFILL = 64 << 20
 _NEG_F32, _ZERO_F32 = np.float32(-np.inf), np.float32(0)
 
 
+def _depth(nope: int, rope: int) -> int:
+    """A query's no-position part and its rotary part once for each position
+    a rope row holds, padded to whole lane tiles."""
+    return -(-(nope + ROPE_PACK * rope) // LANE) * LANE
+
+
 def _prefill_tiles(chunk: int, capacity: int, heads: int, widths, itemsize: int):
     """``(key block, query rows a fold, heads a grid step, VMEM bytes)`` of the
     kernel at these shapes; ``widths`` is ``(nope, rope, v, rank)``."""
@@ -449,7 +455,7 @@ def _prefill_tiles(chunk: int, capacity: int, heads: int, widths, itemsize: int)
     block = min(KEY_BLOCK, capacity)
     rows = next((r for r in (_QUERY_TILE, 128, 64, 32, 16) if chunk % r == 0), 0)
     group = next(g for g in (_HEADS_A_STEP, 2, 1) if heads % g == 0)
-    depth = nope + -(-ROPE_PACK * rope // LANE) * LANE          # a query's two parts, whole lane tiles
+    depth = _depth(nope, rope)                                   # a query's two parts, whole lane tiles
     fetched = (chunk * group * depth * itemsize                  # queries
                + chunk * (LANE + block) * 4                      # cut, scores
                + block * (rank + rope) * itemsize                # latent, rope rows
@@ -466,8 +472,10 @@ def prefill_form(chunk: int, capacity: int, heads: int, widths, itemsize: int) -
     (``widths``: ``(nope, rope, v, rank)``): ``flash_kernel`` (the Pallas
     kernel ``ht_sparse_prefill_attn``) where the Pallas mode is ``tpu`` or
     ``interpret``, the chunk is whole query tiles and, on a TPU, the widths
-    are whole lane tiles and the tiles fit the kernel's VMEM; else
-    ``blockwise_jnp`` (the same blocks and rounding in ``jax.numpy``)."""
+    are whole lane tiles (the no-position part whole sublane tiles: the two
+    parts side by side are padded to lanes) and the tiles fit the kernel's
+    VMEM; else ``blockwise_jnp`` (the same blocks and rounding in
+    ``jax.numpy``)."""
     how = _mode()
     block, rows, _, held = _prefill_tiles(chunk, capacity, heads, widths, itemsize)
     whole = rows > 0 and capacity % block == 0 and block % ROPE_PACK == 0
@@ -475,28 +483,36 @@ def prefill_form(chunk: int, capacity: int, heads: int, widths, itemsize: int) -
     if how == "interpret" and whole:
         return "flash_kernel"
     if (how == "tpu" and whole and held <= _VMEM_PREFILL
-            and all(n % LANE == 0 for n in (nope, v, rank, block, ROPE_PACK * rope))):
+            and nope % 16 == 0
+            and all(n % LANE == 0 for n in (v, rank, block, ROPE_PACK * rope))):
         return "flash_kernel"
     return "blockwise_jnp"
 
 
-def _prefill_kernel(plan_ref, q_ref, cut_ref, score_ref, ckv_ref, rope_ref, wuk_ref, wuv_ref,
-                    out_ref, top_ref, total_ref, kt_ref, v_ref, *, rows, rope):
+def _prefill_kernel(plan_ref, q_ref, *refs, rows, rope, causal):
     """One head group's fold of one key block: grid ``(head groups, key
     blocks)``, the blocks in ascending order, none past ``plan_ref[0]``
     (``plan_ref[1]`` is the chunk's first position).
     ``q_ref`` ``(group, chunk, depth)``: a query's no-position part, then its
-    rotary part once for each position a rope row holds; ``kt_ref`` ``(group,
-    depth, block)`` the keys turned the same way, a slot's rotary key in its
-    own lanes and zeros in its neighbours'.  ``out_ref`` ``(chunk, group *
-    v)`` holds the running value sums and, after the last block, the
-    attention; ``top_ref``, ``total_ref`` ``(group, chunk, 1)`` the running
-    max and total."""
+    rotary part once for each position a rope row holds, then zeros to whole
+    lane tiles; ``kt_ref`` ``(group, depth, block)`` the keys turned the same
+    way, a slot's rotary key in its own lanes and zeros in its neighbours'.
+    ``out_ref`` ``(chunk, group * v)`` holds the running value sums and, after
+    the last block, the attention; ``top_ref``, ``total_ref`` ``(group, chunk,
+    1)`` the running max and total.  A query reads the selection ``score >=
+    cut`` (``cut_ref``, ``score_ref``), or ``causal``: every slot up to its
+    own position (there are no such refs)."""
+    if causal:
+        cut_ref = score_ref = None
+    else:
+        cut_ref, score_ref, *refs = refs
+    ckv_ref, rope_ref, wuk_ref, wuv_ref, out_ref, top_ref, total_ref, kt_ref, v_ref = refs
     j = pl.program_id(1)
     group, chunk, _ = top_ref.shape
     block = ckv_ref.shape[0]
     nope, v = wuk_ref.shape[0] // group, v_ref.shape[1] // group
-    width = kt_ref.shape[1] - nope
+    width = ROPE_PACK * rope
+    pad = kt_ref.shape[1] - nope - width
     dtype = ckv_ref.dtype
     tiles = np.int32(chunk // rows)
 
@@ -530,12 +546,20 @@ def _prefill_kernel(plan_ref, q_ref, cut_ref, score_ref, ckv_ref, rope_ref, wuk_
         k_pe = k_pe.T.astype(dtype)
         for h in range(group):
             kt_ref[h, :nope, :] = k_nope[h * nope:(h + 1) * nope]
-            kt_ref[h, nope:, :] = k_pe
+            kt_ref[h, nope:nope + width, :] = k_pe
+            if pad:
+                kt_ref[h, nope + width:, :] = jnp.zeros((pad, block), dtype)
 
         def one_tile(t, carry):
             at = rows_of(t)
-            score = score_ref[at, :]
-            read = (score >= cut_ref[at, :]) & (score > _NEG_F32)
+            if causal:
+                slot = j * np.int32(block) + jax.lax.broadcasted_iota(jnp.int32, (rows, block), 1)
+                position = plan_ref[1] + t * np.int32(rows) + jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, block), 0)
+                read = slot <= position
+            else:
+                score = score_ref[at, :]
+                read = (score >= cut_ref[at, :]) & (score > _NEG_F32)
             for h in range(group):
                 logit = jnp.dot(q_ref[h, at, :], kt_ref[h], preferred_element_type=_F32)
                 logit = jnp.where(read, logit, _NEG_F32)
@@ -578,24 +602,32 @@ def _prefill_pallas(q_nope, q_pe, latent_cache, rope_cache, scores, cut, pos0, w
     block, rows, group, _ = _prefill_tiles(chunk, capacity, heads, (nope, rope, v, rank),
                                            dtype.itemsize)
     width = ROPE_PACK * rope
-    q = jnp.moveaxis(jnp.concatenate([q_nope, jnp.tile(q_pe, (1, 1, ROPE_PACK))], axis=-1), 1, 0)
+    depth = _depth(nope, rope)
+    q = jnp.concatenate([q_nope, jnp.tile(q_pe, (1, 1, ROPE_PACK))]
+                        + ([jnp.zeros((chunk, heads, depth - nope - width), q_nope.dtype)]
+                           if depth > nope + width else []), axis=-1)
+    q = jnp.moveaxis(q, 1, 0)
     pos0 = jnp.asarray(pos0, jnp.int32)
+    causal = scores is None
     plan = jnp.stack([key_blocks(pos0, chunk, capacity), pos0])
 
     def key(g, j, n):
         """the block a step holds: past the chunk's last block the pipeline keeps its last"""
         return jnp.minimum(j, n[0] - 1)
 
-    depth, passes = nope + width, heads // group
+    passes = heads // group
+    selection = [] if causal else [
+        pl.BlockSpec((chunk, 1), lambda g, j, n: (0, 0)),
+        pl.BlockSpec((chunk, block), lambda g, j, n: (0, key(g, j, n)))]
+    operands = [] if causal else [cut[:, None], scores]
     return pl.pallas_call(
-        functools.partial(_prefill_kernel, rows=rows, rope=rope),
+        functools.partial(_prefill_kernel, rows=rows, rope=rope, causal=causal),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(passes, capacity // block),
             in_specs=[
                 pl.BlockSpec((group, chunk, depth), lambda g, j, n: (g, 0, 0)),
-                pl.BlockSpec((chunk, 1), lambda g, j, n: (0, 0)),
-                pl.BlockSpec((chunk, block), lambda g, j, n: (0, key(g, j, n))),
+            ] + selection + [
                 pl.BlockSpec((block, rank), lambda g, j, n: (key(g, j, n), 0)),
                 pl.BlockSpec((block // ROPE_PACK, ROPE_PACK * rope),
                              lambda g, j, n: (key(g, j, n), 0)),
@@ -618,19 +650,21 @@ def _prefill_pallas(q_nope, q_pe, latent_cache, rope_cache, scores, cut, pos0, w
         cost_estimate=pl.CostEstimate(
             flops=2 * heads * capacity * (chunk * (depth + v) + rank * (nope + v)),
             transcendentals=heads * chunk * capacity,
-            bytes_accessed=(passes * (4 * chunk * capacity + capacity * (rank + rope) * dtype.itemsize)
+            bytes_accessed=(passes * ((0 if causal else 4 * chunk * capacity)
+                                      + capacity * (rank + rope) * dtype.itemsize)
                             + (chunk * depth + rank * (nope + v)) * heads * dtype.itemsize
                             + 4 * chunk * heads * v)),
         interpret=interpret,
         name="ht_sparse_prefill_attn",
-    )(plan, q, cut[:, None], scores, latent_cache, rope_cache, w_uk.reshape(heads * nope, rank),
+    )(plan, q, *operands, latent_cache, rope_cache, w_uk.reshape(heads * nope, rank),
       jnp.moveaxis(w_uv, 0, 1).reshape(rank, heads * v)).reshape(chunk, heads, v)
 
 
 def latent_prefill_attention(q_nope, q_pe, latent_cache, rope_cache, scores, cut, pos0,
                              w_uk, w_uv, scale: float):
     """The per-head attention of a chunk of one session's queries over the
-    selection ``scores >= cut``.  ``q_nope`` ``(chunk, heads, nope)``, ``q_pe``
+    selection ``scores >= cut`` (``scores`` None: over every slot up to the
+    query's position, ``pos0 + row``; no scores are read).  ``q_nope`` ``(chunk, heads, nope)``, ``q_pe``
     ``(chunk, heads, rope)``, ``latent_cache`` ``(capacity, rank)``, ``rope_cache``
     ``(capacity / ROPE_PACK, ROPE_PACK * rope)``, ``scores`` ``(chunk, capacity)`` with ``-inf`` where a slot is not visible,
     ``cut`` ``(chunk,)``, ``w_uk`` ``(heads, nope, rank)``, ``w_uv`` ``(heads,
@@ -667,8 +701,12 @@ def latent_prefill_attention(q_nope, q_pe, latent_cache, rope_cache, scores, cut
         v = jnp.einsum("sc,hcv->shv", c_kv, w_uv, preferred_element_type=_F32).astype(dtype)
         k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe[:, None], (block, heads, rope))], -1)
         logit = jnp.einsum("thd,shd->hts", q, k, preferred_element_type=_F32)
-        score = jax.lax.dynamic_slice_in_dim(scores, j * block, block, axis=1)
-        read = (score >= cut[:, None]) & (score > _NEG)
+        if scores is None:
+            slot = j * block + jnp.arange(block, dtype=jnp.int32)
+            read = slot[None, :] <= pos0 + jnp.arange(chunk, dtype=jnp.int32)[:, None]
+        else:
+            score = jax.lax.dynamic_slice_in_dim(scores, j * block, block, axis=1)
+            read = (score >= cut[:, None]) & (score > _NEG)
         logit = jnp.where(read[None], logit, _NEG)
         new_top = jnp.maximum(top, jnp.max(logit, axis=-1))
         safe = jnp.where(new_top > _NEG, new_top, 0.0)

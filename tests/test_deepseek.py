@@ -53,7 +53,8 @@ PUBLISHED = {
     "tie_word_embeddings": False, "topk_group": 4, "topk_method": "noaux_tc",
     "v_head_dim": 128, "vocab_size": 129280,
 }
-CUT = dict(num_hidden_layers=5, first_k_dense_replace=1, experts_held=(0, 16), vocab_held=16160)
+CUT = dict(num_hidden_layers=5, first_k_dense_replace=1, experts_held=(0, 16), vocab_held=16160,
+           num_nextn_predict_layers=0)
 
 
 def toy(dtype="float32", **over):
@@ -690,7 +691,7 @@ def test_published_parameter_counts():
         assert row["config"] == PUBLISHED
         published = row["config"]
     whole = deepseek.DeepSeekConfig.from_dict(published)
-    assert whole == deepseek.DeepSeekConfig()
+    assert whole == deepseek.DeepSeekConfig(num_nextn_predict_layers=1)     # its MTP module
     assert whole.experts_held == (0, 256) and whole.vocab_held == 129280
     n = deepseek.param_count(whole)
     mla = (7168 * 1536 + 1536 * 24576 + 7168 * 576 + 512 * 32768 + 16384 * 7168 + 1536 + 512)
