@@ -21,11 +21,17 @@ blocks of up to four key blocks of
 position scalar-prefetched): a block past the session's last visible slot is
 neither fetched nor folded, the causal mask of the step's positions is applied
 in the key block they fall into alone, and a running softmax in float32 folds
-the key blocks in VMEM.  It asks for no VMEM beyond what every kernel has: it
-runs in a decode program whose other fusions keep theirs (PERF.md section
-6).  Elsewhere, and for shapes the kernel does not
-take (:func:`stream_form`), the same attention is a masked softmax over the
-whole cache in ``jax.numpy``.
+the key blocks in VMEM.  A key block's rope rows are read as stored: each slot
+is given its pair's whole row (in bfloat16 by writing a row's 16 bits into
+both halves of a 32-bit word, which is that row twice; else by a product with
+a 0/1 table), the query's rotary part comes once for each position a row
+holds, in that position's lanes and zeros elsewhere, and one product gives
+the rotary logits against every position of a slot's pair, of which a select
+by the slot's parity keeps its own.  It asks for no VMEM beyond what every
+kernel has: it runs in a decode program whose other fusions keep theirs
+(PERF.md section 6).  Elsewhere, and for shapes the kernel does not take
+(:func:`stream_form`), the same attention is a masked softmax over the whole
+cache in ``jax.numpy``.
 """
 
 from __future__ import annotations
@@ -88,20 +94,44 @@ def _masked(q_lat, q_pe, latent_cache, rope_cache, positions, scale):
                       preferred_element_type=_F32)
 
 
+def _rows_by_words(dtype) -> bool:
+    """Whether :func:`_pair_rows` doubles a row within 32-bit words (two
+    positions a rope row, each 16-bit bfloat16) rather than by a product."""
+    return jnp.dtype(dtype) == jnp.bfloat16 and ROPE_PACK == 2
+
+
+def _pair_rows(rope_rows, block: int):
+    """Each slot's row of a key block's rope rows ``(block / ROPE_PACK,
+    ROPE_PACK * rope)``: ``(block, ROPE_PACK * rope)`` in the cache's type,
+    slot ``s`` holding row ``s // ROPE_PACK`` whole, both its positions'
+    keys."""
+    dtype = rope_rows.dtype
+    if _rows_by_words(dtype):
+        # bfloat16 widened to float32 is its 16 bits shifted up: the same bits in both halves of
+        # a word are the row twice, the two neighbouring rows a word of bfloat16 holds
+        bits = pltpu.bitcast(rope_rows.astype(_F32), jnp.uint32)
+        return pltpu.bitcast(bits | (bits >> np.uint32(16)), dtype)
+    shape = (block, rope_rows.shape[0])
+    table = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) // ROPE_PACK
+             == jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    # a product with a 0/1 table: exact
+    return jnp.dot(table.astype(dtype), rope_rows, preferred_element_type=_F32).astype(dtype)
+
+
 def _stream_kernel(pos_ref, q_ref, qpe_ref, ckv_ref, rope_ref, out_ref, top_ref, total_ref, *,
                    heads: int, queries: int, scale: float, block: int):
     """One session's fold of one fetched block, ``block`` slots at a time:
     grid ``(sessions, fetched blocks)``, the blocks in ascending order.
-    ``q_ref`` ``(queries * heads, rank)``, ``qpe_ref`` ``(queries * heads,
-    ROPE_PACK * rope)`` (a query's rotary part once for each position a rope
-    row holds), ``out_ref`` ``(queries * heads, rank)`` holds the running
-    value sums and, after the session's last visible block, the attention;
-    ``top_ref``, ``total_ref`` ``(queries * heads, 1)`` the running max and
-    total."""
+    ``q_ref`` ``(queries * heads, rank)``, ``qpe_ref`` ``(ROPE_PACK * queries
+    * heads, ROPE_PACK * rope)`` (copy ``p`` of a query's rotary part in the
+    lanes of a rope row's position ``p``, zeros elsewhere), ``out_ref``
+    ``(queries * heads, rank)`` holds the running value sums and, after the
+    session's last visible block, the attention; ``top_ref``, ``total_ref``
+    ``(queries * heads, 1)`` the running max and total.  A block's rotary
+    logits are one product of ``qpe_ref`` with each slot's pair row
+    (:func:`_pair_rows`), copy ``s % ROPE_PACK`` kept for slot ``s``."""
     b, j = pl.program_id(0), pl.program_id(1)
     fetched = ckv_ref.shape[0]
-    width = rope_ref.shape[1]
-    rope = width // ROPE_PACK
     rows = q_ref.shape[0]
     first = pos_ref[b]                               # the step's first position in this session
     last = first + np.int32(queries - 1)             # the slot the step's last query reads up to
@@ -117,25 +147,17 @@ def _stream_kernel(pos_ref, q_ref, qpe_ref, ckv_ref, rope_ref, out_ref, top_ref,
         start = j * np.int32(fetched) + np.int32(i * block)
         c_kv = ckv_ref[i * block:(i + 1) * block, :]
         half = block // ROPE_PACK
-        rope_rows = rope_ref[i * half:(i + 1) * half, :]
         dtype = c_kv.dtype
         logit = jax.lax.dot_general(q_ref[...], c_kv, (((1,), (1,)), ((), ())),
                                     preferred_element_type=_F32)
-        # each slot's row of the rope block (a product with a 0/1 table: exact), its own lanes
-        # kept and its neighbour's zeroed
-        shape = (block, block // ROPE_PACK)
-        slot = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-        row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (block, width), 1)
-        k_pe = jnp.zeros((block, width), _F32)
-        for pair in range(ROPE_PACK):
-            spread = jnp.dot((slot == row * ROPE_PACK + pair).astype(dtype), rope_rows,
-                             preferred_element_type=_F32)
-            k_pe = k_pe + jnp.where((lane >= pair * rope) & (lane < (pair + 1) * rope), spread,
-                                    _ZERO_F32)
-        logit = logit + jax.lax.dot_general(qpe_ref[...], k_pe.astype(dtype),
-                                            (((1,), (1,)), ((), ())), preferred_element_type=_F32)
-        logit = logit * np.float32(scale)
+        k_pair = _pair_rows(rope_ref[i * half:(i + 1) * half, :], block)
+        pair = jax.lax.dot_general(qpe_ref[...], k_pair, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=_F32)
+        parity = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 1) % ROPE_PACK
+        rope_logit = pair[:rows]
+        for p in range(1, ROPE_PACK):
+            rope_logit = jnp.where(parity == p, pair[p * rows:(p + 1) * rows], rope_logit)
+        logit = (logit + rope_logit) * np.float32(scale)
         if masked:
             key = start + jax.lax.broadcasted_iota(jnp.int32, (rows, block), 1)
             query = first + jax.lax.broadcasted_iota(jnp.int32, (rows, block), 0) // np.int32(heads)
@@ -177,8 +199,11 @@ def _stream_pallas(q_lat, q_pe, latent_cache, rope_cache, positions, scale, *, i
     rows = queries * heads
     width = ROPE_PACK * rope
     q = q_lat.astype(dtype).reshape(batch, rows, rank)
-    q_pe = jnp.tile(q_pe.astype(dtype), (1, 1, 1, ROPE_PACK)).reshape(batch, rows, width)
+    q_pe = q_pe.astype(dtype).reshape(batch, rows, rope)
+    q_pe = jnp.concatenate([jnp.pad(q_pe, ((0, 0), (0, 0), (p * rope, width - (p + 1) * rope)))
+                            for p in range(ROPE_PACK)], axis=1)
     positions = jnp.asarray(positions, jnp.int32)
+    expand = 0 if _rows_by_words(dtype) else block // ROPE_PACK * width   # _pair_rows' product
 
     def key(b, j, pos):
         """the block a step holds: past the session's last visible block the pipeline keeps its
@@ -192,7 +217,7 @@ def _stream_pallas(q_lat, q_pe, latent_cache, rope_cache, positions, scale, *, i
             grid=(batch, capacity // fetched),
             in_specs=[
                 pl.BlockSpec((None, rows, rank), lambda b, j, pos: (b, 0, 0)),
-                pl.BlockSpec((None, rows, width), lambda b, j, pos: (b, 0, 0)),
+                pl.BlockSpec((None, ROPE_PACK * rows, width), lambda b, j, pos: (b, 0, 0)),
                 pl.BlockSpec((None, fetched, rank), lambda b, j, pos: (b, key(b, j, pos), 0)),
                 pl.BlockSpec((None, fetched // ROPE_PACK, width),
                              lambda b, j, pos: (b, key(b, j, pos), 0)),
@@ -205,10 +230,11 @@ def _stream_pallas(q_lat, q_pe, latent_cache, rope_cache, positions, scale, *, i
         # the capacity, not the visible rows (the positions are traced): the bound the scheduler
         # plans with
         cost_estimate=pl.CostEstimate(
-            flops=2 * batch * capacity * (rows * (2 * rank + width) + 2 * block * width),
+            flops=2 * batch * capacity * (rows * (2 * rank + ROPE_PACK * width) + expand),
             transcendentals=batch * rows * capacity,
             bytes_accessed=batch * (capacity * (rank + rope) * dtype.itemsize
-                                    + rows * (rank + width) * dtype.itemsize + 4 * rows * rank)),
+                                    + rows * (rank + ROPE_PACK * width) * dtype.itemsize
+                                    + 4 * rows * rank)),
         interpret=interpret,
         name="ht_latent_stream",
     )(positions, q, q_pe, latent_cache, rope_cache).reshape(batch, queries, heads, rank)

@@ -162,26 +162,55 @@ def stream_inputs(dtype, batch, queries, capacity, heads=4, rank=32, rope=8, see
             jnp.asarray(normal(batch, capacity // la.ROPE_PACK, la.ROPE_PACK * rope), dtype))
 
 
-@pytest.mark.parametrize("visible", [1, 256, 300, 512], ids=["one_row", "block_edge", "mid_block",
-                                                             "capacity"])
+def kernel_and_masked(args, positions, monkeypatch, capacity=512):
+    """The attention through ``ht_latent_stream`` in the interpreter and
+    through the ``jax.numpy`` form, in that order."""
+    got = {}
+    for how, form in (("interpret", "stream_kernel"), ("off", "masked_jnp")):
+        monkeypatch.setenv("HEAT_TPU_PALLAS", how)
+        assert ls.stream_form(capacity, 32, 8) == form
+        got[how] = ls.latent_stream_attention(*args, jnp.asarray(positions, jnp.int32), 0.3)
+    return got["interpret"], got["off"]
+
+
+@pytest.mark.parametrize("visible", [1, 256, 300, 512, 255, 257],
+                         ids=["one_row", "block_edge", "mid_block", "capacity", "odd_last_slot",
+                              "even_last_slot_past_edge"])
 @pytest.mark.parametrize("queries", [1, 2])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_the_stream_kernel_is_the_masked_form(dtype, queries, visible, monkeypatch):
     """``ht_latent_stream`` through the interpreter against the ``jax.numpy``
     form: a session whose last query sees ``visible`` rows of a capacity of
     two key blocks (one row, a block edge, inside the second block, every
-    row), beside sessions at other positions."""
+    row; a last visible slot odd and even on either side of the edge),
+    beside sessions at other positions."""
     capacity = 512
     args = stream_inputs(dtype, 3, queries, capacity, seed=visible)
     # a step's last query sees its own rows at least
-    positions = jnp.asarray([max(visible - queries, 0), 0, capacity - queries], jnp.int32)
-    got = {}
-    for how, form in (("off", "masked_jnp"), ("interpret", "stream_kernel")):
-        monkeypatch.setenv("HEAT_TPU_PALLAS", how)
-        assert ls.stream_form(capacity, 32, 8) == form
-        got[how] = ls.latent_stream_attention(*args, positions, 0.3)
-    assert got["interpret"].shape == (3, queries, 4, 32) and got["interpret"].dtype == jnp.float32
-    assert rel_err(got["interpret"], got["off"]) < (F32_TOL if dtype == "float32" else 4e-3)
+    positions = [max(visible - queries, 0), 0, capacity - queries]
+    got, want = kernel_and_masked(args, positions, monkeypatch)
+    assert got.shape == (3, queries, 4, 32) and got.dtype == jnp.float32
+    assert rel_err(got, want) < (F32_TOL if dtype == "float32" else 4e-3)
+
+
+@pytest.mark.parametrize("positions", [[3, 129, 201], [255, 1, 509]],
+                         ids=["odd_steps_in_a_block", "odd_steps_across_the_edge"])
+@pytest.mark.parametrize("rope_only", [False, True], ids=["latent_and_rope", "rope_only"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_stream_kernel_reads_each_slot_its_own_rotary_key(dtype, rope_only, positions,
+                                                               monkeypatch):
+    """A rope row holds two neighbouring positions' keys and the kernel keeps
+    a slot's own by its parity: steps of two queries that start at odd
+    positions, so that the pair of the step's first and second position
+    straddles a rope row (in a block, and across the block edge at 255 and
+    256), against the ``jax.numpy`` form.  With ``q_lat`` zero the rotary
+    keys alone decide the weights, so a slot that took its neighbour's key
+    would move the result by the weights' whole spread."""
+    args = stream_inputs(dtype, 3, 2, 512, seed=sum(positions))
+    if rope_only:
+        args = (jnp.zeros_like(args[0]),) + args[1:]
+    got, want = kernel_and_masked(args, positions, monkeypatch)
+    assert rel_err(got, want) < (F32_TOL if dtype == "float32" else 4e-3)
 
 
 def test_the_masked_form_is_the_per_position_softmax():
