@@ -909,6 +909,80 @@ def st_glm_drafter():
             "draft_logits_err_median": draft_median, "cache_bytes": held}
 
 
+def st_sdar_block_diffusion():
+    """SDAR-30B-A3B-Chat at the benchmark configuration's widths (128 experts
+    of 768 a layer, the whole vocabulary) and two layers, the cell's seeded
+    weights, on one device: prefill of two sessions of 4,094 and 3,001 tokens
+    (tails of 2 and 1 waiting in the first block), a save, three blocks of
+    four positions (two denoising passes and a commit each), a rewind and the
+    same blocks again, against the plain reference teacher-forced on the
+    program's block states (the block-causal forward pass, float32); every
+    pass reads through the kernel ``ht_decode_attention``, each session to its
+    own block's end."""
+    from heat_tpu.models import sdar
+    from perf import manifest
+    from perf.drivers import diffusion_decode
+    from perf.generators import sdar_weights
+    from perf.reference import sdar as reference
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "perf", "configs", "sdar-30b-a3b-5L-bd4-bf16.json")) as fh:
+        config = json.load(fh)
+    if REHEARSAL:
+        config = manifest.merged(config, config["rehearse"])
+        lengths, tol = [30, 21], 1e-4
+    else:
+        config["num_hidden_layers"] = 2
+        lengths, tol = [4094, 3001], 3e-2
+    cfg = diffusion_decode.model_config(config)
+    params = sdar_weights.weights(config, jax.random.key(12), lambda leaf: leaf)
+    model = sdar.SDAR(cfg, params)
+    blocks, width = 3, cfg.block_length
+    tokens = np.random.default_rng(12).integers(0, cfg.mask_token_id, (2, max(lengths)))
+    session = model.session(2, max(lengths) + width * blocks)
+    (_, saved), prefill_s = clocked(lambda: (session.prefill(tokens.astype(np.int32),
+                                                             lengths=lengths), session.save()))
+    (made, logits), decode_s = clocked(lambda: session.decode(blocks))
+    made, logits, confidence = np.asarray(made.larray), np.asarray(logits.larray), session.confidence
+    session.rewind(saved)
+    again, _ = session.decode(blocks)
+    check(np.array_equal(np.asarray(again.larray), made),
+          "block diffusion after a rewind does not repeat itself")
+    whole = [n - n % width for n in lengths]
+    check(session.positions.tolist() == [w + width * blocks for w in whole],
+          "the sessions did not advance by whole blocks from their prompts' whole blocks")
+    rcfg = diffusion_decode.reference_config(config)
+    errs = []
+    for b, n in enumerate(lengths):
+        before = reference.prefix(rcfg, model.params, jnp.asarray(tokens[b, :whole[b]]),
+                                  session.capacity)
+        for j in range(blocks):
+            pos = whole[b] + j * width
+            final = made[b, j * width:(j + 1) * width].copy()
+            for i in range(max(0, min(width, n - pos))):
+                final[i] = tokens[b, pos + i]
+            fixed = np.isinf(confidence[b, j])
+            for s in range(cfg.denoising_steps):
+                state = np.where(fixed[s], final, cfg.mask_token_id).astype(np.int32)
+                want = np.asarray(reference.block(rcfg, model.params, before, jnp.asarray(state),
+                                                  pos)[0], np.float64)
+                after = fixed[s + 1] if s + 1 < cfg.denoising_steps else np.ones(width, bool)
+                for i in np.flatnonzero(after & ~fixed[s]):
+                    got = logits[b, j * width + i].astype(np.float64)
+                    check(np.isfinite(got).all(), "sdar logits: non-finite values")
+                    errs.append(np.linalg.norm(got - want[i]) / np.linalg.norm(want[i]))
+            _, kv = reference.block(rcfg, model.params, before, jnp.asarray(final), pos)
+            before = reference.committed(before, kv, pos)
+    median = float(np.median(errs))
+    check(median <= tol, f"sdar logits vs reference: median error {median:.3e} > {tol:.3e}")
+    held = session.cache_bytes()["shared"]
+    model.params = None
+    return {"layers": cfg.num_hidden_layers, "prefill_s": round(prefill_s, 3),
+            "decode3_first_s": round(decode_s, 3), "positions": session.positions.tolist(),
+            "logits_err_median": median, "logits_err_worst": float(max(errs)),
+            "cache_bytes": held}
+
+
 def st_fence():
     """ROADMAP S1: one chain of matmuls timed by block_until_ready and by a
     scalar readback.  If the two agree and both dwarf the enqueue-only
@@ -1118,6 +1192,7 @@ def main():
     stage("brumby_serve", st_brumby)
     stage("deepseek_serve", st_deepseek)
     stage("glm_drafter_serve", st_glm_drafter)
+    stage("sdar_block_diffusion", st_sdar_block_diffusion)
     stage("fence_timing", st_fence)
     if NDEV > 1:
         stage("multichip_schedules", st_multichip)

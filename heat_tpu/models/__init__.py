@@ -7,11 +7,12 @@ from .session import DecodeSession
 from .sambay import SambaY, SambaYConfig
 from .brumby import Brumby, BrumbyConfig
 from .deepseek import DeepSeek, DeepSeekConfig
+from .sdar import SDAR, SDARConfig
 
 __all__ = [
     "MLP",
     "ResNet", "ResNet18", "ResNet34", "ResNet50", "ResNet101", "ResNet152",
     "TransformerLM", "TransformerBlock", "MoEMlp",
     "SambaY", "SambaYConfig", "Brumby", "BrumbyConfig", "DeepSeek", "DeepSeekConfig",
-    "DecodeSession",
+    "SDAR", "SDARConfig", "DecodeSession",
 ]

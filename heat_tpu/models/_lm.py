@@ -1,10 +1,11 @@
 """What the served language models share below the session: the seeded
 parameter trees (a spec of ``(shape, init)`` leaves, made leaf by leaf on the
 device), the product in the weights' type with float32 accumulation, the
-gated-SiLU MLP, the embedding lookup, the greedy head and the rotary
-frequencies under YaRN.  A model (:mod:`~heat_tpu.models.sambay`,
-:mod:`~heat_tpu.models.brumby`, :mod:`~heat_tpu.models.deepseek`) keeps its
-own norms, mixers and programs.
+gated-SiLU MLP, the embedding lookup, the greedy head, the rotary frequencies
+under YaRN, and Qwen3's RMSNorm and rotary embedding over the whole head (of
+:mod:`~heat_tpu.models.brumby` and :mod:`~heat_tpu.models.sdar`).  A model
+(:mod:`~heat_tpu.models.sambay`, :mod:`~heat_tpu.models.deepseek`, ...) keeps
+its other norms, its mixers and its programs.
 """
 
 from __future__ import annotations
@@ -94,7 +95,32 @@ def greedy(h, table):
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
 
 
+def rms_norm(x, w, eps):
+    """RMSNorm in float32 times its gain (Qwen3's: the gain multiplies the
+    float32 normed value)."""
+    x = x.astype(_F32)
+    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * w.astype(_F32)
+
+
 # ---------------------------------------------------------------------- rotary
+
+def rope_frequencies(head_dim: int, theta: float) -> np.ndarray:
+    """``theta^(-2i / head_dim)`` for the ``head_dim / 2`` pairs, float32."""
+    return (float(theta) ** (-np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+            ).astype(np.float32)
+
+
+def rope_half(x, positions, theta: float):
+    """Rotary embedding over the whole head, lane ``i`` paired with lane ``i +
+    head_dim / 2`` (Qwen3's pairing).  ``x``: ``(..., seq, heads, head_dim)``;
+    ``positions``: ``(seq,)``, or ``(batch, seq)`` where each sequence has its
+    own."""
+    half = x.shape[-1] // 2
+    angle = positions.astype(_F32)[..., None] * rope_frequencies(x.shape[-1], theta)
+    cos, sin = jnp.cos(angle)[..., None, :], jnp.sin(angle)[..., None, :]
+    lo, hi = x[..., :half], x[..., half:]
+    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin], axis=-1)
+
 
 def yarn_frequencies(dim: int, theta: float, factor: float = 1.0, original: int = 4096,
                      beta_fast: float = 32.0, beta_slow: float = 1.0) -> np.ndarray:

@@ -42,7 +42,7 @@ from ..core import telemetry
 from ..ops.power_retention import (feature_blocks, retention_chunked, retention_step,
                                    state_rows)
 from ._lm import (dot as _dot, embed as _embed, gated_mlp as _gated_mlp, greedy as _greedy,
-                  init_tree, spec_size)
+                  init_tree, rms_norm as _rms_norm, rope_half, spec_size)
 from .session import DecodeSession, tree_bytes
 
 __all__ = ["Brumby", "BrumbyConfig"]
@@ -153,27 +153,6 @@ def init_params(cfg: BrumbyConfig, key, sharding=None) -> dict:
 
 # ---------------------------------------------------------------------- layers
 
-def _rms_norm(x, w, eps):
-    x = x.astype(_F32)
-    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * w.astype(_F32)
-
-
-def rope_frequencies(head_dim: int, theta: float) -> np.ndarray:
-    """``theta^(-2i / head_dim)`` for the ``head_dim / 2`` pairs, float32."""
-    return (float(theta) ** (-np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
-            ).astype(np.float32)
-
-
-def _rope(cfg, x, positions):
-    """Rotary embedding over the whole head, lane ``i`` paired with lane ``i +
-    head_dim / 2``.  ``x``: ``(batch, seq, heads, head_dim)``."""
-    half = cfg.head_dim // 2
-    angle = positions.astype(_F32)[:, None] * rope_frequencies(cfg.head_dim, cfg.rope_theta)
-    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
-    lo, hi = x[..., :half], x[..., half:]
-    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin], axis=-1)
-
-
 def _retention(cfg, p, x, pos0, S, z):
     """The mixer on the stream ``x`` of ``(batch, seq, d)`` whose first
     position is ``pos0``.  Returns ``(output, S, z)``."""
@@ -187,8 +166,8 @@ def _retention(cfg, p, x, pos0, S, z):
         q = qkv[..., :cfg.q_width].reshape(batch, seq, heads, hd)
         k = qkv[..., cfg.q_width:cfg.q_width + cfg.kv_width].reshape(batch, seq, kv_heads, hd)
         v = qkv[..., cfg.q_width + cfg.kv_width:].reshape(batch, seq, kv_heads, hd)
-        q = _rope(cfg, _rms_norm(q, mixer["q_norm"], cfg.rms_norm_eps), positions)
-        k = _rope(cfg, _rms_norm(k, mixer["k_norm"], cfg.rms_norm_eps), positions)
+        q = rope_half(_rms_norm(q, mixer["q_norm"], cfg.rms_norm_eps), positions, cfg.rope_theta)
+        k = rope_half(_rms_norm(k, mixer["k_norm"], cfg.rms_norm_eps), positions, cfg.rope_theta)
         q = q.reshape(batch, seq, kv_heads, cfg.group, hd)
         log_g = -jax.nn.softplus(-(_dot(u, mixer["w_g"]) + mixer["b_g"]))
         with jax.named_scope("ht.lm.retention_state"):

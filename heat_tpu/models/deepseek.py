@@ -76,7 +76,7 @@ from ..ops.latent_attention import (KEY_BLOCK, ROPE_PACK, index_scores_chunk, ke
 from ..ops.latent_stream import latent_stream_attention, stream_form
 from ..parallel.expert import held_experts_ffn
 from ._lm import (dot as _dot, embed as _embed, gated_mlp as _gated_mlp, greedy as _greedy,
-                  init_tree, spec_size, yarn_frequencies)
+                  init_tree, rms_norm as _rms_norm, spec_size, yarn_frequencies)
 from .session import DecodeSession, tree_bytes
 
 __all__ = ["DeepSeek", "DeepSeekConfig"]
@@ -288,11 +288,6 @@ def init_params(cfg: DeepSeekConfig, key, sharding=None) -> dict:
 
 
 # ---------------------------------------------------------------------- layers
-
-def _rms_norm(x, w, eps):
-    x = x.astype(_F32)
-    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * w.astype(_F32)
-
 
 def _layer_norm(x, w, b, eps):
     x = x.astype(_F32)

@@ -8,7 +8,11 @@ tokens back once, ``save()`` / ``rewind(snapshot)`` return to earlier
 positions.  A model whose step yields one token advances every sequence in
 lockstep; a model that drafts (``drafts`` 1: a decode step checks the pending
 token and a draft, and yields one token or two) advances each by what it
-accepted, and its prompts may differ in length.  What differs between models
+accepted, and its prompts may differ in length; a model that denoises blocks
+(``block_length`` B > 1: a decode step is a block of B positions, fixed over
+several passes) prefills each prompt's whole blocks, keeps the prompt's tail
+pending in the first block, and advances every sequence by whole blocks, each
+from its own position.  What differs between models
 is what the model supplies, the five ``serve_*`` methods and
 ``prefill_chunk``, the positions one of its prefill programs walks:
 
@@ -28,7 +32,10 @@ is what the model supplies, the five ``serve_*`` methods and
   ``lm.decode`` span's end event carries).  A model that drafts takes every
   sequence's position, ``lengths`` in ``serve_prefill``, a pending token that
   is the pair ``(token, draft)``, and returns as ``chosen`` the pair
-  ``(emitted, accepted)``;
+  ``(emitted, accepted)``; a model that denoises blocks takes every sequence's
+  position and ``lengths``, its pending token is the pending block ``(tokens,
+  fixed)``, ``steps`` counts blocks, and it returns as ``chosen`` the pair
+  ``(tokens, confidence)`` (and no logits from ``serve_prefill``);
 - ``serve_notes(session, steps) -> (notes, counts)``: the attributes its
   ``lm.decode`` span carries and what the call adds to the ``lm`` counters;
 - ``serve_bytes(shared, state) -> dict``: the bytes held, by kind.
@@ -72,8 +79,12 @@ __all__ = ["DecodeSession", "Snapshot"]
 # prefills that the attention kernel folded (from shapes); drafts_made and drafts_accepted the
 # drafts a drafting model's steps checked and those that stood (counted on the device), and
 # latent_rows_streamed the latent rows its full reads visited, layers x sessions x visible rows
-# summed over steps (counted on the device); cache_bytes is what the newest session allocated, by
-# kind
+# summed over steps (counted on the device); denoise_passes and commit_passes the passes a model
+# that denoises blocks ran (each once for the whole batch, as decode_steps, which counts its
+# blocks), tokens_unmasked the positions its denoising passes fixed (counted on the device), and
+# kv_rows_streamed the key/value rows its passes' stream read, layers x sessions x visible rows
+# summed over every pass, commits included (from the positions); cache_bytes is what the newest
+# session allocated, by kind
 _LM = telemetry.register_group(
     "lm",
     {"decode_steps": 0, "prefill_tokens": 0, "prefill_s": 0.0,
@@ -82,6 +93,7 @@ _LM = telemetry.register_group(
      "index_keys_scanned": 0, "latent_rows_read": 0, "selections_by_cut": 0,
      "expert_pairs": 0, "experts_hit": 0, "prefill_blocks_by_kernel": 0,
      "drafts_made": 0, "drafts_accepted": 0, "latent_rows_streamed": 0,
+     "denoise_passes": 0, "commit_passes": 0, "tokens_unmasked": 0, "kv_rows_streamed": 0,
      "cache_bytes": {"shared": 0, "window": 0, "state": 0}},
 )
 
@@ -120,8 +132,8 @@ def _restored(live, saved):
 class Snapshot(NamedTuple):
     """Saved positions of a :class:`DecodeSession`: the furthest position,
     the token waiting to be fed at each sequence's (a model that drafts: the
-    token and its draft), a copy of the constant-size state, and every
-    sequence's position."""
+    token and its draft; a model that denoises blocks: the pending block), a
+    copy of the constant-size state, and every sequence's position."""
 
     position: int
     token: Any
@@ -142,12 +154,14 @@ class DecodeSession:
         self.cfg = model.cfg
         self.batch = int(batch)
         self.drafts = int(getattr(model, "drafts", 0))
+        self.block = int(getattr(model, "block_length", 1))
         self.capacity, self._shared, self._state = model.serve_cache(self.batch, int(max_context))
         self.positions = np.zeros(self.batch, np.int64)
         self._token = None  # the token waiting to be fed at each position (and its draft)
         self.tokens = None  # the newest decode call's tokens, on the host
         self.emitted = None  # a drafting model's newest call: tokens emitted a sequence, on the host
         self.accepted = None  # and whether each step's draft stood, (batch, steps)
+        self.confidence = None  # a block model's newest call: each pass's confidences, on the host
         _LM["cache_bytes"].update(dict.fromkeys(_LM["cache_bytes"], 0), **self.cache_bytes())
 
     @property
@@ -172,18 +186,24 @@ class DecodeSession:
         """Append a prompt of ``(batch, n)`` token ids at the current position
         (one for every sequence).  Returns the logits of its last position,
         ``(batch, vocab)``; their argmax is the token the next :meth:`decode`
-        feeds first.  ``lengths`` (a model that drafts): sequence ``b``'s
-        prompt is ``tokens[b, :lengths[b]]``, and the logits are those of its
-        last position."""
+        feeds first.  ``lengths`` (a model that drafts, or that denoises
+        blocks): sequence ``b``'s prompt is ``tokens[b, :lengths[b]]``, and the
+        logits are those of its last position.  A model that denoises blocks
+        prefills each prompt's whole blocks and returns the pending first
+        block, ``(batch, block_length)`` token ids: the prompt's tail, then the
+        mask id."""
         ids = tokens.larray if isinstance(tokens, DNDarray) else jnp.asarray(tokens)
         ids = ids.astype(jnp.int32)
         if ids.ndim != 2 or ids.shape[0] != self.batch or ids.shape[1] < 1:
             raise ValueError(f"prefill takes (batch={self.batch}, n >= 1) token ids, got {ids.shape}")
         n = int(ids.shape[1])
+        if lengths is None and self.block > 1:
+            lengths = np.full(self.batch, n)
         if lengths is not None:
-            if not self.drafts:
+            if not self.drafts and self.block == 1:
                 raise ValueError("the sequences of a model whose step yields one token advance in "
-                                 "lockstep: prefill takes no lengths")
+                                 "lockstep: prefill takes no lengths (a model that drafts, or "
+                                 "that denoises blocks, does)")
             lengths = np.asarray(lengths, np.int64)
             if lengths.shape != (self.batch,) or lengths.min() < 1 or lengths.max() > n:
                 raise ValueError(f"lengths are (batch={self.batch},) numbers in 1 .. {n}")
@@ -191,7 +211,8 @@ class DecodeSession:
             raise ValueError("prefill appends at one position for every sequence")
         if self.position + n > self.capacity:
             raise ValueError(f"{self.position} + {n} positions pass the session's {self.capacity}")
-        advance = np.full(self.batch, n, np.int64) if lengths is None else lengths
+        advance = np.full(self.batch, n, np.int64) if lengths is None else (
+            lengths - lengths % self.block)
         started = time.perf_counter()
         with telemetry.span("lm.prefill", tokens=int(advance.sum()),
                             chunk=self.model.prefill_chunk) as span:
@@ -202,15 +223,19 @@ class DecodeSession:
             span.note(**notes)
             # the span ends when the first token is ready, as a serving loop
             # waits for it before it streams: the session's time to first token
+            # (a model that denoises blocks makes no token before its first
+            # block: when the prompt's keys and values are written)
             with telemetry.sync("lm.prefill"):
-                jax.block_until_ready(self._token)
+                jax.block_until_ready(self._token if logits is not None
+                                      else (self._token, self._shared))
             self.positions = self.positions + advance
         if telemetry.ledger_enabled():
             _LM["prefill_s"] += time.perf_counter() - started
         _LM["prefill_tokens"] += int(advance.sum())
         for name, count in counts.items():
             _LM[name] += count
-        return self._wrap(logits)
+        # the pending block is the session's own, which decode gives up: a copy
+        return self._wrap(jnp.copy(self._token[0]) if logits is None else logits)
 
     def decode(self, steps: int):
         """``steps`` greedy steps for every sequence.  Returns ``(tokens,
@@ -224,15 +249,23 @@ class DecodeSession:
         emitted tokens, then -1 (``session.emitted``: how many, read back with
         them, as ``session.accepted``, ``(batch, steps)``, whether each step's
         draft stood); ``logits`` ``(batch, steps, 2, vocab)`` are those of the
-        positions each step checked, the pending token's and the draft's."""
+        positions each step checked, the pending token's and the draft's.
+
+        A model that denoises blocks: ``steps`` blocks of B positions a
+        sequence.  ``tokens`` ``(batch, steps B)`` are each block's, -1 where
+        a prompt token sat in the first; ``logits`` ``(batch, steps B,
+        vocab)`` those of the pass that fixed each position (zero at a
+        prompt's); ``session.confidence`` ``(batch, steps, passes, B)`` each
+        pass's confidence at every position, ``-inf`` where it was already
+        fixed (read back with the tokens)."""
         steps = int(steps)
         if self._token is None:
             raise ValueError("decode needs a prompt: call prefill first")
-        reach = (1 + self.drafts) * steps
+        reach = (1 + self.drafts) * self.block * steps
         if self.position + reach > self.capacity:
             raise ValueError(f"{self.position} + {reach} positions pass the session's {self.capacity}")
         notes, counts = self.model.serve_notes(self, steps)
-        at = self.positions if self.drafts else self.position
+        at = self.positions if self.drafts or self.block > 1 else self.position
         with telemetry.span("lm.decode", **notes) as span:
             self._shared, self._state, self._token, chosen, logits, *counted = (
                 self.model.serve_decode(self._shared, self._state, self._token, at, steps))
@@ -245,6 +278,10 @@ class DecodeSession:
                 self.emitted = advance = steps + self.accepted.sum(axis=1)
                 chosen = chosen[0]
                 span.note(acceptance=counted["drafts_accepted"] / (self.batch * steps))
+            elif self.block > 1:
+                self.tokens, self.confidence = self.tokens
+                advance = self.block * steps
+                chosen = chosen[0]
             span.note(**counted)
         self.positions = self.positions + advance
         _LM["decode_steps"] += steps

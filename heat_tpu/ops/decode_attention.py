@@ -9,22 +9,28 @@ block of query rows) and both with float32 softmax and accumulation:
   - window`` under a sliding window).  Key slots may be in any order, so a
   rolling window kept as a ring buffer is read as it lies.  Prefill chunks and
   the window layers of a decode step go through it.
-* :func:`decode_attention` — one query position against a cache whose slot
-  ``t`` holds position ``t``, of which the first ``kv_len`` are visible: the
-  read that a decode step of a long generation spends its time in.  On a TPU it
+* :func:`decode_attention` — a few query rows against a cache whose slot
+  ``t`` holds position ``t``, of which the first ``kv_len`` are visible to
+  every row (one query position, or a block of positions that see each other
+  and all before them): the read that a decode step of a long generation
+  spends its time in.  ``kv_len`` is one number for the batch, or one a
+  sequence where each is at its own position.  On a TPU it
   is a Pallas kernel that streams the cache through VMEM once, in blocks of
   ``block`` keys: the whole blocks below ``kv_len`` through the pipeline, and
   the block ``kv_len`` falls into as a copy of its first ``block // 8`` keys
   where those hold all that is visible (through the pipeline too where more
   of it is: a finer cut cost more in copies than it saved in bytes);
-  nothing past that is fetched (:func:`keys_fetched` states the rule);
+  nothing past that is fetched, sequence by sequence (:func:`keys_fetched`
+  states the rule);
   elsewhere it is :func:`masked_attention` (``HEAT_TPU_PALLAS=interpret``
   runs the kernel on the CPU through the interpreter, as for the other
   kernels of this package).
 
 Shapes: ``q`` is ``(batch, G, rows, Dk)``, ``k`` ``(batch, G, T, Dk)``, ``v``
 ``(batch, G, T, Dv)``; the result is ``(batch, G, rows, Dv)`` in float32.
-Positions are shared by the batch: its sequences advance in lockstep.
+A query row's position is one for the batch (``q_pos`` of ``(rows,)``), or
+one a sequence (``(batch, rows)``; :func:`decode_attention`'s ``kv_len`` of
+``(batch,)``).
 """
 
 from __future__ import annotations
@@ -44,16 +50,18 @@ __all__ = ["masked_attention", "decode_attention", "keys_fetched"]
 # typed constants: a Python float inside a kernel becomes a float64 constant
 # where x64 is on (the CPU tests), which Mosaic does not take
 _NEG, _ZERO, _ONE = np.float32(-1e30), np.float32(0.0), np.float32(1.0)
-_ROWS = 16  # query rows are padded to one bfloat16 sublane tile
+_ROWS = 16  # query rows are padded to whole bfloat16 sublane tiles
 
 
 def masked_attention(q, k, v, q_pos, k_pos, *, scale, window=None, kv_len=None,
                      block: int = 1024):
     """``softmax(q k^T * scale + mask) v`` with the mask from positions.
 
-    ``q_pos``: ``(rows,)``, ``k_pos``: ``(T,)`` (a negative position marks an
-    empty slot).  ``kv_len``, where given, says that no slot at or past it is
-    visible, so the blocks beyond it are not read at all."""
+    ``q_pos``: ``(rows,)``, or ``(batch, rows)`` where each sequence has its
+    own; ``k_pos``: ``(T,)`` (a negative position marks an empty slot).
+    ``kv_len``, where given (one number, or one a sequence), says that no
+    slot at or past it is visible, so the blocks beyond it (beyond the
+    largest) are not read at all."""
     f32 = jnp.float32
     batch, groups, rows, _ = q.shape
     slots, dv = k.shape[2], v.shape[3]
@@ -63,6 +71,8 @@ def masked_attention(q, k, v, q_pos, k_pos, *, scale, window=None, kv_len=None,
     nblocks = slots // block
     q_pos = q_pos.astype(jnp.int32)
     k_pos = k_pos.astype(jnp.int32)
+    if q_pos.ndim == 2 and window is not None:
+        raise ValueError("a sliding window takes positions shared by the batch")
 
     def body(i, carry):
         m, l, acc = carry
@@ -70,7 +80,10 @@ def masked_attention(q, k, v, q_pos, k_pos, *, scale, window=None, kv_len=None,
         vb = jax.lax.dynamic_slice_in_dim(v, i * block, block, axis=2)
         kp = jax.lax.dynamic_slice_in_dim(k_pos, i * block, block)
         s = jnp.einsum("bgmd,bgtd->bgmt", q, kb, preferred_element_type=f32) * scale
-        seen = (kp[None, :] <= q_pos[:, None]) & (kp[None, :] >= 0)
+        if q_pos.ndim == 2:  # a position a sequence: (batch, 1, rows, keys)
+            seen = ((kp[None, None, :] <= q_pos[:, :, None]) & (kp[None, None, :] >= 0))[:, None]
+        else:
+            seen = (kp[None, :] <= q_pos[:, None]) & (kp[None, :] >= 0)
         if window is not None:
             seen &= kp[None, :] > q_pos[:, None] - window
         s = jnp.where(seen, s, _NEG)
@@ -90,7 +103,7 @@ def masked_attention(q, k, v, q_pos, k_pos, *, scale, window=None, kv_len=None,
     if nblocks == 1:
         _, l, acc = body(0, init)
     else:
-        trips = nblocks if kv_len is None else jnp.minimum(nblocks, -(-kv_len // block))
+        trips = nblocks if kv_len is None else jnp.minimum(nblocks, -(-jnp.max(kv_len) // block))
         _, l, acc = jax.lax.fori_loop(0, trips, body, init)
     return acc / jnp.where(l == 0.0, 1.0, l)[..., None]
 
@@ -113,9 +126,11 @@ def _tail_rule(nfull, rem, piece):
 
 
 def keys_fetched(kv_len: int, slots: int, block: int) -> int:
-    """Key slots the decode kernel fetches from a stream of ``slots`` of which
-    ``kv_len`` are visible: the pipeline's blocks and, where the last block
-    holds no more than a piece of visible keys, that piece."""
+    """Key slots the decode kernel fetches from one sequence's stream of
+    ``slots`` of which ``kv_len`` are visible: the pipeline's blocks and,
+    where the last block holds no more than a piece of visible keys, that
+    piece.  Each sequence by this rule at its own ``kv_len`` where they
+    differ (the kernel's plan is then one a sequence)."""
     block = min(int(block), int(slots))
     piece = _piece(block)
     npipe, copied = _tail_rule(*divmod(int(kv_len), block), piece)
@@ -152,9 +167,12 @@ def _stream_plan(kv_len, block, piece, steps):
 
 
 def _decode_kernel(plan_ref, q_ref, k_ref, v_ref, k_hbm, v_hbm, o_ref,
-                   m_ref, l_ref, acc_ref, k_tail, v_tail, sems, *, scale, piece):
+                   m_ref, l_ref, acc_ref, k_tail, v_tail, sems, *, scale, piece, width):
+    """``width``: the length of a sequence's plan where each has its own (the
+    plans lie one after another), 0 where the batch shares one."""
     b, g, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    tail0, copied, seen = plan_ref[0], plan_ref[1] > 0, plan_ref[4]
+    base = b * width if width else 0
+    tail0, copied, seen = plan_ref[base + 0], plan_ref[base + 1] > 0, plan_ref[base + 4]
 
     def tail_copies():
         rows = pl.ds(pl.multiple_of(tail0, piece), piece)
@@ -198,9 +216,9 @@ def _decode_kernel(plan_ref, q_ref, k_ref, v_ref, k_hbm, v_hbm, o_ref,
             for copy in tail_copies():
                 copy.start()
 
-    @pl.when((t == 0) | (t > plan_ref[2]))  # a step with a block to fold
+    @pl.when((t == 0) | (t > plan_ref[base + 2]))  # a step with a block to fold
     def _():
-        closing = t == plan_ref[3]
+        closing = t == plan_ref[base + 3]
 
         @pl.when(~closing)
         def _():
@@ -234,58 +252,72 @@ def _decode_pallas(q, k, v, kv_len, *, scale, block, interpret):
     if block % piece:
         raise ValueError(f"blocks of {block} keys do not divide into pieces of {piece}")
     steps = slots // block
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, _ROWS - rows), (0, 0)))
+    padded = -(-rows // _ROWS) * _ROWS
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, padded - rows), (0, 0)))
+    if jnp.ndim(kv_len):  # a plan a sequence, one after another
+        width = _PLAN + steps
+        plan = jax.vmap(lambda n: _stream_plan(n, block, piece, steps))(kv_len).reshape(-1)
 
-    def kv_block(b, g, t, plan_ref):
-        return b, g, plan_ref[_PLAN + t], 0
+        def kv_block(b, g, t, plan_ref):
+            return b, g, plan_ref[b * width + _PLAN + t], 0
+    else:
+        width, plan = 0, _stream_plan(kv_len, block, piece, steps)
+
+        def kv_block(b, g, t, plan_ref):
+            return b, g, plan_ref[_PLAN + t], 0
 
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, piece=piece),
+        functools.partial(_decode_kernel, scale=scale, piece=piece, width=width),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(batch, groups, steps),
             in_specs=[
-                pl.BlockSpec((None, None, _ROWS, dk), lambda b, g, t, n: (b, g, 0, 0)),
+                pl.BlockSpec((None, None, padded, dk), lambda b, g, t, n: (b, g, 0, 0)),
                 pl.BlockSpec((None, None, block, dk), kv_block),
                 pl.BlockSpec((None, None, block, dv), kv_block),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((None, None, _ROWS, dv), lambda b, g, t, n: (b, g, 0, 0)),
+            out_specs=pl.BlockSpec((None, None, padded, dv), lambda b, g, t, n: (b, g, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((_ROWS, 1), jnp.float32),
-                pltpu.VMEM((_ROWS, 1), jnp.float32),
-                pltpu.VMEM((_ROWS, dv), jnp.float32),
+                pltpu.VMEM((padded, 1), jnp.float32),
+                pltpu.VMEM((padded, 1), jnp.float32),
+                pltpu.VMEM((padded, dv), jnp.float32),
                 pltpu.VMEM((piece, dk), k.dtype),
                 pltpu.VMEM((piece, dv), v.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((batch, groups, _ROWS, dv), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((batch, groups, padded, dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         # the capacity, not the visible keys: kv_len is traced, and this is
         # the bound the scheduler plans with, not a measurement
         cost_estimate=pl.CostEstimate(
-            flops=2 * batch * groups * _ROWS * slots * (dk + dv),
+            flops=2 * batch * groups * padded * slots * (dk + dv),
             bytes_accessed=batch * groups * slots * (dk + dv) * k.dtype.itemsize,
-            transcendentals=batch * groups * _ROWS * slots,
+            transcendentals=batch * groups * padded * slots,
         ),
         interpret=interpret,
         name="ht_decode_attention",
-    )(_stream_plan(kv_len, block, piece, steps), q, k, v, k, v)
+    )(plan, q, k, v, k, v)
     return out[:, :, :rows]
 
 
 def decode_attention(q, k, v, kv_len, *, scale, block: int = 2048):
-    """One query position (``rows`` <= 16 query rows a group) against the
-    first ``kv_len`` slots of a position-ordered cache."""
+    """``rows`` query rows a group against the first ``kv_len`` slots of a
+    position-ordered cache: one number for the batch, or an int32 ``(batch,)``
+    (scalar-prefetched; each sequence's blocks past its own are neither
+    fetched nor folded)."""
     how = _mode()
     if how == "off":
         rows, slots = q.shape[2], k.shape[2]
-        return masked_attention(
-            q, k, v, jnp.broadcast_to(kv_len - 1, (rows,)), jnp.arange(slots),
-            scale=scale, kv_len=kv_len, block=block)
+        if jnp.ndim(kv_len):
+            q_pos = jnp.broadcast_to((kv_len - 1)[:, None], (q.shape[0], rows))
+        else:
+            q_pos = jnp.broadcast_to(kv_len - 1, (rows,))
+        return masked_attention(q, k, v, q_pos, jnp.arange(slots), scale=scale, kv_len=kv_len,
+                                block=block)
     return _decode_pallas(q, k, v, kv_len, scale=scale, block=block,
                           interpret=(how == "interpret"))

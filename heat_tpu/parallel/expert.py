@@ -27,10 +27,12 @@ shard, the two paths agree exactly only while nothing is dropped
 
 **Which entry point drops tokens.**  :func:`moe_ffn` (softmax top-k, a
 static per-expert capacity, either every expert here or an ``all_to_all``)
-drops the choices that pass an expert's capacity.  :func:`held_experts_ffn`
+drops the choices that pass an expert's capacity.  :func:`held_experts_sum`
 never drops one: it serves a model whose experts are shared between chips,
-routes every token over *all* the experts (sigmoid scores, a balancing bias
-in the choice only, groups of experts, :func:`sigmoid_group_routing`), is
+takes a choice of every token over *all* the experts (sigmoid scores, a
+balancing bias in the choice only, groups of experts,
+:func:`sigmoid_group_routing`, which :func:`held_experts_ffn` routes by; or a
+softmax over all of them and its top k, :func:`softmax_topk_routing`), is
 told which consecutive experts this chip holds, and returns their part of
 the routed sum.  It runs no exchange and stands in for none: what the other
 chips' experts would add is theirs to add.
@@ -49,7 +51,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .collectives import all_to_all, axis_size, psum, shard_map_unchecked
 
 __all__ = ["top_k_routing", "moe_ffn", "expert_capacity", "held_experts_ffn",
-           "sigmoid_group_routing"]
+           "held_experts_sum", "sigmoid_group_routing", "softmax_topk_routing"]
 
 
 def expert_capacity(
@@ -66,6 +68,14 @@ def expert_capacity(
     return max(cap, 1)
 
 
+def _softmax_top_k(logits, k: int):
+    """``softmax(logits)`` in float32 over the experts, its ``k`` largest and
+    their weights divided by their sum: ``(probs, weights, chosen)``."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, chosen = jax.lax.top_k(probs, k)
+    return probs, weights / jnp.sum(weights, axis=-1, keepdims=True), chosen
+
+
 def _route(gate_logits: jax.Array, k: int, capacity: int):
     """Top-k token→expert assignment with capacity-limited slot positions.
 
@@ -78,9 +88,7 @@ def _route(gate_logits: jax.Array, k: int, capacity: int):
     size, since routing happens on each shard's local tokens.
     """
     t, num_experts = gate_logits.shape
-    probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-    top_w, top_idx = jax.lax.top_k(probs, k)  # (t, k)
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    probs, top_w, top_idx = _softmax_top_k(gate_logits, k)  # (t, E), (t, k), (t, k)
 
     # position of each (token, choice) within its expert's queue; choices
     # are ranked token-major then slot-major so priority is deterministic
@@ -403,6 +411,23 @@ def sigmoid_group_routing(h, router, bias, *, top_k: int, n_group: int, topk_gro
     return weights, chosen.astype(jnp.int32)
 
 
+def softmax_topk_routing(h, router, *, top_k: int):
+    """Route ``h`` of ``(tokens, d)`` over all ``E`` experts of ``router``
+    ``(d, E)``: ``p = softmax(h router)`` over the ``E``, the ``top_k``
+    largest chosen, their weights ``p_e`` divided by the chosen ones' sum
+    (Qwen3-MoE's ``norm_topk_prob``; :func:`moe_ffn`'s choice too, without
+    its capacity).  The product and the
+    softmax are float32, the product at ``highest``, as
+    :func:`sigmoid_group_routing`'s.
+
+    Returns ``(weights, chosen)``: float32 ``(tokens, top_k)`` and int32
+    ``(tokens, top_k)`` expert numbers."""
+    _, weights, chosen = _softmax_top_k(
+        jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST), top_k)
+    return weights, chosen.astype(jnp.int32)
+
+
 def _dot(x, w):
     return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
 
@@ -458,10 +483,23 @@ def _grouped(x, weights, local, mine, experts):
 
 def held_experts_ffn(x, router, experts, *, held, top_k: int, n_group: int = 1,
                      topk_group: int = 1, scale: float = 1.0, bias=None):
-    """The held experts' part of a routed gated-SiLU layer: ``sum over e
-    chosen and held of g_e * Expert_e(x)`` for every token of ``x`` ``(tokens,
-    d)``, float32.  ``router`` ``(d, E)`` is over all ``E`` experts
-    (:func:`sigmoid_group_routing`); ``experts`` holds this chip's, ``w_gate``
+    """The held experts' part of a routed gated-SiLU layer whose router is
+    :func:`sigmoid_group_routing` (``router`` ``(d, E)`` over all ``E``
+    experts): :func:`held_experts_sum` of its choice.
+
+    Returns ``(y, counts)`` as :func:`held_experts_sum`."""
+    if bias is None:
+        bias = jnp.zeros((router.shape[1],), jnp.float32)
+    weights, chosen = sigmoid_group_routing(x, router, bias, top_k=top_k, n_group=n_group,
+                                            topk_group=topk_group, scale=scale)
+    return held_experts_sum(x, weights, chosen, experts, held=held)
+
+
+def held_experts_sum(x, weights, chosen, experts, *, held):
+    """``sum over e chosen and held of g_e * Expert_e(x)`` for every token of
+    ``x`` ``(tokens, d)``, float32, from a router's ``(weights, chosen)`` of
+    ``(tokens, top_k)`` (:func:`sigmoid_group_routing`,
+    :func:`softmax_topk_routing`).  ``experts`` holds this chip's, ``w_gate``
     and ``w_up`` of ``(count, d, f)`` and ``w_down`` of ``(count, f, d)``;
     ``held = (first, count)`` are their numbers among the ``E``.
 
@@ -476,10 +514,6 @@ def held_experts_ffn(x, router, experts, *, held, top_k: int, n_group: int = 1,
     token-expert pairs computed here and the held experts that received a
     token."""
     first, count = held
-    if bias is None:
-        bias = jnp.zeros((router.shape[1],), jnp.float32)
-    weights, chosen = sigmoid_group_routing(x, router, bias, top_k=top_k, n_group=n_group,
-                                            topk_group=topk_group, scale=scale)
     local = chosen - first
     mine = (local >= 0) & (local < count)
     lands = mine[:, :, None] & (local[:, :, None] == jnp.arange(count))    # (tokens, top_k, count)
