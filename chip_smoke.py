@@ -668,6 +668,27 @@ def st_kernels():
     check(np.array_equal(np.sort(got, axis=-1), want), "sparse_cut vs lax.top_k: another set")
     check(all((np.diff(r[r >= 0]) > 0).all() for r in got), "sparse_cut: not in slot order")
     info["sparse_cut"] = [round(first, 3), round(steady, 5), 0.0]
+
+    # the read of the chosen rows at the same shape: the kernel against the jax.numpy gather,
+    # over the slots just chosen (one row of them short, so some are -1)
+    rank, rope, heads = (512, 64, 128) if not REHEARSAL else (32, 8, 4)
+    form = latent_attention.read_form(batch, capacity, k, rank, rope)
+    check(form == "gather_kernel", f"sparse_read form {form!r}")
+    latent = jnp.asarray(rng.standard_normal((batch, capacity, rank)), jnp.bfloat16)
+    turned = jnp.asarray(rng.standard_normal((batch, capacity // 2, 2 * rope)), jnp.bfloat16)
+    q_lat = jnp.asarray(rng.standard_normal((batch, heads, rank)) * 0.05, jnp.float32)
+    q_pe = jnp.asarray(rng.standard_normal((batch, heads, rope)) * 0.05, jnp.float32)
+    chosen = jnp.asarray(got)
+    fused = jax.jit(lambda *a: latent_attention.latent_decode_attention(*a, 0.1))
+    got, first, steady = first_and_steady(lambda: fused(q_lat, q_pe, latent, turned, chosen))
+    os.environ["HEAT_TPU_PALLAS"] = "off"
+    try:
+        want = jax.jit(lambda *a: latent_attention.latent_decode_attention(*a, 0.1))(
+            q_lat, q_pe, latent, turned, chosen)
+    finally:
+        os.environ["HEAT_TPU_PALLAS"] = KERNEL_MODE
+    err = close(got, want, 1e-5, "sparse_read kernel vs the jax.numpy gather")
+    info["sparse_read"] = [round(first, 3), round(steady, 5), err]
     return {"kernel": "[first_s, steady_s, max_err]", **info}
 
 
@@ -755,6 +776,10 @@ def st_deepseek():
     session = model.session(2, length + 8)
     form = latent_attention.selection_form(2, session.capacity, cfg.index_topk)
     check(form == "cut_kernel", f"the selection of a decode step is {form!r}, not the kernel")
+    form = latent_attention.read_form(2, session.capacity, cfg.index_topk, cfg.kv_lora_rank,
+                                      cfg.qk_rope_head_dim, jnp.dtype(cfg.dtype).itemsize,
+                                      cfg.num_attention_heads)
+    check(form == "gather_kernel", f"the read of a decode step is {form!r}, not the kernel")
     (first, saved), prefill_s = clocked(
         lambda: (session.prefill(tokens), session.save()))
     first_token = np.asarray(jnp.argmax(first.larray, -1))

@@ -71,8 +71,8 @@ import numpy as np
 from ..core import telemetry
 from ..ops.latent_attention import (KEY_BLOCK, ROPE_PACK, index_scores_chunk, key_blocks,
                                     kth_largest, latent_decode_attention,
-                                    latent_prefill_attention, prefill_form, selection_form,
-                                    sparse_select)
+                                    latent_prefill_attention, prefill_form, read_form,
+                                    selection_form, sparse_select)
 from ..ops.latent_stream import latent_stream_attention, stream_form
 from ..parallel.expert import held_experts_ffn
 from ._lm import (dot as _dot, embed as _embed, gated_mlp as _gated_mlp, greedy as _greedy,
@@ -863,12 +863,16 @@ class DeepSeek:
         seen = [session.position + j + 1 for j in range(steps)]
         each = session.batch * cfg.num_hidden_layers
         select = selection_form(session.batch, session.capacity, cfg.index_topk)
-        notes.update(select=select, selected=cfg.index_topk,
+        read = read_form(session.batch, session.capacity, min(cfg.index_topk, session.capacity),
+                         cfg.kv_lora_rank, cfg.qk_rope_head_dim, itemsize,
+                         cfg.num_attention_heads)
+        notes.update(select=select, read=read, selected=cfg.index_topk,
                      index_bytes=cfg.index_head_dim * itemsize)
+        layer_steps = steps * cfg.num_hidden_layers
         return notes, {"index_keys_scanned": each * sum(seen),
                        "latent_rows_read": each * sum(min(cfg.index_topk, s) for s in seen),
-                       "selections_by_cut": (steps * cfg.num_hidden_layers
-                                             if select.startswith("cut") else 0)}
+                       "selections_by_cut": layer_steps if select.startswith("cut") else 0,
+                       "latent_reads_by_kernel": layer_steps if read == "gather_kernel" else 0}
 
     def serve_decode(self, shared, state, token, position, steps: int):
         """Greedy steps; a model that drafts takes each session's position and

@@ -73,7 +73,8 @@ __all__ = ["DecodeSession", "Snapshot"]
 # state_bytes_copied what save and rewind copied; index_keys_scanned and latent_rows_read are the
 # index keys that a sparse selection scored and the latent rows its attention then read (from
 # shapes), selections_by_cut the layer-steps whose selection was a cut and a compaction and not
-# every visible slot, expert_pairs and experts_hit the token-expert pairs computed on the experts
+# every visible slot, latent_reads_by_kernel the layer-steps whose chosen rows the read's kernel
+# fetched (from shapes), expert_pairs and experts_hit the token-expert pairs computed on the experts
 # held here and the held experts that received a token, summed over a call's steps and layers
 # (counted on the device); prefill_blocks_by_kernel the layers x key blocks of a sparse model's
 # prefills that the attention kernel folded (from shapes); drafts_made and drafts_accepted the
@@ -91,6 +92,7 @@ _LM = telemetry.register_group(
      "cache_keys_visible": 0, "cache_keys_fetched": 0,
      "state_bytes_stepped": 0, "state_bytes_copied": 0,
      "index_keys_scanned": 0, "latent_rows_read": 0, "selections_by_cut": 0,
+     "latent_reads_by_kernel": 0,
      "expert_pairs": 0, "experts_hit": 0, "prefill_blocks_by_kernel": 0,
      "drafts_made": 0, "drafts_accepted": 0, "latent_rows_streamed": 0,
      "denoise_passes": 0, "commit_passes": 0, "tokens_unmasked": 0, "kv_rows_streamed": 0,

@@ -35,17 +35,26 @@ one Pallas kernel, ``ht_sparse_prefill_attn``, which keeps a block's logits,
 mask and weights in VMEM (:func:`prefill_form` says where it is taken).
 
 Products take their operands in the caches' type and accumulate in float32.
-The scan, the gather and the prefill's scores and cut are ``jax.numpy`` (XLA
-fuses the scan into one pass over the index keys at 91% of its bytes' floor).  The selection
-behind the scan is *cut, membership, compaction* and no sort
+The scan and the prefill's scores and cut are ``jax.numpy`` (XLA fuses the
+scan into one pass over the index keys at 91% of its bytes' floor).  The
+selection behind the scan is *cut, membership, compaction* and no sort
 (:func:`largest_slots`): on a TPU one Pallas kernel, ``ht_sparse_cut``, entered
 by :func:`~heat_tpu.ops._pallas_common.mode` alone, as
 ``ops/decode_attention.py`` and ``ops/power_retention.py`` are, and by what the
 shapes show (:func:`selection_form`); elsewhere the same three parts as
 ``jax.numpy``.  There is no classic body that could win somewhere and no
 autotune arm: XLA's ``top_k`` at a decode cell's shapes is a stable sort of
-every score, ten times the kernel's time (PERF.md section 6, PRs 34 and 35,
-which also has what XLA makes of the row gather).
+every score, ten times the kernel's time (PERF.md section 6).
+
+The read of the chosen rows is, on a TPU, one Pallas kernel too,
+``ht_sparse_latent_read``, entered the same way (:func:`read_form`), in place
+of XLA's two row gathers.  A kernel's copy takes whole tiles of a cache's
+layout, eight rows, so each chosen slot's tile of latent rows is copied (a
+chunk's copies all in flight at once, the next chunk's started before this
+one's rows are picked) and the slot's own row is picked from it in VMEM; a
+session's whole rope cache (4.2 MB at the published widths) is one copy, from
+which each slot's rope row is picked.  The logits, the softmax and the value
+sum follow in VMEM.  Elsewhere the rows are gathered by ``jax.numpy``.
 """
 
 from __future__ import annotations
@@ -63,7 +72,7 @@ from ._pallas_common import mode as _mode
 
 __all__ = ["KEY_BLOCK", "ROPE_PACK", "index_scores", "index_scores_chunk", "key_blocks",
            "kth_largest", "largest_slots", "latent_decode_attention", "latent_prefill_attention",
-           "prefill_form", "rope_rows", "selection_form", "sparse_select"]
+           "prefill_form", "read_form", "rope_rows", "selection_form", "sparse_select"]
 
 _F32 = jnp.float32
 _NEG = -jnp.inf
@@ -352,14 +361,293 @@ def sparse_select(q_idx, w, k_idx_cache, kv_len, k: int):
     return largest_slots(index_scores(q_idx, w, k_idx_cache, kv_len), k)
 
 
+# ---- the read of the chosen rows as one kernel: the rows fetched by copies, the math in VMEM
+
+# rows of a cache that one copy fetches: the caches lie in tiles of eight rows on a TPU, and a
+# kernel's copy takes whole tiles, so a chosen slot's tile is copied and its row picked in VMEM
+_TILE_ROWS = 8
+# chosen slots whose tiles one of the two buffers holds, and the logits' columns a product takes
+_READ_CHUNK = 128
+_READ_COLUMNS = 512
+# pairs of rows a step of the pick loop moves: its scalar work is what bounds it
+_PICK_UNROLL = 8
+# the VMEM every kernel has without asking, which this one keeps to: the decode program's
+# fusions keep theirs (PERF.md section 6)
+_VMEM_READ = 16 << 20
+_ROPE_SHIFT = ROPE_PACK.bit_length() - 1
+
+
+def _shr(x, bits: int):
+    """``x // 2**bits`` of a nonnegative int32, as one shift."""
+    return jax.lax.shift_right_logical(x, np.int32(bits))
+
+
+def _read_plan(capacity: int, k: int, heads: int, rank: int, width: int, itemsize: int):
+    """``(chosen slots padded to whole chunks, a chunk's slots, VMEM bytes)``
+    of :func:`_read_pallas` at these shapes."""
+    chunk = min(_READ_CHUNK, -(-k // 8) * 8)
+    places = -(-k // chunk) * chunk
+    columns = min(_READ_COLUMNS, places)
+    held = ((2 * chunk * _TILE_ROWS * rank                         # the tiles' two buffers
+             + capacity // ROPE_PACK * width                       # a session's rope rows
+             + places * (rank + width)) * itemsize                 # the picked rows
+            + places * heads * 4                                   # the logits
+            + 2 * (heads * (rank + ROPE_PACK * width) * itemsize   # the queries, two buffers
+                   + places * 4 + heads * rank * 4)                # the slots, the output
+            + columns * heads * (2 + 3 * 4))                       # a product's temporaries
+    return places, chunk, held
+
+
+def _pick_rows(word_of, rows, first, count: int, pack: int):
+    """Row ``first + i`` of ``rows`` (``uint32`` ``(places / pack, width)``,
+    ``pack`` rows of the cache's type a word) for ``i < count``:
+    ``word_of(i)`` is ``(the 32-bit row that holds slot i's row, which half
+    of each word it is)``; a word of 16-bit rows holds an even row in its low
+    half and the next row in its high half."""
+    if pack == 1:
+        def one_row(i, carry):
+            rows[pl.ds(first + i, 1), :] = word_of(i)[0]
+            return carry
+
+        jax.lax.fori_loop(np.int32(0), np.int32(count), one_row, np.int32(0))
+        return
+
+    unroll = next(u for u in (_PICK_UNROLL, 4, 2, 1) if count // 2 % u == 0)
+
+    def some_pairs(n, carry):
+        base = pl.multiple_of(_shr(first, 1) + n * np.int32(unroll), unroll)
+        for u in range(unroll):
+            i = (n * np.int32(unroll) + u) * 2
+            (low, even), (high, odd) = word_of(i), word_of(i + 1)
+            low = low >> (even << 4).astype(jnp.uint32)
+            high = high << ((odd ^ 1) << 4).astype(jnp.uint32)
+            low, high = low & np.uint32(0xFFFF), high & np.uint32(0xFFFF0000)
+            rows[pl.ds(base + u, 1), :] = low | high
+        return carry
+
+    jax.lax.fori_loop(np.int32(0), np.int32(count // 2 // unroll), some_pairs, np.int32(0))
+
+
+def _read_kernel(slot_ref, q_ref, qpe_ref, chosen_ref, latent_hbm, rope_hbm, out_ref,
+                 tiles, rope_all, rows, rope_rows, logit_ref, sems, *, chunk, scale):
+    """One session's read: grid ``(sessions,)``, in order.  ``slot_ref``
+    (scalar-prefetched) ``(batch * places,)`` the chosen slots clamped to the
+    capacity; ``chosen_ref`` ``(1, places)`` the same with ``-1`` where
+    nothing is chosen; ``q_ref`` ``(heads, rank)``, ``qpe_ref`` ``(ROPE_PACK
+    * heads, ROPE_PACK * rope)`` (copy ``p`` of the rotary part in the lanes
+    of a rope row's position ``p``); ``latent_hbm`` ``(batch, capacity / 8,
+    8, rank)`` in HBM, ``rope_hbm`` the rope cache in HBM.
+
+    The copies of a chunk's tiles, one a chosen slot, are all started into
+    one of the two buffers ``tiles`` before the other buffer's rows are
+    picked; a session's last chunk starts the next session's first before
+    the math, and the next session's whole rope cache is copied into
+    ``rope_all`` once this session's rope rows are picked."""
+    b = pl.program_id(0)
+    batch = pl.num_programs(0)
+    heads, rank = q_ref.shape
+    places = chosen_ref.shape[1]
+    chunks = places // chunk
+    dtype = q_ref.dtype
+    pack = 4 // dtype.itemsize
+
+    def slot(s, j):
+        return slot_ref[s * np.int32(places) + j]
+
+    def start(s, c, buffer):
+        """the copies of chunk ``c`` of session ``s``'s tiles into buffer ``buffer``"""
+        def eight(n, carry):
+            for u in range(8):
+                i = n * np.int32(8) + u
+                tile = _shr(slot(s, c * np.int32(chunk) + i), 3)
+                pltpu.make_async_copy(latent_hbm.at[s, tile], tiles.at[buffer, i],
+                                      sems.at[buffer]).start()
+            return carry
+
+        jax.lax.fori_loop(np.int32(0), np.int32(chunk // 8), eight, np.int32(0))
+
+    def rope_copy(s):
+        return pltpu.make_async_copy(rope_hbm.at[s], rope_all, sems.at[2])
+
+    @pl.when(b == 0)
+    def _():
+        rope_copy(0).start()
+        start(0, np.int32(0), 0)
+
+    tile_words = tiles.bitcast(jnp.uint32)            # (2, chunk, 8 / pack, rank)
+    rope_words = rope_all.bitcast(jnp.uint32)         # (capacity / ROPE_PACK / pack, width)
+
+    def one_chunk(c, carry):
+        buffer = (b * np.int32(chunks) + c) % 2
+
+        @pl.when(c + 1 < chunks)
+        def _():
+            start(b, c + 1, 1 - buffer)
+
+        @pl.when((c + 1 == chunks) & (b + 1 < batch))
+        def _():
+            start(b + 1, np.int32(0), 1 - buffer)
+
+        pltpu.make_async_copy(tiles.at[buffer], tiles.at[buffer], sems.at[buffer]).wait()
+        first = c * np.int32(chunk)
+
+        def latent_word(i):
+            at = slot(b, first + i)
+            word = _shr(at, pack - 1) & np.int32(_TILE_ROWS // pack - 1)
+            return tile_words[buffer, i, pl.ds(word, 1), :], at & 1
+
+        def rope_word(i):
+            row = _shr(slot(b, first + i), _ROPE_SHIFT)
+            return rope_words[pl.ds(_shr(row, pack - 1), 1), :], row & 1
+
+        _pick_rows(latent_word, rows, first, chunk, pack)
+
+        @pl.when(c == 0)
+        def _():
+            rope_copy(b).wait()
+
+        _pick_rows(rope_word, rope_rows, first, chunk, pack)
+        return carry
+
+    jax.lax.fori_loop(np.int32(0), np.int32(chunks), one_chunk, np.int32(0))
+
+    @pl.when(b + 1 < batch)
+    def _():
+        rope_copy(b + 1).start()
+
+    # the math of the jax.numpy form, a block of columns at a time
+    columns = min(_READ_COLUMNS, places)
+    q, qpe = q_ref[...], qpe_ref[...]
+
+    def picked(ref, j):
+        return pltpu.bitcast(ref[j * columns // pack:(j + 1) * columns // pack, :], dtype)
+
+    top = jnp.full((heads, 1), _NEG_F32, _F32)
+    for j in range(places // columns):
+        at = slice(j * columns, (j + 1) * columns)
+        logit = jax.lax.dot_general(q, picked(rows, j), (((1,), (1,)), ((), ())),
+                                    preferred_element_type=_F32)
+        # the rotary logits against both positions of each slot's rope row, the slot's own kept
+        pair = jax.lax.dot_general(qpe, picked(rope_rows, j), (((1,), (1,)), ((), ())),
+                                   preferred_element_type=_F32)
+        chosen = jnp.broadcast_to(chosen_ref[:, at], (heads, columns))
+        rope_logit = pair[:heads]
+        for p in range(1, ROPE_PACK):
+            rope_logit = jnp.where(chosen % ROPE_PACK == p, pair[p * heads:(p + 1) * heads],
+                                   rope_logit)
+        logit = jnp.where(chosen >= 0, (logit + rope_logit) * np.float32(scale), _NEG_F32)
+        logit_ref[:, at] = logit
+        top = jnp.maximum(top, jnp.max(logit, axis=1, keepdims=True))
+    total = jnp.zeros((heads, 1), _F32)
+    for j in range(places // columns):
+        at = slice(j * columns, (j + 1) * columns)
+        weight = jnp.exp(logit_ref[:, at] - top)
+        logit_ref[:, at] = weight
+        total = total + jnp.sum(weight, axis=1, keepdims=True)
+    acc = jnp.zeros((heads, rank), _F32)
+    for j in range(places // columns):
+        at = slice(j * columns, (j + 1) * columns)
+        prob = (logit_ref[:, at] / total).astype(dtype)
+        acc = acc + jnp.dot(prob, picked(rows, j), preferred_element_type=_F32)
+    out_ref[...] = acc
+
+
+def _read_pallas(q_lat, q_pe, latent_cache, rope_cache, chosen, scale, *, interpret: bool):
+    batch, heads, rank = q_lat.shape
+    capacity, rope = latent_cache.shape[1], q_pe.shape[-1]
+    dtype = latent_cache.dtype
+    pack = 4 // dtype.itemsize
+    width = ROPE_PACK * rope
+    places, chunk, _ = _read_plan(capacity, chosen.shape[1], heads, rank, width, dtype.itemsize)
+    chosen = jnp.pad(chosen.astype(jnp.int32), ((0, 0), (0, places - chosen.shape[1])),
+                     constant_values=-1)
+    q = q_lat.astype(dtype)
+    q_pe = q_pe.astype(dtype)
+    q_pe = jnp.concatenate([jnp.pad(q_pe, ((0, 0), (0, 0), (p * rope, width - (p + 1) * rope)))
+                            for p in range(ROPE_PACK)], axis=1)
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_read_kernel, chunk=chunk, scale=float(scale)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch,),
+            in_specs=[
+                pl.BlockSpec((None, heads, rank), lambda b, s: (b, 0, 0)),
+                pl.BlockSpec((None, ROPE_PACK * heads, width), lambda b, s: (b, 0, 0)),
+                pl.BlockSpec((None, 1, places), lambda b, s: (b, 0, 0)),
+                any_space, any_space,
+            ],
+            out_specs=pl.BlockSpec((None, heads, rank), lambda b, s: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, chunk, _TILE_ROWS, rank), dtype),
+                pltpu.VMEM((capacity // ROPE_PACK, width), dtype),
+                pltpu.VMEM((places // pack, rank), jnp.uint32),
+                pltpu.VMEM((places // pack, width), jnp.uint32),
+                pltpu.VMEM((heads, places), _F32),
+                pltpu.SemaphoreType.DMA((3,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((batch, heads, rank), _F32),
+        # the slots are clamped to the capacity below: every copy and pick lies in bounds
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                             disable_bounds_checks=True),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * batch * heads * places * (2 * rank + ROPE_PACK * width),
+            transcendentals=batch * heads * places,
+            bytes_accessed=batch * (places * _TILE_ROWS * rank * dtype.itemsize
+                                    + capacity // ROPE_PACK * width * dtype.itemsize
+                                    + heads * (rank + ROPE_PACK * width) * dtype.itemsize
+                                    + 4 * heads * rank)),
+        interpret=interpret,
+        name="ht_sparse_latent_read",
+    )(jnp.clip(chosen, 0, capacity - 1).reshape(-1), q, q_pe, chosen[:, None, :],
+      latent_cache.reshape(batch, capacity // _TILE_ROWS, _TILE_ROWS, rank), rope_cache)
+
+
+def read_form(batch: int, capacity: int, k: int, rank: int, rope: int, itemsize: int = 2,
+              heads: int = 128) -> str:
+    """Which form :func:`latent_decode_attention` takes at these shapes (the
+    caches' ``itemsize``, the queries' ``heads``): ``gather_kernel`` (the Pallas kernel
+    ``ht_sparse_latent_read``) where the Pallas mode is ``tpu`` or
+    ``interpret``, fewer slots are chosen than the capacity holds, the
+    capacity is whole tiles of rows and of rope rows, the caches' type 16 or
+    32 bits and, on a TPU, the latent row is whole lane tiles, a rope row one
+    lane tile and the kernel's buffers within the VMEM every kernel has; else
+    ``gather_jnp`` (the rows gathered by ``jax.numpy``)."""
+    del batch
+    how = _mode()
+    if (how not in ("tpu", "interpret") or k >= capacity or itemsize not in (2, 4)
+            or capacity % (ROPE_PACK * _TILE_ROWS)):
+        return "gather_jnp"
+    if how == "interpret":
+        return "gather_kernel"
+    width = ROPE_PACK * rope
+    *_, held = _read_plan(capacity, k, heads, rank, width, itemsize)
+    if rank % LANE or width != LANE or held > _VMEM_READ:
+        return "gather_jnp"
+    return "gather_kernel"
+
+
 def latent_decode_attention(q_lat, q_pe, latent_cache, rope_cache, chosen, scale: float):
     """Attention of one query a session over chosen rows of the latent cache,
     in the latent space.  ``q_lat`` ``(batch, heads, rank)`` (the no-position
     part of the query with ``W_uk`` folded in), ``q_pe`` ``(batch, heads,
     rope)``, ``latent_cache`` ``(batch, capacity, rank)``, ``rope_cache``
     ``(batch, capacity / ROPE_PACK, ROPE_PACK * rope)``, ``chosen`` ``(batch,
-    k)`` as :func:`sparse_select` gives them.  Returns float32 ``(batch, heads,
-    rank)``: the softmax-weighted sum of the chosen ``c_kv``."""
+    k)`` as :func:`sparse_select` gives them (any order).  Returns float32
+    ``(batch, heads, rank)``: the softmax-weighted sum of the chosen ``c_kv``.
+
+    The queries and products take the cache's type; the logits ``(q_lat .
+    c_kv + q_pe . k_pe) * scale`` and the softmax are float32, ``-1`` slots
+    masked to ``-inf``; the weights are rounded to the cache's type for the
+    value sum, which accumulates in float32.  On a TPU it is the kernel
+    ``ht_sparse_latent_read`` where :func:`read_form` says so, else the rows
+    are gathered by ``jax.numpy``."""
+    batch, capacity, rank = latent_cache.shape
+    if read_form(batch, capacity, chosen.shape[1], rank, q_pe.shape[-1],
+                 latent_cache.dtype.itemsize, q_lat.shape[1]) == "gather_kernel":
+        return _read_pallas(q_lat, q_pe, latent_cache, rope_cache, chosen, scale,
+                            interpret=(_mode() == "interpret"))
     dtype = latent_cache.dtype
     slots = jnp.maximum(chosen, 0)
     rows = jnp.take_along_axis(latent_cache, slots[:, :, None], axis=1)

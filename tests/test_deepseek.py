@@ -411,6 +411,83 @@ def test_sparse_select_scans_then_selects(monkeypatch):
         assert np.array_equal(got, np.sort(np.asarray(theirs), axis=-1))
 
 
+# ---- the read of the chosen rows
+
+def read_inputs(kind, batch, capacity, k, seed, heads=4, rank=32, rope=8):
+    """Queries, bfloat16 caches and each session's chosen slots in slot order,
+    ``-1`` past the visible ones.  ``below``: ``k`` of many visible slots;
+    ``short``: fewer visible slots than ``k``; ``odd`` / ``even``: slots of one
+    parity, the queries' latent part zero so that the rotary keys alone decide
+    the weights; ``last``: the capacity's last row among them."""
+    rng = np.random.default_rng(seed)
+    latent = jnp.asarray(rng.standard_normal((batch, capacity, rank)), jnp.bfloat16)
+    # the two positions of a rope row far apart, so that reading a neighbour's key shows
+    turned = rng.standard_normal((batch, capacity // la.ROPE_PACK, la.ROPE_PACK, rope))
+    turned[:, :, 1] *= -3.0
+    turned = jnp.asarray(turned.reshape(batch, capacity // la.ROPE_PACK, -1), jnp.bfloat16)
+    q_lat = rng.standard_normal((batch, heads, rank)) * (0.0 if kind in ("odd", "even") else 0.3)
+    q_pe = rng.standard_normal((batch, heads, rope)) * 0.3
+    chosen = np.full((batch, k), -1, np.int32)
+    for b in range(batch):
+        visible = capacity if kind == "last" else [capacity - 5, k // 3 + b, capacity // 2][b % 3]
+        if kind == "short":
+            visible = k // 2 + b
+        pool = np.arange(visible)
+        if kind in ("odd", "even"):
+            pool = pool[pool % 2 == (kind == "odd")]
+        slots = np.sort(rng.choice(pool, min(k, pool.size), replace=False))
+        if kind == "last":
+            slots[-1] = capacity - 1
+            slots = np.unique(slots)
+        chosen[b, :slots.size] = slots
+    return (jnp.asarray(q_lat, jnp.float32), jnp.asarray(q_pe, jnp.float32), latent, turned,
+            jnp.asarray(chosen))
+
+
+@pytest.mark.parametrize("batch", [1, 3])
+@pytest.mark.parametrize("kind,capacity,k", [("below", 256, 40), ("short", 256, 40),
+                                             ("odd", 128, 24), ("even", 128, 24),
+                                             ("last", 96, 30), ("below", 64, 16)])
+def test_the_read_kernel_is_the_gather(kind, capacity, k, batch, monkeypatch):
+    """``ht_sparse_latent_read`` under the interpreter equals the ``jax.numpy``
+    gather's attention over the same chosen slots, each slot reading its own
+    position's rotary key (reading the neighbour's is far off)."""
+    q_lat, q_pe, latent, turned, chosen = read_inputs(kind, batch, capacity, k, seed=batch)
+    got = {}
+    for how in ("off", "interpret"):
+        monkeypatch.setenv("HEAT_TPU_PALLAS", how)
+        assert la.read_form(batch, capacity, k, 32, 8) == (
+            "gather_kernel" if how == "interpret" else "gather_jnp")
+        got[how] = la.latent_decode_attention(q_lat, q_pe, latent, turned, chosen, 0.3)
+    assert rel_err(got["interpret"], got["off"]) < F32_TOL
+    if kind in ("odd", "even"):
+        monkeypatch.setenv("HEAT_TPU_PALLAS", "off")
+        swapped = jnp.where(chosen >= 0, chosen ^ 1, -1)
+        wrong = la.latent_decode_attention(q_lat, q_pe, latent, turned, swapped, 0.3)
+        assert rel_err(wrong, got["off"]) > 100 * F32_TOL
+
+
+@pytest.mark.parametrize("how,capacity,k,rank,rope,form", [
+    ("off", 33024, 2048, 512, 64, "gather_jnp"),
+    ("tpu", 33024, 33024, 512, 64, "gather_jnp"),
+    ("tpu", 33024, 2048, 512, 64, "gather_kernel"),
+    ("tpu", 33024, 16384, 512, 64, "gather_jnp"),
+    ("tpu", 33024, 2048, 576, 64, "gather_jnp"),
+    ("tpu", 33024, 2048, 512, 32, "gather_jnp"),
+    ("tpu", 33032, 2048, 512, 64, "gather_jnp"),
+    ("interpret", 64, 12, 32, 8, "gather_kernel"),
+    ("interpret", 40, 12, 32, 8, "gather_jnp"),
+    ("interpret", 64, 64, 32, 8, "gather_jnp")])
+def test_the_read_kernel_takes_whole_tiles_that_fit_its_memory(how, capacity, k, rank, rope, form,
+                                                               monkeypatch):
+    """``read_form``: the kernel where the mode runs kernels, fewer slots are
+    chosen than the capacity holds (the ``reads_all`` control chooses all),
+    the capacity is whole tiles of rows and, on a TPU, the rows are whole
+    lane tiles and the buffers fit the 16 MiB every kernel has."""
+    monkeypatch.setenv("HEAT_TPU_PALLAS", how)
+    assert la.read_form(16, capacity, k, rank, rope) == form
+
+
 # ---- the router and the held experts
 
 def routed(tokens=40, experts=8, d=32, seed=0, bias_std=0.3):
@@ -610,24 +687,43 @@ def test_spans_counters_and_one_sync_a_decode(model):
                                          "sync:lm.tokens"]
     decode = {e["name"]: e for e in begun}["lm.decode"]
     assert {k: decode[k] for k in ("batch", "context", "steps", "layers", "moe_layers", "selected",
-                                   "select", "latent_bytes", "index_bytes", "experts_held",
+                                   "select", "read", "latent_bytes", "index_bytes", "experts_held",
                                    "expert_bytes")
             } == dict(batch=2, context=11, steps=2, layers=3, moe_layers=2, selected=12,
-                      select="cut_jnp", latent_bytes=4 * 40, index_bytes=4 * 16, experts_held=2,
-                      expert_bytes=4 * 3 * 64 * 32)
+                      select="cut_jnp", read="gather_jnp", latent_bytes=4 * 40,
+                      index_bytes=4 * 16, experts_held=2, expert_bytes=4 * 3 * 64 * 32)
     lm = {k: after["lm"][k] - before["lm"][k] for k in
           ("decode_steps", "prefill_tokens", "index_keys_scanned", "latent_rows_read",
-           "selections_by_cut", "state_bytes_copied", "state_bytes_stepped")}
+           "selections_by_cut", "latent_reads_by_kernel", "state_bytes_copied",
+           "state_bytes_stepped")}
     seen = [12, 13, 14, 12, 13]
     assert lm == {"decode_steps": 5, "prefill_tokens": 22, "index_keys_scanned": 2 * 3 * sum(seen),
                   "latent_rows_read": 2 * 3 * sum(min(12, s) for s in seen),
-                  "selections_by_cut": 3 * 5, "state_bytes_copied": 0, "state_bytes_stepped": 0}
+                  "selections_by_cut": 3 * 5, "latent_reads_by_kernel": 0,
+                  "state_bytes_copied": 0, "state_bytes_stepped": 0}
     pairs = after["lm"]["expert_pairs"] - before["lm"]["expert_pairs"]
     hit = after["lm"]["experts_hit"] - before["lm"]["experts_hit"]
     assert 0 <= hit <= 5 * 2 * 2 and hit <= pairs <= 5 * 2 * 2 * 2
     assert after["sync"]["count"] - before["sync"]["count"] == 3  # one a prefill, one a decode
     assert session.tokens.shape == (2, 2) and session.position == 13
     assert model.last_selection.shape == (3, 2, 12)
+
+
+@pytest.mark.parametrize("how,read,counted", [("off", "gather_jnp", 0),
+                                              ("interpret", "gather_kernel", 3 * 4)])
+def test_the_decode_span_says_which_form_read(model, pallas, how, read, counted):
+    """``lm.decode`` carries ``read``, and ``lm`` counts the layer-steps the
+    read's kernel fetched: steps x layers, or 0."""
+    pallas(how)
+    session = model.session(2, 64)
+    session.prefill(ht.array(prompts(model.cfg, 2, 8)))
+    before = telemetry.snapshot()["lm"]["latent_reads_by_kernel"]
+    with telemetry.telemetry_level("events"):
+        telemetry.clear_events()
+        session.decode(4)
+        begun = [e for e in telemetry.events("span_begin") if e["name"] == "lm.decode"]
+    assert [e["read"] for e in begun] == [read]
+    assert telemetry.snapshot()["lm"]["latent_reads_by_kernel"] - before == counted
 
 
 @pytest.mark.parametrize("how,prompt,attend,folded", [
@@ -936,4 +1032,31 @@ def test_the_selection_compiled_for_the_chip_holds_no_sort(v5e, monkeypatch):
     # the kernel reserves no more VMEM than any kernel has: with 64 MiB reserved, XLA could no
     # longer keep a layer's rope cache (67 MB) in VMEM for the gather behind it (PERF.md, PR 35)
     call = next(line for line in text.splitlines() if "ht_sparse_cut" in line and "custom-call(" in line)
+    assert max(map(int, re.findall(r'"size":"(\d+)"', call)), default=0) <= 16 << 20
+
+
+def test_the_read_compiled_for_the_chip_is_the_kernel(v5e, monkeypatch):
+    """A decode step's attention at the cell's shapes (16 sessions, a capacity
+    of 33,024, 2,048 chosen) compiled for a v5e reads the chosen rows through
+    ``ht_sparse_latent_read``: no gather of latent rows is left to XLA, and
+    the kernel reserves no more VMEM than any kernel has (PERF.md section 6)."""
+    monkeypatch.setenv("HEAT_TPU_PALLAS", "tpu")
+    cfg = deepseek.DeepSeekConfig.from_dict(PUBLISHED, **CUT)
+    batch, capacity = 16, 33024
+    assert la.read_form(batch, capacity, cfg.index_topk, 512, 64) == "gather_kernel"
+    params = jax.eval_shape(lambda: deepseek.init_params(cfg, jax.random.key(0)))["layers"][1]
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(s, dtype, sharding=v5e)  # noqa: E731
+    params = jax.tree.map(lambda x: shape(*x.shape, dtype=x.dtype), params)
+    caches = (shape(batch, capacity, 512), shape(batch, capacity // 2, 128),
+              shape(batch, capacity, 128))
+    with jax.enable_x64(False):
+        text = jax.jit(lambda p, x, pos, c: deepseek._attention_step(cfg, p, x, pos, c)).lower(
+            params, shape(batch, cfg.hidden_size, dtype=jnp.float32), shape(dtype=jnp.int32),
+            caches).compile().as_text()
+    assert "ht_sparse_latent_read" in text and "ht_sparse_cut" in text
+    # the rotary turns' small lane gathers stay; a row gather takes one whole row a slot
+    assert not [line for line in text.splitlines()
+                if re.search(r"\bgather\(", line) and re.search(r"slice_sizes=\{1,1,\d+\}", line)]
+    call = next(line for line in text.splitlines()
+                if "ht_sparse_latent_read" in line and "custom-call(" in line)
     assert max(map(int, re.findall(r'"size":"(\d+)"', call)), default=0) <= 16 << 20
